@@ -118,6 +118,12 @@ class _ReceiverLoop:
                             return
                         payload = self._recv()
                 except zmq.Again:
+                    # Let a sender blocked on the socket lock have it:
+                    # Python locks are not fair, and this loop re-taking
+                    # the lock the instant it drops it can starve a
+                    # send() for minutes (seen as a 2-process gang hung
+                    # in its checkpoint gather).
+                    time.sleep(0.001)  # resilience-ok: lock hand-off yield, not a retry
                     continue
                 except zmq.ZMQError as e:
                     if self._is_closed():

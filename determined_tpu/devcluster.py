@@ -92,8 +92,12 @@ class DevCluster:
 
     # -- agents (start/kill for chaos tests, ref test_agent_restart.py) -------
     def start_agent(
-        self, agent_id: str, slots: int, state_dir: Optional[str] = None
+        self, agent_id: str, slots: Any, state_dir: Optional[str] = None
     ) -> AgentDaemon:
+        """`slots`: an int (artificial slots — what the tests use), or
+        "auto" to detect the host's chips. Detection runs in a short-lived
+        child (agent.detect_devices), so this process — which also hosts
+        the master — never holds a chip its trials need."""
         agent = AgentDaemon(
             self.api.url, agent_id=agent_id, slots=slots,
             python_exe=sys.executable, state_dir=state_dir,

@@ -1567,10 +1567,18 @@ def build_routes(m: Master) -> List[Tuple[str, re.Pattern, Handler]]:
         raise _PlainText(data, content_type="application/octet-stream")
 
     def master_info(r: ApiRequest):
+        from determined_tpu.master import native_sched
+
         return {
             "cluster_id": m.cluster_id,
             "version": __import__("determined_tpu").__version__,
             "agents": m.agent_hub.list(),
+            # Which gang-fitting scan this process runs: a failed build of
+            # native/scheduler.cpp falls back silently otherwise.
+            "scheduler_fit": (
+                "native" if native_sched.load_library(build=False) is not None
+                else "python"
+            ),
         }
 
     def master_logs(r: ApiRequest):

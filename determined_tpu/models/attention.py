@@ -22,7 +22,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from determined_tpu.common.jaxcompat import shard_map
+from jax import shard_map
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 

@@ -774,7 +774,7 @@ class GPT(Model):
         context ppermutes K/V — independent rings of the same program);
         remaining axes (data/fsdp/tensor) stay under GSPMD control.
         """
-        from determined_tpu.common.jaxcompat import shard_map
+        from jax import shard_map
 
         from determined_tpu.parallel.pipeline import (
             circular_pipeline_apply,
@@ -1309,7 +1309,7 @@ class GPT(Model):
         the trainer's jax.grad unchanged. eval reuses this path and simply
         discards the gradients.
         """
-        from determined_tpu.common.jaxcompat import shard_map
+        from jax import shard_map
         from determined_tpu.parallel.pipeline import one_f_one_b_grads
 
         c = self.config

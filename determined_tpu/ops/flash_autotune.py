@@ -15,11 +15,18 @@ resolve block sizes at model-build time (see GPT._flash_blocks) and pass
 plain ints into the traced code.
 
 Cache: one JSON object at `DTPU_FLASH_TUNE_CACHE` (default
-`~/.cache/determined_tpu/flash_blocks.json`), keyed by cache-format
-version, device kind, jax version, folded shape, dtype and masking mode —
-any of those changing invalidates the entry by construction; delete the
-file to force a re-probe. Writes are atomic (tempfile + rename) and
-best-effort: a read-only filesystem degrades to probing once per process.
+`<checkout>/.cache/flash_blocks.json`, next to the XLA compile cache —
+common/compile_cache.py — so the winners that decide which kernel shape
+runs live and die with the checkout), keyed by cache-format version,
+device kind, jax version, folded shape, dtype and masking mode — any of
+those changing invalidates the entry by construction; delete the file to
+force a re-probe. Writes are atomic (tempfile + rename) and best-effort:
+a read-only filesystem degrades to probing once per process.
+
+A candidate the compiler refuses (VMEM, shape) loses and is named at
+`warning`; when EVERY candidate fails the tuner raises with the
+compiler's message — on a machine that has the chip there is no
+untuned fallback to hide behind.
 
 Off-TPU (CPU tests, trial processes on the master) no probe ever runs: the
 tuner returns the caller's wanted blocks fitted to the sequence, which is
@@ -33,11 +40,12 @@ import logging
 import os
 import tempfile
 import time
-from typing import List, Optional, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from determined_tpu.common.compile_cache import cache_root
 from determined_tpu.ops.flash_attention import (
     _MONO_MAX_SCORES,
     fit_block,
@@ -70,10 +78,7 @@ _PROBE_STEPS = 3
 def cache_path() -> str:
     return os.environ.get(
         "DTPU_FLASH_TUNE_CACHE",
-        os.path.join(
-            os.path.expanduser("~"), ".cache", "determined_tpu",
-            "flash_blocks.json",
-        ),
+        os.path.join(cache_root(), "flash_blocks.json"),
     )
 
 
@@ -135,99 +140,122 @@ def candidate_blocks(s_q: int, s_k: int,
     return out
 
 
+def _best_of_ms(step: Callable[[], object]) -> float:
+    """Best-of-N wall ms of `step` after warmup."""
+    for _ in range(_PROBE_WARMUP):
+        jax.block_until_ready(step())
+    best = float("inf")
+    for _ in range(_PROBE_STEPS):
+        t0 = time.perf_counter()
+        jax.block_until_ready(step())
+        best = min(best, time.perf_counter() - t0)
+    return best * 1e3
+
+
 def _probe_ms(bq: int, bk: int, *, s_q: int, s_k: int, n_heads: int,
               head_dim: int, batch: int, dtype, causal: bool,
               window: Optional[int], segments: bool = False) -> float:
-    """Best-of-N wall ms of one jitted fwd+bwd step at (bq, bk); inf on
-    compile/OOM failure so the candidate simply loses."""
-    try:
-        keys = jax.random.split(jax.random.PRNGKey(0), 3)
-        q = jax.random.normal(keys[0], (batch, s_q, n_heads, head_dim), dtype)
-        k = jax.random.normal(keys[1], (batch, s_k, n_heads, head_dim), dtype)
-        v = jax.random.normal(keys[2], (batch, s_k, n_heads, head_dim), dtype)
-        seg = kv_seg = None
-        if segments:
-            # Representative packed pattern: a few contiguous docs per
-            # row. The mask VALUES barely matter for timing; the extra
-            # operands and the segment-compare VPU work do.
-            def runs(s):
-                return jnp.cumsum(
-                    (jnp.arange(s) % max(s // 4, 1) == 0).astype(jnp.int32)
-                )[None, :].repeat(batch, axis=0)
+    """Best-of-N wall ms of one jitted fwd+bwd step at (bq, bk). Raises
+    what the compiler raises (`_pick_fastest` decides what that means)."""
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(keys[0], (batch, s_q, n_heads, head_dim), dtype)
+    k = jax.random.normal(keys[1], (batch, s_k, n_heads, head_dim), dtype)
+    v = jax.random.normal(keys[2], (batch, s_k, n_heads, head_dim), dtype)
+    seg = kv_seg = None
+    if segments:
+        # Representative packed pattern: a few contiguous docs per
+        # row. The mask VALUES barely matter for timing; the extra
+        # operands and the segment-compare VPU work do.
+        def runs(s):
+            return jnp.cumsum(
+                (jnp.arange(s) % max(s // 4, 1) == 0).astype(jnp.int32)
+            )[None, :].repeat(batch, axis=0)
 
-            seg, kv_seg = runs(s_q), runs(s_k)
+        seg, kv_seg = runs(s_q), runs(s_k)
 
-        def loss(q, k, v):
-            o = flash_attention(
-                q, k, v, causal=causal, window=window, segment_ids=seg,
-                kv_segment_ids=kv_seg, block_q=bq, block_k=bk,
-            )
-            return jnp.sum(o.astype(jnp.float32))
+    def loss(q, k, v):
+        o = flash_attention(
+            q, k, v, causal=causal, window=window, segment_ids=seg,
+            kv_segment_ids=kv_seg, block_q=bq, block_k=bk,
+        )
+        return jnp.sum(o.astype(jnp.float32))
 
-        # All three gradients: grad-wrt-q alone would let XLA dead-code the
-        # dk/dv pass out of the two-pass backward split and rank candidates
-        # on a backward real training never runs.
-        step = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-        for _ in range(_PROBE_WARMUP):
-            jax.block_until_ready(step(q, k, v))
-        best = float("inf")
-        for _ in range(_PROBE_STEPS):
-            t0 = time.perf_counter()
-            jax.block_until_ready(step(q, k, v))
-            best = min(best, time.perf_counter() - t0)
-        return best * 1e3
-    except Exception:  # noqa: BLE001 - losing candidate, not an error
-        logger.debug("flash probe (%d, %d) failed", bq, bk, exc_info=True)
-        return float("inf")
+    # All three gradients: grad-wrt-q alone would let XLA dead-code the
+    # dk/dv pass out of the two-pass backward split and rank candidates
+    # on a backward real training never runs.
+    step = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    return _best_of_ms(lambda: step(q, k, v))
 
 
 def _probe_paged_ms(block_h: int, *, n_heads: int, head_dim: int,
                     page_size: int, num_pages: int, pages_per_slot: int,
                     batch: int, q_rows: int, dtype) -> float:
     """Best-of-N wall ms of one jitted paged-attention decode step at
-    `block_h` heads per grid step; inf on compile/OOM failure."""
-    try:
-        import functools
+    `block_h` heads per grid step. Raises what the compiler raises."""
+    import functools
 
-        from determined_tpu.ops.paged_attention import paged_attention
+    from determined_tpu.ops.paged_attention import paged_attention
 
-        keys = jax.random.split(jax.random.PRNGKey(0), 3)
-        # Probe on a REDUCED pool: per-step cost depends on the pages a
-        # slot actually reads (page_size × pages_per_slot × batch), not
-        # on total pool residency — and the engine calls this AFTER its
-        # real pools are allocated, so probing at the full num_pages
-        # would double peak HBM (and OOM exactly the headroom-sized
-        # pools the tuner matters for).
-        probe_pages = min(num_pages, batch * pages_per_slot + 1)
-        kp = jax.random.normal(
-            keys[0], (probe_pages, page_size, n_heads, head_dim), dtype
-        )
-        vp = jax.random.normal(
-            keys[1], (probe_pages, page_size, n_heads, head_dim), dtype
-        )
-        q = jax.random.normal(
-            keys[2], (batch, q_rows, n_heads, head_dim), dtype
-        )
-        # High-occupancy state: the regime the kernel exists for.
-        pt = (
-            jnp.arange(batch * pages_per_slot, dtype=jnp.int32)
-            % max(probe_pages - 1, 1) + 1
-        ).reshape(batch, pages_per_slot)
-        lengths = jnp.full((batch,), pages_per_slot * page_size - 1,
-                           jnp.int32)
-        active = jnp.ones((batch,), jnp.int32)
-        step = jax.jit(functools.partial(paged_attention, block_h=block_h))
-        for _ in range(_PROBE_WARMUP):
-            jax.block_until_ready(step(q, kp, vp, pt, lengths, active))
-        best = float("inf")
-        for _ in range(_PROBE_STEPS):
-            t0 = time.perf_counter()
-            jax.block_until_ready(step(q, kp, vp, pt, lengths, active))
-            best = min(best, time.perf_counter() - t0)
-        return best * 1e3
-    except Exception:  # noqa: BLE001 - losing candidate, not an error
-        logger.debug("paged probe block_h=%d failed", block_h, exc_info=True)
-        return float("inf")
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    # Probe on a REDUCED pool: per-step cost depends on the pages a
+    # slot actually reads (page_size × pages_per_slot × batch), not
+    # on total pool residency — and the engine calls this AFTER its
+    # real pools are allocated, so probing at the full num_pages
+    # would double peak HBM (and OOM exactly the headroom-sized
+    # pools the tuner matters for).
+    probe_pages = min(num_pages, batch * pages_per_slot + 1)
+    kp = jax.random.normal(
+        keys[0], (probe_pages, page_size, n_heads, head_dim), dtype
+    )
+    vp = jax.random.normal(
+        keys[1], (probe_pages, page_size, n_heads, head_dim), dtype
+    )
+    q = jax.random.normal(
+        keys[2], (batch, q_rows, n_heads, head_dim), dtype
+    )
+    # High-occupancy state: the regime the kernel exists for.
+    pt = (
+        jnp.arange(batch * pages_per_slot, dtype=jnp.int32)
+        % max(probe_pages - 1, 1) + 1
+    ).reshape(batch, pages_per_slot)
+    lengths = jnp.full((batch,), pages_per_slot * page_size - 1,
+                       jnp.int32)
+    active = jnp.ones((batch,), jnp.int32)
+    step = jax.jit(functools.partial(paged_attention, block_h=block_h))
+    return _best_of_ms(lambda: step(q, kp, vp, pt, lengths, active))
+
+
+def _pick_fastest(key: str, cands: Sequence, probe: Callable):
+    """The fastest of `cands`. A candidate whose probe raises (the
+    compiler refused its VMEM or shape) loses and is named at warning.
+    Every candidate failing is an error carrying the compiler's message:
+    there is no kernel to run, and an untuned guess would only move the
+    same failure into the first training or decode step."""
+    timings, last_err = {}, None
+    for cand in cands:
+        try:
+            timings[cand] = probe(cand)
+        except Exception as e:  # noqa: BLE001 — a losing candidate
+            logger.warning("autotune %s: candidate %s failed: %s",
+                           key, cand, str(e)[:500])
+            last_err = e
+    # Forget what the probes traced and compiled. The losers' executables
+    # are dead weight, and — the reason it matters — a Mosaic kernel
+    # serialises source locations that jax's tracing caches carry over
+    # from whichever kernel was traced first: without this, the process
+    # that probed compiles its real programs under other persistent-cache
+    # keys than the next process, which reads the winner from disk, and
+    # every first restart recompiles them.
+    jax.clear_caches()
+    if not timings:
+        raise RuntimeError(
+            f"autotune {key}: all {len(cands)} candidates failed; "
+            f"last error: {last_err}"
+        ) from last_err
+    best = min(timings, key=timings.get)
+    logger.info("autotune %s -> %s (%.2f ms; %d candidates)",
+                key, best, timings[best], len(cands))
+    return best
 
 
 def tune_paged_block_h(
@@ -249,18 +277,22 @@ def tune_paged_block_h(
     at the cost of VMEM residency.
 
     Call OUTSIDE jit. Off-TPU (or with DTPU_FLASH_AUTOTUNE=0) returns
-    the deterministic VMEM-budget fallback; on TPU the winner is probed
-    once and cached, keyed by the FULL pool geometry (page_size ×
+    the deterministic `default_paged_block_h`; on TPU the winner is
+    probed once and cached, keyed by the FULL pool geometry (page_size ×
     num_pages × pages_per_slot × batch × heads/dim/q_rows/dtype) — a
-    resized pool re-probes by construction.
+    resized pool re-probes by construction. Raises if the compiler
+    refuses every candidate.
     """
-    from determined_tpu.ops.paged_attention import default_paged_block_h
+    from determined_tpu.ops.paged_attention import (
+        default_paged_block_h,
+        paged_block_h_candidates,
+    )
 
-    fallback = default_paged_block_h(n_heads, head_dim, page_size, dtype)
-    if os.environ.get("DTPU_FLASH_AUTOTUNE", "1") == "0":
-        return fallback
-    if jax.default_backend() != "tpu":
-        return fallback
+    if (
+        os.environ.get("DTPU_FLASH_AUTOTUNE", "1") == "0"
+        or jax.default_backend() != "tpu"
+    ):
+        return default_paged_block_h(n_heads, head_dim, page_size, dtype)
 
     path = cache_file or cache_path()
     key = "|".join([
@@ -272,41 +304,22 @@ def tune_paged_block_h(
         f"ps{page_size}np{num_pages}pp{pages_per_slot}",
         jnp.dtype(dtype).name,
     ])
-    cache = _load_cache(path)
-    hit = cache.get(key)
+    hit = _load_cache(path).get(key)
     if isinstance(hit, int) and hit >= 1:
         return hit
-    from determined_tpu.ops.paged_attention import paged_block_h_fits
-
-    # Divisors of H whose resident K+V page group fits the kernel's VMEM
-    # budget — candidates past it can never win, and each would cost a
-    # full Pallas compile just to fail to inf. The fallback is always in
-    # the set by construction (it is chosen through the same predicate).
-    cands = [
-        h for h in range(1, n_heads + 1)
-        if n_heads % h == 0
-        and paged_block_h_fits(h, head_dim, page_size, dtype)
-    ] or [fallback]
-    timings = {
-        h: _probe_paged_ms(
+    # Only what the lowering admits and the VMEM budget fits
+    # (paged_block_h_candidates): a refused candidate costs a full Pallas
+    # compile just to lose.
+    best = _pick_fastest(
+        key,
+        paged_block_h_candidates(n_heads, head_dim, page_size, dtype),
+        lambda h: _probe_paged_ms(
             h, n_heads=n_heads, head_dim=head_dim, page_size=page_size,
             num_pages=num_pages, pages_per_slot=pages_per_slot,
             batch=batch, q_rows=q_rows, dtype=dtype,
-        )
-        for h in cands
-    }
-    best = min(timings, key=timings.get)
-    if timings[best] == float("inf"):
-        logger.warning(
-            "paged autotune %s: all %d probes failed; using fallback %d "
-            "(not cached)", key, len(cands), fallback,
-        )
-        return fallback
-    logger.info(
-        "paged autotune %s -> block_h %d (%.2f ms; %d candidates)",
-        key, best, timings[best], len(cands),
+        ),
     )
-    cache = _load_cache(path)
+    cache = _load_cache(path)  # re-read: another process may have written
     cache[key] = int(best)
     _store_cache(path, cache)
     return best
@@ -332,7 +345,8 @@ def tune_flash_blocks(
     Call OUTSIDE jit (this may execute probe steps on the device). Returns
     the fitted wanted blocks immediately off-TPU or when disabled via
     DTPU_FLASH_AUTOTUNE=0; otherwise returns the cached winner, probing
-    once per (device kind, jax version, shape, dtype, mask mode).
+    once per (device kind, jax version, shape, dtype, mask mode). Raises
+    if the compiler refuses every candidate.
 
     `segments`: tune for packed-sequence batches — the probe carries
     segment ids (so every candidate times the kernel that configuration
@@ -357,28 +371,14 @@ def tune_flash_blocks(
     if isinstance(hit, (list, tuple)) and len(hit) == 2:
         return int(hit[0]), int(hit[1])
 
-    cands = candidate_blocks(s_q, s_k, want_q, want_k)
-    timings = {}
-    for bq, bk in cands:
-        timings[(bq, bk)] = _probe_ms(
-            bq, bk, s_q=s_q, s_k=s_k, n_heads=n_heads, head_dim=head_dim,
-            batch=batch, dtype=dtype, causal=causal, window=window,
-            segments=segments,
-        )
-    best = min(timings, key=timings.get)
-    if timings[best] == float("inf"):
-        # Every candidate failed (transient device trouble, fragmented
-        # HBM): return the fallback for THIS process but do NOT cache it —
-        # a written entry would pin the untuned blocks on this box forever
-        # while the condition that caused it was temporary.
-        logger.warning(
-            "flash autotune %s: all %d probes failed; using fallback %s "
-            "(not cached)", key, len(cands), fallback,
-        )
-        return fallback
-    logger.info(
-        "flash autotune %s -> blocks %s (%.2f ms; %d candidates)",
-        key, best, timings[best], len(cands),
+    best = _pick_fastest(
+        key,
+        candidate_blocks(s_q, s_k, want_q, want_k),
+        lambda c: _probe_ms(
+            c[0], c[1], s_q=s_q, s_k=s_k, n_heads=n_heads,
+            head_dim=head_dim, batch=batch, dtype=dtype, causal=causal,
+            window=window, segments=segments,
+        ),
     )
     cache = _load_cache(path)  # re-read: another process may have written
     cache[key] = list(best)

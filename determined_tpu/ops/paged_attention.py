@@ -28,6 +28,15 @@ forward (same op sequence on the same fp32 values), so decode through
 this kernel is bitwise-equal to the gather path whenever the gather
 path's ``block_k`` equals ``page_size`` — the parity tests pin that.
 
+Layout: the pool is row-major ``[pages, page, H, Dh]``, so the kernel
+takes it (and q/o) with heads folded into the minor dimension —
+``[pages, page, H·Dh]``, a free reshape — and slices each head's ``Dh``
+lanes out of the block. Heads as a second-minor block axis is what the
+TPU compiler refuses below the 128-lane tile (GPT-2's 12 × 64: Mosaic
+cannot lay out the ``(q_rows, Dh)`` → ``(1, q_rows, 1, Dh)`` store, and
+no ``block_h`` but ``H`` passes the block-shape rule); folded, a block's
+minor extent is ``block_h·Dh`` and `paged_block_h_ok` is the rule.
+
 ``block_h`` (heads per grid step) is the one tunable: more heads per
 step amortize each page's DMA across heads at the cost of VMEM
 residency. It is sized by ``ops/flash_autotune.tune_paged_block_h``
@@ -37,7 +46,7 @@ residency. It is sized by ``ops/flash_autotune.tune_paged_block_h``
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -59,7 +68,7 @@ class _LazyPallas:
 pl = _LazyPallas()
 
 #: K/V pages enter the kernel as ``(page_size, head_dim)`` MXU tiles with
-#: ``page_size`` on the lane-tiled axis — the same granule ``fit_block``
+#: ``page_size`` on the lane-tiled axis of the score matrix — the same granule ``fit_block``
 #: prefers for flash ``block_k``. A misaligned ``page_size`` must be a
 #: named config error (serving/config.py mirrors this constant), not a
 #: mid-decode Mosaic shape failure.
@@ -84,17 +93,35 @@ def paged_block_h_fits(block_h: int, head_dim: int, page_size: int,
     )
 
 
+def paged_block_h_ok(block_h: int, n_heads: int, head_dim: int) -> bool:
+    """Does the TPU lowering admit ``block_h`` heads per grid step? The
+    block's minor extent is ``block_h · head_dim`` lanes of the folded
+    ``H · Dh`` axis: a multiple of the 128-lane tile, or the whole axis."""
+    return n_heads % block_h == 0 and (
+        block_h == n_heads or (block_h * head_dim) % LANE_GRANULE == 0
+    )
+
+
+def paged_block_h_candidates(n_heads: int, head_dim: int, page_size: int,
+                             dtype) -> List[int]:
+    """Every ``block_h`` the lowering admits whose K+V page group fits
+    the VMEM budget, ascending — the autotuner's candidate set. Never
+    empty: ``block_h == n_heads`` is always admissible, and when nothing
+    fits the budget the smallest admissible group (least VMEM) stands."""
+    ok = [
+        h for h in range(1, n_heads + 1)
+        if paged_block_h_ok(h, n_heads, head_dim)
+    ]
+    return [
+        h for h in ok if paged_block_h_fits(h, head_dim, page_size, dtype)
+    ] or ok[:1]
+
+
 def default_paged_block_h(n_heads: int, head_dim: int, page_size: int,
                           dtype) -> int:
-    """Largest divisor of ``n_heads`` whose K+V page group fits the VMEM
-    budget — the deterministic no-probe fallback the autotuner refines."""
-    best = 1
-    for cand in range(1, n_heads + 1):
-        if n_heads % cand:
-            continue
-        if paged_block_h_fits(cand, head_dim, page_size, dtype):
-            best = cand
-    return best
+    """Largest candidate — the deterministic no-probe choice the
+    autotuner refines."""
+    return paged_block_h_candidates(n_heads, head_dim, page_size, dtype)[-1]
 
 
 def _page_index(b, hg, j, pt_ref, len_ref, ql_ref, act_ref, *, page_size):
@@ -113,7 +140,7 @@ def _page_index(b, hg, j, pt_ref, len_ref, ql_ref, act_ref, *, page_size):
 
 def _paged_kernel(pt_ref, len_ref, ql_ref, act_ref, q_ref, k_ref, v_ref,
                   o_ref, m_scr, l_scr, acc_scr, *, scale, page_size,
-                  block_h, num_page_slots, q_rows):
+                  block_h, num_page_slots, q_rows, head_dim):
     """One (slot, head-group, page) step of the paged decode grid.
 
     Math per head mirrors ops/flash_attention._fwd_kernel exactly (dot →
@@ -143,9 +170,10 @@ def _paged_kernel(pt_ref, len_ref, ql_ref, act_ref, q_ref, k_ref, v_ref,
 
     def _compute(edge_masked):
         for h in range(block_h):
-            q = q_ref[0, :, h, :]     # [q_rows, Dh]
-            k = k_ref[0, :, h, :]     # [page_size, Dh]
-            v = v_ref[0, :, h, :]
+            lanes = slice(h * head_dim, (h + 1) * head_dim)
+            q = q_ref[0, :, lanes]    # [q_rows, Dh]
+            k = k_ref[0, :, lanes]    # [page_size, Dh]
+            v = v_ref[0, :, lanes]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -204,7 +232,9 @@ def _paged_kernel(pt_ref, len_ref, ql_ref, act_ref, q_ref, k_ref, v_ref,
             rows = slice(h * q_rows, (h + 1) * q_rows)
             l = l_scr[rows, 0:1]
             l_safe = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, :, h, :] = (acc_scr[rows] / l_safe).astype(o_ref.dtype)
+            o_ref[0, :, h * head_dim:(h + 1) * head_dim] = (
+                acc_scr[rows] / l_safe
+            ).astype(o_ref.dtype)
 
 
 def paged_attention(
@@ -267,28 +297,34 @@ def paged_attention(
                                         k_pool.dtype)
     if n_heads % block_h:
         raise ValueError(f"block_h {block_h} must divide n_heads {n_heads}")
+    if not interpret and not paged_block_h_ok(block_h, n_heads, head_dim):
+        raise ValueError(
+            f"block_h {block_h} x head_dim {head_dim} is neither a "
+            f"multiple of {LANE_GRANULE} lanes nor all {n_heads} heads — "
+            "the TPU lowering refuses that block"
+        )
     scale = scale if scale is not None else 1.0 / (head_dim ** 0.5)
 
     kv_map = functools.partial(_page_index, page_size=page_size)
 
     def head_map(b_, hg, j, pt_ref, len_ref, ql_ref, act_ref):
         del j, pt_ref, len_ref, ql_ref, act_ref
-        return (b_, 0, hg, 0)
+        return (b_, 0, hg)
 
     def kv_block_map(b_, hg, j, pt_ref, len_ref, ql_ref, act_ref):
         return (
-            kv_map(b_, hg, j, pt_ref, len_ref, ql_ref, act_ref), 0, hg, 0
+            kv_map(b_, hg, j, pt_ref, len_ref, ql_ref, act_ref), 0, hg
         )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b, n_heads // block_h, num_page_slots),
         in_specs=[
-            pl.BlockSpec((1, q_rows, block_h, head_dim), head_map),
-            pl.BlockSpec((1, page_size, block_h, head_dim), kv_block_map),
-            pl.BlockSpec((1, page_size, block_h, head_dim), kv_block_map),
+            pl.BlockSpec((1, q_rows, block_h * head_dim), head_map),
+            pl.BlockSpec((1, page_size, block_h * head_dim), kv_block_map),
+            pl.BlockSpec((1, page_size, block_h * head_dim), kv_block_map),
         ],
-        out_specs=pl.BlockSpec((1, q_rows, block_h, head_dim), head_map),
+        out_specs=pl.BlockSpec((1, q_rows, block_h * head_dim), head_map),
         scratch_shapes=[
             pltpu.VMEM((block_h * q_rows, 128), jnp.float32),   # m
             pltpu.VMEM((block_h * q_rows, 128), jnp.float32),   # l
@@ -297,21 +333,25 @@ def paged_attention(
     )
     kernel = functools.partial(
         _paged_kernel, scale=scale, page_size=page_size, block_h=block_h,
-        num_page_slots=num_page_slots, q_rows=q_rows,
+        num_page_slots=num_page_slots, q_rows=q_rows, head_dim=head_dim,
     )
+    # Heads fold into the minor dimension (free: all three are row-major
+    # with [H, Dh] innermost) — see the module docstring.
+    folded = n_heads * head_dim
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, q_rows, n_heads, head_dim),
-                                       k_pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, q_rows, folded), k_pool.dtype),
         interpret=interpret,
     )(
         page_table.astype(jnp.int32),
         lengths.astype(jnp.int32),
         q_lens.astype(jnp.int32),
         active.astype(jnp.int32),
-        q, k_pool, v_pool,
-    )
+        q.reshape(b, q_rows, folded),
+        k_pool.reshape(num_pages, page_size, folded),
+        v_pool.reshape(num_pages, page_size, folded),
+    ).reshape(b, q_rows, n_heads, head_dim)
 
 
 def paged_pages_read(lengths, active, page_size: int, q_lens=None) -> int:

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from determined_tpu.common import jaxcompat
 
 
 def pipeline_apply(
@@ -51,7 +50,7 @@ def pipeline_apply(
 
     Returns [M, mb, ...]: final-stage outputs, replicated across the axis.
     """
-    n_stages = jaxcompat.axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     stage_idx = lax.axis_index(axis_name)
     n_micro = microbatches.shape[0]
     ticks = n_micro + n_stages - 1
@@ -117,7 +116,7 @@ def circular_pipeline_apply(
 
     Returns [M, mb, ...] final outputs, replicated across the axis.
     """
-    n_stages = jaxcompat.axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     d = lax.axis_index(axis_name)
     v_stages = jax.tree.leaves(stage_params)[0].shape[0]
     n_micro = microbatches.shape[0]
@@ -247,7 +246,7 @@ def one_f_one_b_grads(
     stage_grads per-device with a leading stacking axis of 1 (use out_spec
     P(axis_name)).
     """
-    n_stages = jaxcompat.axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     d = lax.axis_index(axis_name)
     n_micro = tokens_mb.shape[0]
     cap = one_f_one_b_stash_size(n_micro, n_stages)

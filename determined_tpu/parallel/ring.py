@@ -44,10 +44,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from determined_tpu.common import jaxcompat
-from determined_tpu.common.jaxcompat import shard_map
 
 from determined_tpu.ops.flash_attention import fit_block, flash_attention_lse
 
@@ -141,7 +139,7 @@ def ring_attention(
     masking is not expressible with static offsets in this interleaved
     placement — windowed zigzag raises.
     """
-    ring_size = jaxcompat.axis_size(axis_name)
+    ring_size = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     b, s_local, h, d = q.shape
     scale = scale if scale is not None else 1.0 / (d ** 0.5)

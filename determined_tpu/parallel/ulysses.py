@@ -16,10 +16,8 @@ import functools
 from typing import Callable, Optional
 
 import jax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from determined_tpu.common import jaxcompat
-from determined_tpu.common.jaxcompat import shard_map
 
 from determined_tpu.parallel.ring import reference_attention
 
@@ -37,7 +35,7 @@ def ulysses_attention(
 
     Requires H divisible by the context-axis size.
     """
-    c = jaxcompat.axis_size(axis_name)
+    c = lax.axis_size(axis_name)
     local_attn = local_attn or functools.partial(reference_attention, causal=causal)
     if c == 1:
         return local_attn(q, k, v)

@@ -73,8 +73,11 @@ def _device_memory_metrics() -> Dict[str, float]:
                 continue
             used = stats.get("bytes_in_use")
             limit = stats.get("bytes_limit")
+            peak = stats.get("peak_bytes_in_use")
             if used is not None:
                 out[f"device{d.id}_bytes_in_use"] = float(used)
+            if peak is not None:
+                out[f"device{d.id}_peak_bytes_in_use"] = float(peak)
             if used is not None and limit:
                 out[f"device{d.id}_hbm_util"] = float(used) / float(limit)
     except Exception:  # noqa: BLE001 - profiling must never break training

@@ -316,6 +316,8 @@ class GenerationEngine:
              c.n_heads, c.head_dim), c.dtype,
         )
         self.cache_v = jnp.zeros_like(self.cache_k)
+        #: the replica's device (stats() reads its memory high-water mark)
+        self._device = next(iter(self.cache_k.devices()))
         #: decode query-row padding: lane-friendly on TPU, minimal on CPU
         #: (the blockwise reference pays per padded row; the MXU doesn't).
         self._q_pad = 8 if jax.default_backend() == "tpu" else 1
@@ -536,7 +538,52 @@ class GenerationEngine:
         )
 
     # -- lifecycle ----------------------------------------------------------
+    def warmup(self) -> None:
+        """Compile every program the loop can reach — packed prefill, page
+        scatter, cached-tail prefill, and the decode step (or the
+        speculative verify step that replaces it) — by running each once
+        on an all-padding / all-inactive batch, which writes scratch page
+        0 only. A program the compiler refuses is not a transient fault:
+        raising here, before the server accepts traffic, ends the process
+        instead of erroring every request behind a healthy `/healthz`
+        (`_recover` keeps its job for run-time faults)."""
+        import jax
+
+        cfg = self.cfg
+        b, per_req = cfg.max_batch_size, cfg.max_pages_per_request
+
+        def zeros(*shape, dtype=np.int32):
+            return self._jnp.zeros(shape, dtype)
+
+        grid = zeros(cfg.prefill_rows, cfg.prefill_seq)
+        _, k_l, v_l = self._prefill_fn(self.params, grid, grid, grid)
+        self.cache_k, self.cache_v = self._scatter_fn(
+            self.cache_k, self.cache_v, k_l, v_l,
+            zeros(self._prefill_pages_max, cfg.page_size),
+            zeros(self._prefill_pages_max),
+        )
+        if self._prefill_cached_fn is not None:
+            # Block: this reads the pool the decode step below donates.
+            jax.block_until_ready(self._prefill_cached_fn(
+                self.params, grid, grid, grid, self.cache_k, self.cache_v,
+                zeros(cfg.prefill_rows, per_req), zeros(cfg.prefill_rows),
+            ))
+        tail = (
+            zeros(b, dtype=bool), self.cache_k, self.cache_v,
+            zeros(b, per_req), zeros(b, dtype=np.float32),
+            jax.random.PRNGKey(0),
+        )
+        if self._spec_fn is not None:
+            out = self._spec_fn(
+                self.params, zeros(b, self._spec_draft_len + 1), zeros(b),
+                self._jnp.ones((b,), np.int32), *tail,
+            )
+        else:
+            out = self._decode_fn(self.params, zeros(b), zeros(b), *tail)
+        self.cache_k, self.cache_v = jax.block_until_ready(out[-2:])
+
     def start(self) -> None:
+        self.warmup()
         self._thread = threading.Thread(
             target=self._run, name="serving-engine", daemon=True
         )
@@ -1311,6 +1358,10 @@ class GenerationEngine:
             "pages_free": self.pool.free_pages,
             "decode_backend": self._decode_backend,
             "decode_kernel": self._decode_kernel,
+            # None where the backend keeps no memory statistics (CPU).
+            "device_peak_bytes": (self._device.memory_stats() or {}).get(
+                "peak_bytes_in_use"
+            ),
             "max_batch_size": self.cfg.max_batch_size,
             "max_context": self.max_total,
             "cache_hit_rate": 0.0,

@@ -425,7 +425,12 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser.add_argument("--config", default="", help="serving config JSON")
     args = parser.parse_args(argv)
     raw = args.config or os.environ.get("DTPU_SERVING_CONFIG", "") or "{}"
+    from determined_tpu.common import compile_cache
+
+    compile_cache.enable()
     engine = build_engine(json.loads(raw))
+    # Compiles prefill/decode/verify before the port opens; a program the
+    # compiler refuses raises here and the process exits non-zero.
     engine.start()
     server = GenerationServer(engine, host=args.host, port=args.port)
     server.start()

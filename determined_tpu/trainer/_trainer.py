@@ -32,6 +32,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from determined_tpu import core as core_mod
@@ -357,12 +358,18 @@ class Trainer:
 
         def init_fn(rng: jax.Array) -> Dict[str, Any]:
             params = self.model.init(rng)
-            # Constrain params here so XLA propagates the same shardings to
-            # the optimizer buffers (mu/nu mirror params) without us having
-            # to name them — GSPMD sharding propagation does the bookkeeping
-            # the reference delegated to DeepSpeed ZeRO config.
             params = jax.lax.with_sharding_constraint(params, param_shardings)
-            opt_state = self._tx.init(params)
+            # The optimizer's params-shaped buffers (adam's mu/nu) take the
+            # params' shardings by name: they are built by zeros_like, which
+            # carries no data dependence for GSPMD to propagate a sharding
+            # along, so left alone they come out REPLICATED — every device
+            # holding the whole optimizer state until the first step
+            # reshards it, and the step compiling twice (once for each
+            # input layout).
+            opt_state = optax.tree_utils.tree_map_params(
+                self._tx, jax.lax.with_sharding_constraint,
+                self._tx.init(params), param_shardings,
+            )
             return {
                 "step": jnp.zeros((), jnp.int32),
                 "params": params,
@@ -371,6 +378,15 @@ class Trainer:
 
         with self.mesh:
             return jax.jit(init_fn)(self._rng)
+
+    def _zero_skips(self) -> jax.Array:
+        """The consecutive-skip counter at zero, placed as the step returns
+        it (replicated over the mesh): a bare `jnp.zeros` has another type
+        to jit than the step's own output, and the step would trace and
+        compile a second time on its second call."""
+        return jax.device_put(
+            jnp.zeros((), jnp.int32), NamedSharding(self.mesh, P())
+        )
 
     @property
     def state(self) -> Dict[str, Any]:
@@ -796,7 +812,7 @@ class Trainer:
                 "sentinel wants a rollback (%s) but no checkpoint exists "
                 "yet; continuing with guarded params only", reason,
             )
-            self._skips = jnp.zeros((), jnp.int32)
+            self._skips = self._zero_skips()
             self._spike.reset()
             return None
         logger.warning(
@@ -819,7 +835,7 @@ class Trainer:
         # step stays consumed, which is exactly "skip the offending
         # batches". Recorded so checkpoints replay the same decision.
         self._data_offset = self._data_consumed - restored
-        self._skips = jnp.zeros((), jnp.int32)
+        self._skips = self._zero_skips()
         self._spike.reset()
         logger.warning(
             "sentinel rollback done: step %d, data stream fast-forwarded "
@@ -1058,7 +1074,7 @@ class Trainer:
         # and kill host/device overlap.
         step = self.steps_completed
         last_ckpt_step = -1
-        self._skips = jnp.zeros((), jnp.int32)
+        self._skips = self._zero_skips()
         self._sentinel_reason: Optional[str] = None
         last_div_audit = step
         # First progress beat (every rank): arms the master's gang stall

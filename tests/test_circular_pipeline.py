@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from determined_tpu.common.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from determined_tpu.parallel.mesh import MeshConfig, make_mesh

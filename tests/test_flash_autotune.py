@@ -138,23 +138,99 @@ def test_gpt_resolves_blocks_from_config():
     assert m._flash_blocks() is m._resolved_flash_blocks
 
 
-def test_all_probes_failing_not_cached(tmp_path, monkeypatch):
-    """Transient all-candidate probe failure returns the fallback but must
-    NOT pin it into the on-disk cache."""
+def test_all_probes_failing_raises_not_cached(tmp_path, monkeypatch):
+    """Every candidate failing is an error that carries the compiler's
+    message — never an untuned fallback — and pins nothing into the
+    on-disk cache."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     class _Dev:
         device_kind = "fake"
 
     monkeypatch.setattr(jax, "devices", lambda *a, **k: [_Dev()])
-    monkeypatch.setattr(fat, "_probe_ms", lambda *a, **k: float("inf"))
+
+    def refuse(*a, **k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel: no")
+
+    monkeypatch.setattr(fat, "_probe_ms", refuse)
     cache = tmp_path / "cache.json"
-    got = fat.tune_flash_blocks(
-        s_q=64, n_heads=2, head_dim=16, want_q=64, want_k=64,
-        cache_file=str(cache),
-    )
-    assert got == (64, 64)
+    with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+        fat.tune_flash_blocks(
+            s_q=64, n_heads=2, head_dim=16, want_q=64, want_k=64,
+            cache_file=str(cache),
+        )
     assert not cache.exists()
+
+
+def test_one_probe_failing_loses(tmp_path, monkeypatch):
+    """A single refused candidate (VMEM, shape) just loses."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    class _Dev:
+        device_kind = "fake"
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [_Dev()])
+
+    def probe(bq, bk, **kw):
+        if (bq, bk) == (256, 256):
+            return 1.0
+        raise RuntimeError("vmem")
+
+    monkeypatch.setattr(fat, "_probe_ms", probe)
+    cleared = []
+    monkeypatch.setattr(jax, "clear_caches", lambda: cleared.append(1))
+    got = fat.tune_flash_blocks(
+        s_q=1024, n_heads=2, head_dim=16, cache_file=str(tmp_path / "c.json"),
+    )
+    assert got == (256, 256)
+    # What the probes traced is forgotten, so that the programs compiled
+    # next get the persistent-cache keys of a process that never probed.
+    assert cleared == [1]
+
+
+def test_paged_tuner_raises_when_every_probe_fails(tmp_path, monkeypatch):
+    """The paged tuner offers only block_h the lowering admits, and when
+    the compiler refuses all of them it raises with that message instead
+    of handing the engine a kernel that cannot run."""
+    from determined_tpu.ops.paged_attention import paged_block_h_candidates
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    class _Dev:
+        device_kind = "fake"
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [_Dev()])
+    tried = []
+
+    def refuse(block_h, **kw):
+        tried.append(block_h)
+        raise RuntimeError("Mosaic failed to compile TPU kernel: no")
+
+    monkeypatch.setattr(fat, "_probe_paged_ms", refuse)
+    cache = tmp_path / "cache.json"
+    with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+        fat.tune_paged_block_h(
+            n_heads=12, head_dim=64, page_size=128, num_pages=129,
+            pages_per_slot=8, batch=8, q_rows=8, cache_file=str(cache),
+        )
+    # 12 x 64: a block's minor extent is block_h * 64 lanes — a multiple
+    # of 128, or all 768.
+    assert tried == [2, 4, 6, 12]
+    assert tried == paged_block_h_candidates(12, 64, 128, jnp.bfloat16)
+    assert not cache.exists()
+
+
+def test_autotune_cache_sits_in_the_checkout(monkeypatch):
+    import os
+
+    from determined_tpu.common import compile_cache
+
+    monkeypatch.delenv("DTPU_FLASH_TUNE_CACHE", raising=False)
+    assert fat.cache_path() == os.path.join(
+        compile_cache.cache_root(), "flash_blocks.json"
+    )
+    monkeypatch.setenv("DTPU_FLASH_TUNE_CACHE", "/elsewhere/t.json")
+    assert fat.cache_path() == "/elsewhere/t.json"
 
 
 def test_segments_mode_probes_and_keys_separately(tmp_path, monkeypatch):

@@ -119,3 +119,40 @@ def test_barrier_and_repeated_collectives():
     for res in out:
         for i, round_result in enumerate(res):
             assert round_result == [(r, i) for r in range(3)]
+
+
+def test_send_is_not_starved_by_the_receiver_loop():
+    """The receiver thread polls the socket under the same lock send()
+    needs. Python locks are not fair: a loop that re-takes the lock the
+    instant it drops it kept a waiting send() out for several poll
+    intervals in a quiet process and for minutes in a busy trial (a
+    2-process gang hung in its checkpoint gather). With the loop yielding
+    between polls a send waits for at most the poll in flight."""
+    import threading
+    import time
+
+    from determined_tpu.common import ipc
+
+    chief = ipc.ChiefServer(1)
+    box = {}
+    t = threading.Thread(
+        target=lambda: box.update(
+            w=ipc.WorkerClient(f"127.0.0.1:{chief.port}", 1)
+        )
+    )
+    t.start()
+    chief.accept(timeout_s=30)
+    t.join(timeout=30)
+    worker = box["w"]
+    try:
+        spent = 0.0
+        for i in range(40):
+            t0 = time.monotonic()
+            worker.send(i)
+            spent += time.monotonic() - t0
+            assert chief.gather(timeout_s=30) == [i]
+        # <= one 50 ms poll each (2 s in all); the unfair loop took 3.7-5 s.
+        assert spent < 3.0, f"40 sends waited {spent:.2f} s on the lock"
+    finally:
+        worker.close()
+        chief.close()

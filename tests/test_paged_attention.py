@@ -251,7 +251,10 @@ class TestParityGrid:
         o8 = np.asarray(paged_attention(
             q8, kp, vp, pt, lengths, active, interpret=True
         ))
-        assert np.array_equal(o1[:, 0], o8[:, 0])
+        # Not bitwise: one query row is a matrix-vector product, eight a
+        # matrix-matrix one, and the CPU interpreter's two routines need
+        # not sum in the same order.
+        np.testing.assert_allclose(o1[:, 0], o8[:, 0], rtol=1e-6, atol=1e-7)
 
 
 class TestFragmentation:

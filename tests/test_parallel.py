@@ -155,7 +155,7 @@ def test_ulysses_matches_dense(devices8, causal):
 
 
 def test_pipeline_matches_sequential(devices8):
-    from determined_tpu.common.jaxcompat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_stages, n_micro, mb, dim = 4, 8, 2, 16

@@ -151,16 +151,57 @@ class TestAgentRestartReattach:
 
 class TestReattachUnits:
     def test_detect_slots_refuses_broken_runtime(self, monkeypatch):
-        import jax
+        from determined_tpu.agent import agent as agent_mod
 
-        def boom():
-            raise RuntimeError("TPU runtime wedged")
-
-        monkeypatch.setattr(jax, "local_devices", boom)
-        with pytest.raises(SlotDetectionError):
+        monkeypatch.setattr(
+            agent_mod, "_DETECT_SCRIPT",
+            "raise RuntimeError('TPU runtime wedged')",
+        )
+        with pytest.raises(SlotDetectionError, match="TPU runtime wedged"):
+            detect_slots("auto")
+        # No accelerator stack to import is the same refusal, not "1 slot".
+        monkeypatch.setattr(
+            agent_mod, "_DETECT_SCRIPT", "import no_such_accelerator_stack"
+        )
+        with pytest.raises(SlotDetectionError, match="no_such_accelerator"):
             detect_slots("auto")
         # Explicit counts never touch the runtime.
         assert detect_slots(4) == 4
+
+    def test_auto_detection_leaves_the_agent_off_the_chip(self):
+        """A chip belongs to one process at a time, and the tasks the
+        agent spawns are the ones that need it: `auto` detection must
+        learn the devices without this process initialising a backend.
+        Run in a fresh interpreter — the test process itself has long
+        since touched jax."""
+        import json
+        import os
+        import subprocess
+        import sys
+
+        code = (
+            "import json, sys\n"
+            "from determined_tpu.agent.agent import detect_devices\n"
+            "devs = detect_devices('auto')\n"
+            "backends = None\n"
+            "if 'jax' in sys.modules:\n"
+            "    from jax._src import xla_bridge\n"
+            "    backends = sorted(xla_bridge._backends)\n"
+            "print(json.dumps({'devices': devs, 'jax_imported': 'jax' in "
+            "sys.modules, 'backends': backends}))\n"
+        )
+        out = subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            timeout=300, check=True,
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ).stdout
+        got = json.loads(out.strip().splitlines()[-1])
+        assert got["devices"] and got["devices"][0]["platform"] == "cpu"
+        assert [d["id"] for d in got["devices"]] == list(
+            range(len(got["devices"]))
+        )
+        # Stronger than "no backend": the agent never even imports jax.
+        assert got["jax_imported"] is False and got["backends"] is None
 
     def test_detect_devices_and_registration_model(self):
         """Per-slot device model rides registration to the master's agent

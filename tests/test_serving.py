@@ -511,8 +511,11 @@ class TestServingFaultDrills:
                 raise RuntimeError("synthetic device failure")
             return real_decode(*args, **kwargs)
 
-        eng._decode_fn = flaky_decode
+        # After start(): its warm-up compiles by calling the real step,
+        # and a failure THERE is fatal by design — this drill is about a
+        # fault at run time.
         eng.start()
+        eng._decode_fn = flaky_decode
         try:
             req = eng.submit([4, 5, 6], max_new_tokens=10)
             events = list(req.stream(timeout=180))

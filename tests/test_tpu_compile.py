@@ -1,0 +1,163 @@
+"""The main path's Pallas kernels, compiled by the real TPU compiler.
+
+Every other kernel test runs in Pallas interpret mode on the CPU, which
+accepts programs the chip's compiler refuses (a block shape off the
+(8, 128) tile, an unsupported vector layout, too much VMEM). The TPU
+compiler is installed here and compiles for a chip that is *described*
+and not attached (`v5e:2x2`), so these cases guard every later change to
+a kernel at no chip time. Nothing runs: a pass says the compiler accepts
+the kernel at this shape, not that its numbers are right — the
+interpret-mode parity suites and `chip_smoke.py` say that.
+"""
+import functools
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from determined_tpu.ops.paged_attention import paged_attention
+
+# The module, not the function `determined_tpu.ops` re-exports under its name.
+fa = importlib.import_module("determined_tpu.ops.flash_attention")
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip; the module is skipped where the topology
+    cannot be described (no TPU compiler in the installation)."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any refusal means "not here"
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _for_the_chip(monkeypatch):
+    """Take the kernels' TPU branch (the process's own backend is the
+    CPU), with the persistent compile cache off: an entry compiled for a
+    described chip is written but can never be read back here, and the
+    next compile would only warn about it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(fa, "_use_pallas", lambda: True)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _flash_train(s):
+    """Forward + all three gradients at batch 1 x 12 heads x 64 (1k rides
+    the GPT-2 bench batch of 24 instead: BH 288)."""
+    b = 24 if s == 1024 else 1
+    qkv = [((b, s, 12, 64), BF16)] * 3
+
+    def step(q, k, v):
+        def loss(q, k, v):
+            o = fa.flash_attention(
+                q, k, v, causal=True, block_q=1024, block_k=1024
+            )
+            return jnp.sum(o.astype(jnp.float32))
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    return step, qkv
+
+
+def _packed_prefill():
+    """serving prefill: 4 rows x 512 packed prompts, segment ids."""
+    def step(q, k, v, seg):
+        return fa.flash_attention(
+            q, k, v, causal=True, block_q=512, block_k=512, segment_ids=seg
+        )
+
+    return step, [((4, 512, 12, 64), BF16)] * 3 + [((4, 512), jnp.int32)]
+
+
+def _cached_prefill():
+    """prefix-cache hit: 512 tail tokens attend through 1024 cached ones
+    at `kv_offset` (the GPT.prefill_kv_cached geometry)."""
+    def step(q, k, v, seg, kv_seg):
+        return fa.flash_attention(
+            q, k, v, causal=True, kv_offset=1024, block_q=512,
+            block_k=fa.fit_block(1536, 1024), segment_ids=seg,
+            kv_segment_ids=kv_seg,
+        )
+
+    return step, [
+        ((4, 512, 12, 64), BF16), ((4, 1536, 12, 64), BF16),
+        ((4, 1536, 12, 64), BF16), ((4, 512), jnp.int32),
+        ((4, 1536), jnp.int32),
+    ]
+
+
+def _gather_decode():
+    """the engine's gather fallback: 8 padded query rows against the
+    whole 1024-token window, bottom-aligned."""
+    def step(q, k, v, seg, kv_seg):
+        return fa.flash_attention(
+            q, k, v, causal=True, kv_offset=1023, block_q=8, block_k=1024,
+            segment_ids=seg, kv_segment_ids=kv_seg,
+        )
+
+    return step, [
+        ((8, 8, 12, 64), BF16), ((8, 1024, 12, 64), BF16),
+        ((8, 1024, 12, 64), BF16), ((8, 8), jnp.int32),
+        ((8, 1024), jnp.int32),
+    ]
+
+
+def _paged_decode(n_heads, head_dim, block_h=None):
+    """bench.py's serving pool (129 pages x 128, batch 8, 8 pages a slot,
+    8 query rows) with `q_lens` given — the speculative-verify call."""
+    step = functools.partial(paged_attention, block_h=block_h)
+    pool = ((129, 128, n_heads, head_dim), BF16)
+    slot = ((8,), jnp.int32)
+
+    def call(q, kp, vp, pt, lengths, active, q_lens):
+        return step(q, kp, vp, pt, lengths, active, q_lens=q_lens)
+
+    return call, [
+        ((8, 8, n_heads, head_dim), BF16), pool, pool,
+        ((8, 8), jnp.int32), slot, slot, slot,
+    ]
+
+
+CASES = {
+    "flash_train_1k_mono": lambda: _flash_train(1024),
+    "flash_train_16k_fused_blocked": lambda: _flash_train(16384),
+    "flash_train_32k_split": lambda: _flash_train(32768),
+    "packed_prefill_4x512_segments": _packed_prefill,
+    "cached_prefill_kv_offset": _cached_prefill,
+    "gather_decode_q8_s1024": _gather_decode,
+    "paged_decode_12x64": lambda: _paged_decode(12, 64),
+    "paged_decode_12x64_block_h_2": lambda: _paged_decode(12, 64, 2),
+    "paged_decode_16x128": lambda: _paged_decode(16, 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(chip, case):
+    step, shapes = CASES[case]()
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+        for shape, dtype in shapes
+    ]
+    compiled = jax.jit(step).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), (
+        "compiled without a Mosaic kernel: the reference path was taken"
+    )
