@@ -104,31 +104,30 @@ SAMPLER_OVERHEAD = METRICS.gauge(
 _thread_phase: Dict[int, str] = {}
 
 
-def set_phase(name: Optional[str]) -> None:
+def set_phase(name: Optional[str]) -> Optional[str]:
     """Mark the CALLING thread's current timeline phase for the sampler
     (data_wait / h2d_put / report / checkpoint; None clears → samples
-    fall back to the 'step' residual like the timeline itself). One dict
-    store — cheap enough for the trainer hot loop."""
+    fall back to the 'step' residual like the timeline itself) and return
+    the tag it replaces, for whoever restores it (`phase` below,
+    `Timeline.phase`). Two dict operations — cheap enough for the trainer
+    hot loop."""
     ident = threading.get_ident()
+    prev = _thread_phase.get(ident)
     if name is None:
         _thread_phase.pop(ident, None)
     else:
         _thread_phase[ident] = name
+    return prev
 
 
 @contextlib.contextmanager
 def phase(name: str) -> Iterator[None]:
-    """Phase-mark a block (trainer data_wait/h2d_put/checkpoint sites)."""
-    ident = threading.get_ident()
-    prev = _thread_phase.get(ident)
-    _thread_phase[ident] = name
+    """Phase-mark a block; the previous tag comes back at its end."""
+    prev = set_phase(name)
     try:
         yield
     finally:
-        if prev is not None:
-            _thread_phase[ident] = prev
-        else:
-            _thread_phase.pop(ident, None)
+        set_phase(prev)
 
 
 def _env_float(name: str, default: float) -> float:

@@ -26,6 +26,13 @@ Params = Any
 Batch = Any
 Metrics = Dict[str, jax.Array]
 
+#: The `jax.named_scope`s a train step opens, each once where its work is
+#: written (`models/gpt.py`: `_embed`, `_attn_half`, `_mlp_half`, `_head` and
+#: the loss after it; `Trainer._build_step_fn`: the update), so a profiler
+#: capture splits the step's device time by them whatever the layer loop,
+#: the remat policy or the mesh. `benchmark/scopes.json` holds the same names.
+STEP_SCOPES = ("embed", "attn", "mlp", "head_loss", "optimizer")
+
 
 class Model(abc.ABC):
     @abc.abstractmethod

@@ -399,16 +399,22 @@ class GPT(Model):
         self, x: jax.Array, blk: Dict[str, jax.Array], *, manual: bool = False,
         segment_ids: Optional[jax.Array] = None,
     ) -> jax.Array:
+        """The `attn` scope is opened around the projections and closed
+        around the attention call between them: a Pallas kernel's name in
+        a trace is the innermost component of its name stack, and the
+        flash kernels keep the ones they have under no scope
+        (`ops/flash_attention.py`)."""
         c = self.config
         block_q, block_k = self._flash_blocks()
         act_spec = P(("data", "fsdp"), "context", None)
 
-        h = _layernorm(x, blk["ln1_scale"], blk["ln1_bias"])
-        qkv = (
-            jnp.einsum("bsd,dthk->bsthk", h, blk["wqkv"].astype(c.dtype))
-            + blk["bqkv"].astype(c.dtype)
-        )
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        with jax.named_scope("attn"):
+            h = _layernorm(x, blk["ln1_scale"], blk["ln1_bias"])
+            qkv = (
+                jnp.einsum("bsd,dthk->bsthk", h, blk["wqkv"].astype(c.dtype))
+                + blk["bqkv"].astype(c.dtype)
+            )
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         if manual:
             ctx = (
                 self.mesh.shape.get("context", 1)
@@ -479,13 +485,15 @@ class GPT(Model):
                 layout=c.sequence_layout, window=c.attn_window,
                 segment_ids=segment_ids,
             )
-        o = jnp.einsum("bshk,hkd->bsd", o, blk["wo"].astype(c.dtype))
-        o = o + blk["bo"].astype(c.dtype)
-        x = x + o
-        if not manual:
-            x = self._constrain(x, act_spec)
+        with jax.named_scope("attn"):
+            o = jnp.einsum("bshk,hkd->bsd", o, blk["wo"].astype(c.dtype))
+            o = o + blk["bo"].astype(c.dtype)
+            x = x + o
+            if not manual:
+                x = self._constrain(x, act_spec)
         return x
 
+    @jax.named_scope("mlp")
     def _mlp_half(
         self, x: jax.Array, blk: Dict[str, jax.Array], *, manual: bool = False
     ) -> Tuple[jax.Array, jax.Array]:
@@ -577,6 +585,7 @@ class GPT(Model):
 
         return stage_fn
 
+    @jax.named_scope("embed")
     def _embed(
         self,
         params: Dict[str, Any],
@@ -599,6 +608,7 @@ class GPT(Model):
         x = self._embed_raw(table, pos, tokens, positions)
         return self._constrain(x, P(("data", "fsdp"), "context", None))
 
+    @jax.named_scope("head_loss")
     def _head(self, params: Dict[str, Any], x: jax.Array) -> jax.Array:
         c = self.config
         w_out = (
@@ -1377,11 +1387,13 @@ class GPT(Model):
 
         stage_fn = self._stage_scan_fn()
 
+        @jax.named_scope("embed")
         def emb_fn(ep, tok, pos):
             return self._embed_raw(
                 ep["tok_embed"], ep["pos_embed"], tok, pos
             ).astype(jnp.float32)
 
+        @jax.named_scope("head_loss")
         def loss_fn(lp, y, tok, msk):
             """Per-microbatch SUM objective + [nll, z, acc, n] sums —
             the same _head_raw + sums math as the GSPMD path. In aligned
@@ -1563,27 +1575,28 @@ class GPT(Model):
                 params, tokens, targets, positions, mask, segment_ids
             )
         logits, moe_aux = self._forward(params, tokens, positions, segment_ids)
-        if targets is not None:
-            # Pre-shifted batch (zigzag-layout pipelines, data/tokens.py):
-            # position i already predicts targets[i] — no in-model shift.
-            nll_sum, z_sum, acc_sum, n_tok = self._aligned_token_sums(
-                logits.astype(jnp.float32), targets, mask
-            )
-        else:
-            # Next-token prediction: position i predicts token i+1 (shift
-            # + per-token sums shared with 1F1B via _aligned_token_sums).
-            nll_sum, z_sum, acc_sum, n_tok = self._next_token_sums(
-                logits.astype(jnp.float32), tokens, mask
-            )
-        n = jnp.maximum(n_tok, 1.0)
-        loss = nll_sum / n
-        if self.config.z_loss:
-            loss = loss + self.config.z_loss * z_sum / n
-        if self.config.n_experts:
-            # 0.01 is the standard switch-transformer aux weight; mean over
-            # layers (aux accumulated once per block in the scan).
-            loss = loss + 0.01 * moe_aux / self.config.n_layers
-        acc = acc_sum / n
+        with jax.named_scope("head_loss"):
+            if targets is not None:
+                # Pre-shifted batch (zigzag-layout pipelines, data/tokens.py):
+                # position i already predicts targets[i] — no in-model shift.
+                nll_sum, z_sum, acc_sum, n_tok = self._aligned_token_sums(
+                    logits.astype(jnp.float32), targets, mask
+                )
+            else:
+                # Next-token prediction: position i predicts token i+1 (shift
+                # + per-token sums shared with 1F1B via _aligned_token_sums).
+                nll_sum, z_sum, acc_sum, n_tok = self._next_token_sums(
+                    logits.astype(jnp.float32), tokens, mask
+                )
+            n = jnp.maximum(n_tok, 1.0)
+            loss = nll_sum / n
+            if self.config.z_loss:
+                loss = loss + self.config.z_loss * z_sum / n
+            if self.config.n_experts:
+                # 0.01 is the standard switch-transformer aux weight; mean
+                # over layers (aux accumulated once per block in the scan).
+                loss = loss + 0.01 * moe_aux / self.config.n_layers
+            acc = acc_sum / n
         return loss, {"loss": loss, "accuracy": acc, "tokens": n_tok}
 
     def _loss_fused(
@@ -1600,21 +1613,22 @@ class GPT(Model):
         x, _moe_aux = self._forward_trunk(
             params, tokens, positions, segment_ids
         )
-        hidden = _layernorm(x, params["lnf_scale"], params["lnf_bias"])
-        w_out = (
-            params["tok_embed"].T if c.tie_embeddings else params["head"]
-        ).astype(c.dtype)
-        if targets is None:
-            # classic in-model shift: position i predicts token i+1
-            hidden = hidden[:, :-1]
-            targets = tokens[:, 1:]
-            mask = mask[:, 1:]
-        obj, _nll, _z, acc_sum, n_tok = fused_next_token_sums(
-            hidden, w_out, targets, mask, z_loss=c.z_loss or 0.0,
-        )
-        n = jnp.maximum(n_tok, 1.0)
-        loss = obj / n
-        acc = acc_sum / n
+        with jax.named_scope("head_loss"):
+            hidden = _layernorm(x, params["lnf_scale"], params["lnf_bias"])
+            w_out = (
+                params["tok_embed"].T if c.tie_embeddings else params["head"]
+            ).astype(c.dtype)
+            if targets is None:
+                # classic in-model shift: position i predicts token i+1
+                hidden = hidden[:, :-1]
+                targets = tokens[:, 1:]
+                mask = mask[:, 1:]
+            obj, _nll, _z, acc_sum, n_tok = fused_next_token_sums(
+                hidden, w_out, targets, mask, z_loss=c.z_loss or 0.0,
+            )
+            n = jnp.maximum(n_tok, 1.0)
+            loss = obj / n
+            acc = acc_sum / n
         return loss, {"loss": loss, "accuracy": acc, "tokens": n_tok}
 
     def eval_metrics(self, params: Dict[str, Any], batch: Dict[str, jax.Array]) -> Metrics:

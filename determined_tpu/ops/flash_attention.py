@@ -71,6 +71,14 @@ pl = _LazyPallas()
 
 NEG_INF = float(-1e30)  # finite mask value; true -inf breaks m-subtraction
 
+# No `pallas_call` below passes `name=`, and callers open no
+# `jax.named_scope` around them: XLA names a Mosaic kernel's operation after
+# the innermost component of jax's name stack, and the names that gives with
+# none (`jvp__`, `transpose_jvp___`, `shard_map`) are what
+# `benchmark/kernels/flash_*.json` find these kernels by in a trace. Forward
+# and backward are told apart there by the `transpose(...)` around the
+# backward's name stack. Name them once those files can follow.
+
 
 def fit_block(seq: int, want: int) -> int:
     """Largest block size ≤ `want` dividing `seq` (the kernel requires

@@ -74,6 +74,9 @@ pl = _LazyPallas()
 #: mid-decode Mosaic shape failure.
 LANE_GRANULE = 128
 
+#: The kernel's name in a profiler capture (`name=` on the `pallas_call`).
+PAGED_ATTN = "dtpu_paged_attn"
+
 #: VMEM budget for one grid step's resident K+V page group (bytes).
 #: Conservative: q/out/softmax scratch ride alongside in ~16 MB of VMEM.
 _PAGE_GROUP_VMEM_CAP = 4 * 1024 * 1024
@@ -343,6 +346,7 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, q_rows, folded), k_pool.dtype),
         interpret=interpret,
+        name=PAGED_ATTN,
     )(
         page_table.astype(jnp.int32),
         lengths.astype(jnp.int32),

@@ -30,6 +30,14 @@ Ledger semantics:
 
 Kill switch: ``DTPU_TIMELINE=0`` (bench.py measures the instrumentation
 overhead against it; acceptance < 1% of step time).
+
+`Timeline.phase(name)` is the one way the trainer marks a phase: it tags
+the thread for the sampling profiler, opens a `TraceAnnotation`
+``dtpu.trainer.<name>`` (a host span on the profiler's clock, so a
+capture shows whether the device waited inside it; free when no capture
+runs, and not switched off by the kill switch) and adds the elapsed time
+to the window. ``report`` has two children, ``report.sync`` (the
+boundary's `device_get`s) and ``report.publish`` (the metric reports).
 """
 from __future__ import annotations
 
@@ -37,9 +45,42 @@ import os
 import time
 from typing import Any, Dict, Optional
 
+from jax.profiler import TraceAnnotation
+
+from determined_tpu.common.profiling import set_phase
+
 #: Window phases the host measures directly; "step" is the residual.
 PHASES = ("data_wait", "h2d_put", "report", "checkpoint")
 ALL_PHASES = PHASES + ("step",)
+#: Prefix of the phases' host spans in a profiler capture.
+SPAN_PREFIX = "dtpu.trainer."
+
+
+class _Phase:
+    """One entry into a phase (see `Timeline.phase`). A class and not a
+    generator: the hot loop enters two a step."""
+
+    __slots__ = ("_timeline", "_key", "_timed", "_span", "_prev", "_t0")
+
+    def __init__(self, timeline: "Timeline", name: str, timed: bool) -> None:
+        self._timeline = timeline
+        # `report.sync` is tagged and accumulated as `report`.
+        self._key = name.partition(".")[0]
+        self._timed = timed and timeline.enabled
+        self._span = TraceAnnotation(SPAN_PREFIX + name)
+
+    def __enter__(self) -> None:
+        self._prev = set_phase(self._key)
+        self._span.__enter__()
+        if self._timed:
+            self._t0 = self._timeline.pc()
+
+    def __exit__(self, *exc: Any) -> None:
+        if self._timed:
+            timeline = self._timeline
+            timeline.window[self._key] += timeline.pc() - self._t0
+        self._span.__exit__(*exc)
+        set_phase(self._prev)
 
 
 class Timeline:
@@ -72,6 +113,14 @@ class Timeline:
         self.uncommitted_s = 0.0
 
     # -- window -------------------------------------------------------------
+    def phase(self, name: str, timed: bool = True) -> _Phase:
+        """Context manager around one phase of the loop (a name of
+        `PHASES`, or a child `<phase>.<part>`): the sampler's tag, the
+        host span, and — unless the site passes ``timed=False`` or the
+        timeline is disabled — the elapsed time into ``window[<phase>]``.
+        The previous tag comes back at exit, so phases nest."""
+        return _Phase(self, name, timed)
+
     def reset_window(self) -> None:
         for p in PHASES:
             self.window[p] = 0.0

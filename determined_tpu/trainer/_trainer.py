@@ -429,28 +429,30 @@ class Trainer:
             (loss, metrics), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(state["params"])
-            updates, new_opt = self._tx.update(
-                grads, state["opt_state"], state["params"]
-            )
-            new_params = jax.tree.map(
-                lambda p, u: (p + u.astype(p.dtype)), state["params"], updates
-            )
-            new_params = jax.lax.with_sharding_constraint(
-                new_params, param_shardings
-            )
-            gnorm = optax_global_norm(grads)
-            new_state = {
-                "step": state["step"] + 1,
-                "params": new_params,
-                "opt_state": new_opt,
-            }
-            # Non-finite guard, in-graph: a NaN/inf loss or grad norm
-            # keeps the old params/optimizer (only the step advances) and
-            # bumps the consecutive-skip counter. The counters ride the
-            # device-resident metrics buffer — no host sync here.
-            new_state, ok, skips_out = _sentinel.guarded_update(
-                state, new_state, loss, gnorm, skips
-            )
+            with jax.named_scope("optimizer"):
+                updates, new_opt = self._tx.update(
+                    grads, state["opt_state"], state["params"]
+                )
+                new_params = jax.tree.map(
+                    lambda p, u: (p + u.astype(p.dtype)),
+                    state["params"], updates,
+                )
+                new_params = jax.lax.with_sharding_constraint(
+                    new_params, param_shardings
+                )
+                gnorm = optax_global_norm(grads)
+                new_state = {
+                    "step": state["step"] + 1,
+                    "params": new_params,
+                    "opt_state": new_opt,
+                }
+                # Non-finite guard, in-graph: a NaN/inf loss or grad norm
+                # keeps the old params/optimizer (only the step advances)
+                # and bumps the consecutive-skip counter. The counters ride
+                # the device-resident metrics buffer — no host sync here.
+                new_state, ok, skips_out = _sentinel.guarded_update(
+                    state, new_state, loss, gnorm, skips
+                )
             metrics = dict(
                 metrics,
                 grad_norm=gnorm,
@@ -995,34 +997,39 @@ class Trainer:
 
         timeline = self.timeline
 
-        # Continuous-profiling phase tag: the sampler (common/profiling.py)
-        # reads this thread's phase on every walk, so flamegraphs split by
-        # data_wait / h2d_put / step / report / checkpoint for free.
-        _set_phase = profiling_mod.set_phase
-
         def flush_report() -> None:
+            # One `report` phase (trainer/_timeline.py) around the whole
+            # boundary, untimed: only the publishing calls add to the
+            # window, the syncs before them are device time and stay in
+            # the `step` residual. Nothing pending (a flush right after a
+            # boundary's own): nothing to do, and no empty span.
+            if pending:
+                with timeline.phase("report", timed=False):
+                    _flush_report()
+
+        def _flush_report() -> None:
             nonlocal pending, t_report
-            _set_phase("report")
             # Sentinel sees EVERY window before it is dropped — flushes
             # also happen at checkpoint/preemption/op-end boundaries that
             # are not report boundaries, and a spike (or skip count) in
             # such a window must not vanish unchecked. The verdict is
             # latched and consumed at the next boundary's rollback gate.
-            if pending:
+            with timeline.phase("report.sync", timed=False):
                 reason = self._sentinel_check(pending)
                 if reason and self._sentinel_reason is None:
                     self._sentinel_reason = reason
-            had_pending = bool(pending)
-            if not pending or not self.core.distributed.is_chief:
+                host = (
+                    [jax.device_get(m) for m in pending]
+                    if self.core.distributed.is_chief else []
+                )
+            if not host:
                 pending = []
-                if had_pending and timeline.enabled:
+                if timeline.enabled:
                     # _sentinel_check just blocked on the device, so the
                     # window residual includes the jitted steps — the one
                     # sync the timeline is allowed to piggyback on.
                     timeline.close_window()
-                _set_phase("step")
                 return
-            host = [jax.device_get(m) for m in pending]
             # Aggregate over FINITE values only: a guarded (skipped) step
             # leaves NaN in loss/grad_norm, and a NaN mean would both
             # poison the metric history and break the metrics POST (NaN
@@ -1047,11 +1054,10 @@ class Trainer:
             agg["steps_skipped"] = float(self._steps_skipped)
             agg["rollbacks"] = float(self._rollbacks)
             steps_now = self.steps_completed
-            _t0 = timeline.pc()
-            self.core.train.report_training_metrics(steps_now, agg)
-            self._tb_scalars(steps_now, agg)
+            with timeline.phase("report.publish"):
+                self.core.train.report_training_metrics(steps_now, agg)
+                self._tb_scalars(steps_now, agg)
             if timeline.enabled:
-                timeline.window["report"] += timeline.pc() - _t0
                 # Settle the window (the device_get above was the sync),
                 # then ship the step-phase breakdown + goodput ledger
                 # under the `profiling` group — the same channel the
@@ -1062,12 +1068,14 @@ class Trainer:
                     # XLA's per-step model FLOPs (cost_analysis of the
                     # compiled step) → master's dtpu_step_flops gauge.
                     prof["step_flops"] = self._step_flops
-                self.core.train.report_metrics("profiling", steps_now, prof)
+                with timeline.phase("report.publish", timed=False):
+                    self.core.train.report_metrics(
+                        "profiling", steps_now, prof
+                    )
             if self._profiler is not None:
                 self._profiler.set_steps_completed(steps_now)
             pending = []
             t_report = time.time()
-            _set_phase("step")
 
         # Host-side step counter: one device sync here, none in the loop —
         # reading state["step"] per batch would block on the in-flight step
@@ -1099,11 +1107,12 @@ class Trainer:
         _first_step_ctx = trace_mod.current()
         _first_step_t0 = time.time()
         _first_step_at = step + 1
-        # Host-phase clock bound once: the hot loop pays 3 perf_counter
-        # calls + 2 float adds per step when enabled, nothing when not.
-        _pc = timeline.pc
         timeline.reset_window()
-        _set_phase("step")
+        # Continuous-profiling phase tag: the sampler (common/profiling.py)
+        # reads this thread's phase on every walk, so flamegraphs split by
+        # data_wait / h2d_put / step / report / checkpoint for free. Every
+        # `timeline.phase` below puts "step" back when it ends.
+        profiling_mod.set_phase("step")
 
         # The finally-join below keeps a raising step loop from abandoning
         # an in-flight background save: the daemon writer thread would
@@ -1115,24 +1124,11 @@ class Trainer:
             for op in searcher.operations():
                 target = to_batches(op.length, bpe)
                 while step < target:
-                    if timeline.enabled:
-                        _set_phase("data_wait")
-                        _t0 = _pc()
+                    with timeline.phase("data_wait"):
                         raw = next(train_iter)
-                        _t1 = _pc()
-                        _set_phase("h2d_put")
+                    with timeline.phase("h2d_put"):
                         batch = self._put_batch(raw)
-                        _set_phase("step")
-                        _w = timeline.window
-                        _w["data_wait"] += _t1 - _t0
-                        _w["h2d_put"] += _pc() - _t1
-                        timeline.step_done()
-                    else:
-                        _set_phase("data_wait")
-                        raw = next(train_iter)
-                        _set_phase("h2d_put")
-                        batch = self._put_batch(raw)
-                        _set_phase("step")
+                    timeline.step_done()
                     self._data_consumed += 1
                     # poison: 1.0 outside fault drills (one None check);
                     # np scalar, not python float, so jit sees a stable
@@ -1213,9 +1209,8 @@ class Trainer:
                             self._exit_for_resize(directive, step)
                         if preempt_now:
                             flush_report()
-                            _set_phase("checkpoint")
-                            self._save_checkpoint(sync=True)
-                            _set_phase("step")
+                            with timeline.phase("checkpoint", timed=False):
+                                self._save_checkpoint(sync=True)
                             timeline.commit()
                             last_ckpt_step = step
                             logger.info(
@@ -1245,14 +1240,10 @@ class Trainer:
                             self._tb_scalars(step, last_val, prefix="val_")
                     if ckpt_period and step % ckpt_period == 0:
                         flush_report()
-                        _set_phase("checkpoint")
-                        _t0 = _pc()
-                        self._save_checkpoint()
-                        _set_phase("step")
-                        if timeline.enabled:
-                            # Host-blocking part only (snapshot + writer
-                            # join); the async upload overlaps training.
-                            timeline.window["checkpoint"] += _pc() - _t0
+                        # Host-blocking part only (snapshot + writer
+                        # join); the async upload overlaps training.
+                        with timeline.phase("checkpoint"):
+                            self._save_checkpoint()
                         # A durable checkpoint is the ledger's commit
                         # point: time since the last one is now goodput.
                         timeline.commit()
@@ -1283,8 +1274,8 @@ class Trainer:
                 (ckpt_period or preempted or self.core.info is not None)
                 and last_ckpt_step != step
             ):
-                _set_phase("checkpoint")
-                self._save_checkpoint(sync=True)
+                with timeline.phase("checkpoint", timed=False):
+                    self._save_checkpoint(sync=True)
                 timeline.commit()
         except BaseException as e:
             fit_error = e
@@ -1299,7 +1290,7 @@ class Trainer:
                 # checkpoint one rather than masking it.
                 logger.exception("background checkpoint failed during teardown")
             finally:
-                _set_phase(None)
+                profiling_mod.set_phase(None)
                 if self._capture_dir is not None:
                     # Abandoned mid-capture exit: stop + report so the
                     # master's capture record does not stay "delivered".

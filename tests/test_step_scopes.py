@@ -1,0 +1,296 @@
+"""The names a profiler capture shows (ISSUE 24): `STEP_SCOPES` on the
+train step, the Pallas kernels' names (`name=` on the paged kernel; the
+flash kernels keep the names jax gives them, which
+`benchmark/kernels/flash_*.json` match: `tests/test_tpu_compile.py`
+holds those), the trainer's phases as `dtpu.trainer.*` host spans
+through `Timeline.phase`, and the benchmark's own copy of those names
+(`benchmark/scopes.json`, `benchmark/kernels/dtpu_paged_attn.json`),
+which it keeps as data because it imports nothing of the program."""
+import dataclasses
+import glob
+import importlib
+import json
+import os
+import re
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from benchmark import scope_reduce
+from determined_tpu import core
+from determined_tpu.common import profiling
+from determined_tpu.models import gpt as gpt_mod
+from determined_tpu.models.base import STEP_SCOPES
+from determined_tpu.trainer import Batch, JAXTrial, Trainer
+from determined_tpu.trainer import _timeline
+from determined_tpu.trainer._timeline import Timeline
+
+# `determined_tpu.ops.flash_attention` / `.paged_attention` name the
+# functions: the modules come through importlib (as in
+# `benchmark/tools/size_cells.py`).
+fa = importlib.import_module("determined_tpu.ops.flash_attention")
+paged = importlib.import_module("determined_tpu.ops.paged_attention")
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+class _GPTTrial(JAXTrial):
+    def __init__(self, **config):
+        super().__init__()
+        self._config = config
+
+    def build_model(self, mesh):
+        return gpt_mod.GPT(
+            dataclasses.replace(gpt_mod.tiny(), **self._config), mesh=mesh)
+
+    def build_optimizer(self):
+        return optax.chain(optax.clip_by_global_norm(1.0), optax.adamw(1e-3))
+
+    def build_training_data(self):
+        rng = np.random.default_rng(0)
+        while True:
+            yield {"tokens": rng.integers(0, 256, (8, 128)).astype(np.int32)}
+
+
+def _dummy_core(tmp_path):
+    return core._context._dummy_init(checkpoint_storage=str(tmp_path))
+
+
+# -- scopes on the train step -----------------------------------------------
+@pytest.mark.parametrize("config", [
+    {"layer_loop": "scan", "remat": True},
+    {"layer_loop": "unroll", "remat": True},
+    {"layer_loop": "scan", "fused_loss": True},
+], ids=["scan", "unroll", "fused-loss"])
+def test_lowered_train_step_holds_every_scope(tmp_path, config):
+    """Forward, backward (`transpose(jvp(attn))`) and recomputed work
+    (`checkpoint/rematted_computation/mlp`) all carry their scope, and
+    the benchmark's peeling (`scope_reduce.scope_of`) finds each."""
+    trainer = Trainer(_GPTTrial(**config), _dummy_core(tmp_path))
+    step_fn = trainer._build_step_fn()
+    batch = trainer._put_batch(next(trainer.trial.build_training_data()))
+    text = step_fn.lower(
+        trainer.state, batch, np.float32(1.0), trainer._zero_skips()
+    ).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    found = {scope_reduce.scope_of(n, STEP_SCOPES) for n in names}
+    assert found >= set(STEP_SCOPES), sorted(found)
+    # backward and recomputed work are under their scope too (a scanned
+    # body's locations start at the body: the wrappers are on the `while`)
+    assert any("transpose(jvp(" in n
+               and scope_reduce.scope_of(n, STEP_SCOPES)
+               == ("attn" if config["layer_loop"] == "unroll" else "head_loss")
+               for n in names)
+    if config.get("remat"):
+        assert any("rematted_computation" in n
+                   and scope_reduce.scope_of(n, STEP_SCOPES) == "mlp"
+                   for n in names)
+
+
+def test_scope_is_a_whole_component_of_the_name_stack():
+    of = lambda n: scope_reduce.scope_of(n, STEP_SCOPES)  # noqa: E731
+    assert of("jit(train_step)/jvp(attn)/bsd,dthk->bsthk/dot_general") == "attn"
+    assert of("jit(train_step)/transpose(jvp())/while/body/closed_call/attn/"
+              "bshk,hkd->bsd/dot_general") == "attn"
+    # the flash kernels sit under no scope (`GPT._attn_half`)
+    assert of("jit(train_step)/transpose(jvp())/while/body/closed_call/"
+              "shard_map/pallas_call") is None
+    assert of("jit(train_step)/transpose(jvp(jvp()))/checkpoint/"
+              "rematted_computation/mlp/tanh") == "mlp"
+    assert of("jit(train_step)/jvp(mlp)/_moe_mlp/dot_general") == "mlp"
+    assert of("jit(train_step)/jvp(_moe_mlp)/dot_general") is None
+    assert of("jit(train_step)/optimizer/mul") == "optimizer"
+    assert of("jit(train_step)/my_optimizer/mul") is None
+    assert of("jit(train_step)/jvp(head_loss)/reduce_max") == "head_loss"
+    assert of("") is None
+
+
+# -- names on the Pallas kernels --------------------------------------------
+def _pallas_names(fn, *args):
+    """The `name` of every `pallas_call` in fn's jaxpr (sub-jaxprs too)."""
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return names
+
+
+def test_paged_attention_pallas_call_is_named():
+    pages, page, heads, d, b = 8, 16, 2, 128, 2
+    pool = jnp.zeros((pages, page, heads, d), jnp.float32)
+    got = _pallas_names(
+        lambda q, k, v, pt, ln, act: paged.paged_attention(
+            q, k, v, pt, ln, act, interpret=True),
+        jnp.zeros((b, 8, heads, d), jnp.float32), pool, pool,
+        jnp.zeros((b, 4), jnp.int32), jnp.ones((b,), jnp.int32),
+        jnp.ones((b,), bool))
+    assert got == [paged.PAGED_ATTN]
+
+
+def test_seven_pallas_call_sites_and_which_pass_a_name():
+    """By source: the paged kernel's `pallas_call(` passes `name=`, the
+    six of the flash kernels pass none (a new kernel fails here until it
+    is put on one side)."""
+    named = {}
+    for mod in (fa, paged):
+        with open(mod.__file__) as f:
+            src = f.read()
+        calls = [m.start() for m in re.finditer(r"pl\.pallas_call\(", src)]
+        ends = calls[1:] + [len(src)]
+        named[mod] = [bool(re.search(r"\bname=[A-Z_]+,", src[at:end]))
+                      for at, end in zip(calls, ends)]
+    assert named == {fa: [False] * 6, paged: [True]}
+
+
+# -- the benchmark's copy of the names --------------------------------------
+def test_benchmark_data_names_the_same_scopes_kernels_and_spans():
+    with open(os.path.join(ROOT, "benchmark", "scopes.json")) as f:
+        data = json.load(f)
+    assert tuple(data["scopes"]) == STEP_SCOPES
+    assert data["span_prefix"] == _timeline.SPAN_PREFIX
+    assert set(data["spans"]) == set(_timeline.PHASES) | {
+        "report.sync", "report.publish"}
+    assert data["flash"]["scope"] in STEP_SCOPES
+    for kernel in data["flash"]["kernels"]:
+        assert os.path.exists(
+            os.path.join(ROOT, "benchmark", "kernels", kernel + ".json"))
+    files = glob.glob(os.path.join(ROOT, "benchmark", "kernels", "dtpu_*.json"))
+    name = paged.PAGED_ATTN
+    assert [os.path.basename(p) for p in files] == [name + ".json"]
+    with open(files[0]) as f:
+        rx = re.compile(json.load(f)["pattern"])
+    # as `trace_reduce.op_name` prints a kernel: `mosaic:<name>[.<n>]`
+    assert rx.search("mosaic:" + name) and rx.search(f"mosaic:{name}.12")
+    assert not rx.search("mosaic:_unknown_.3")
+
+
+# -- Timeline.phase ---------------------------------------------------------
+class _Clock:
+    """A clock that advances only when told to."""
+
+    def __init__(self):
+        self.now = 100.0
+
+    def __call__(self):
+        return self.now
+
+
+def _timeline_with_clock(enabled=True):
+    tl = Timeline(enabled=enabled)
+    tl.pc = clock = _Clock()
+    tl.reset_window()
+    return tl, clock
+
+
+def test_phase_accumulates_as_the_old_arithmetic_did():
+    tl, clock = _timeline_with_clock()
+    for _step in range(3):
+        # the hot loop's two phases, then the jitted step's dispatch
+        with tl.phase("data_wait"):
+            clock.now += 0.25
+        with tl.phase("h2d_put"):
+            clock.now += 0.125
+        tl.step_done()
+        clock.now += 1.0
+    with tl.phase("report", timed=False):       # flush_report
+        with tl.phase("report.sync", timed=False):
+            clock.now += 2.0                    # device time: the residual
+        with tl.phase("report.publish"):
+            clock.now += 0.5
+    assert tl.window == {"data_wait": 0.75, "h2d_put": 0.375,
+                         "report": 0.5, "checkpoint": 0.0}
+    out = tl.close_window()
+    assert out["window_s"] == pytest.approx(6.625)
+    assert out["report_frac"] == pytest.approx(0.5 / 6.625)
+    assert out["step_frac"] == pytest.approx(5.0 / 6.625)
+    assert out["step_time_s"] == pytest.approx(6.625 / 3)
+    with tl.phase("checkpoint"):
+        clock.now += 4.0
+    assert tl.window["checkpoint"] == 4.0
+
+
+def test_phase_restores_the_samplers_tag_and_nests():
+    tl, _clock = _timeline_with_clock()
+    tag = lambda: profiling._thread_phase.get(  # noqa: E731
+        threading.get_ident())
+    profiling.set_phase("step")
+    try:
+        with tl.phase("data_wait"):
+            assert tag() == "data_wait"
+        assert tag() == "step"
+        with tl.phase("report", timed=False):
+            assert tag() == "report"
+            with tl.phase("report.sync", timed=False):
+                assert tag() == "report"    # children keep the phase's tag
+            assert tag() == "report"
+            with pytest.raises(RuntimeError):
+                with tl.phase("report.publish"):
+                    raise RuntimeError("a failed report")
+            assert tag() == "report"
+        assert tag() == "step"
+    finally:
+        profiling.set_phase(None)
+    assert tag() is None
+
+
+def test_phase_accumulates_nothing_when_disabled(monkeypatch):
+    monkeypatch.setenv("DTPU_TIMELINE", "0")
+    tl = Timeline()
+    tl.pc = clock = _Clock()
+    with tl.phase("data_wait"):
+        clock.now += 1.0
+    with tl.phase("report.publish"):
+        clock.now += 1.0
+    assert tl.enabled is False
+    assert tl.window == {p: 0.0 for p in _timeline.PHASES}
+
+
+# -- the spans in a capture -------------------------------------------------
+def test_fit_leaves_the_trainers_spans_on_the_host_plane(tmp_path):
+    from jax.profiler import ProfileData
+
+    from benchmark import trace_reduce
+
+    trainer = Trainer(_GPTTrial(), _dummy_core(tmp_path / "ckpt"))
+    trainer.fit(max_length=Batch(1))            # compile outside the capture
+    trace_dir = str(tmp_path / "trace")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        trainer.fit(max_length=Batch(3), report_period=Batch(1),
+                    checkpoint_period=Batch(2))
+    finally:
+        jax.profiler.stop_trace()
+    data = ProfileData.from_file(trace_reduce.find_xplane(trace_dir))
+    spans = {}
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(_timeline.SPAN_PREFIX):
+                    spans.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    names = {n[len(_timeline.SPAN_PREFIX):] for n in spans}
+    assert names >= {"data_wait", "h2d_put", "report", "report.sync",
+                     "report.publish", "checkpoint"}, names
+    assert len(spans["dtpu.trainer.data_wait"]) == 2
+    assert len(spans["dtpu.trainer.h2d_put"]) == 2
+    # the children lie inside a `report` span
+    for child in ("report.sync", "report.publish"):
+        for a, b in spans["dtpu.trainer." + child]:
+            assert any(ra <= a and b <= rb
+                       for ra, rb in spans["dtpu.trainer.report"]), child

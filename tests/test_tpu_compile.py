@@ -10,8 +10,10 @@ the kernel at this shape, not that its numbers are right — the
 interpret-mode parity suites and `chip_smoke.py` say that.
 """
 import functools
+import dataclasses
 import importlib
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -29,9 +31,10 @@ BF16 = jnp.bfloat16
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e chip; the module is skipped where the topology
-    cannot be described (no TPU compiler in the installation)."""
+def chips():
+    """The four described chips of a v5e 2x2; the module is skipped where
+    the topology cannot be described (no TPU compiler in the
+    installation)."""
     from jax.experimental import topologies
 
     try:
@@ -40,7 +43,13 @@ def chip():
         )
     except Exception as e:  # noqa: BLE001 — any refusal means "not here"
         pytest.skip(f"cannot describe a v5e topology here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def chip(chips):
+    """One described v5e chip."""
+    return SingleDeviceSharding(chips[0])
 
 
 @pytest.fixture(autouse=True)
@@ -161,3 +170,61 @@ def test_kernel_compiles_for_v5e(chip, case):
     assert "tpu_custom_call" in compiled.as_text(), (
         "compiled without a Mosaic kernel: the reference path was taken"
     )
+
+
+# -- the names the flash kernels have in a trace ------------------------------
+def _gpt_grad(mesh, layer_loop):
+    """The gradient of a small GPT's loss, flash attention on, under the
+    train step's scopes as `GPT` opens them."""
+    from determined_tpu.models import gpt as gpt_mod
+
+    model = gpt_mod.GPT(dataclasses.replace(
+        gpt_mod.tiny(256), d_model=256, attn_impl="flash", dtype=BF16,
+        layer_loop=layer_loop, remat=True), mesh=mesh)
+    rng = jax.random.PRNGKey(0)
+    params = jax.eval_shape(lambda: model.init(rng))
+
+    def grad(params, tokens):
+        return jax.grad(
+            lambda p: model.loss(p, {"tokens": tokens}, rng)[0])(params)
+
+    return grad, params, jax.ShapeDtypeStruct((4, 256), jnp.int32)
+
+
+@pytest.mark.parametrize("n_chips,layer_loop,want", [
+    (1, "unroll", {"flash_forward", "flash_backward"}),   # small-train-1k
+    (4, "scan", {"flash_sharded"}),                       # xl-train-fsdp4
+], ids=["one-chip", "mesh"])
+def test_flash_kernels_keep_the_names_the_benchmark_matches(
+        chips, n_chips, layer_loop, want):
+    """XLA names a Pallas kernel's operation after the innermost
+    component of jax's name stack. `benchmark/kernels/flash_*.json`
+    (`flash_time_share`, `flash_roofline`, `scope_reduce`) find the
+    kernels by the names that gives in the benchmark's two training
+    cells: a `name=` on a `pallas_call`, or a `jax.named_scope` left open
+    around the attention call, renames them and fails here."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from benchmark import kernel_events, trace_reduce
+    from determined_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    if n_chips == 1:
+        mesh, sharding = None, SingleDeviceSharding(chips[0])
+    else:
+        mesh = make_mesh(MeshConfig(data=1, fsdp=n_chips), chips)
+        sharding = NamedSharding(mesh, PartitionSpec())
+    grad, params, tokens = _gpt_grad(mesh, layer_loop)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        (params, tokens))
+    text = jax.jit(grad).lower(*args).compile().as_text()
+    # as the benchmark prints a trace's events: `mosaic:<instruction>`
+    ops = {trace_reduce.op_name(line.strip()) for line in text.splitlines()
+           if trace_reduce.MOSAIC in line}
+    assert ops, "compiled without a Mosaic kernel"
+    found = {op: {k for k in ("flash_forward", "flash_backward",
+                              "flash_sharded")
+                  if re.search(kernel_events.kernel(k)["pattern"], op)}
+             for op in ops}
+    assert all(found.values()), found
+    assert set().union(*found.values()) == want, found

@@ -6,6 +6,7 @@ import threading
 import time
 from http.server import BaseHTTPRequestHandler, HTTPServer
 
+import pytest
 import requests
 
 from determined_tpu.master.api_server import ApiServer
@@ -98,12 +99,26 @@ class TestTracer:
         tracer.stop()
         assert exp.exported == 4
 
-    def test_null_tracer_default(self):
-        master = Master()
-        try:
-            from determined_tpu.master.tracing import NullTracer
+    @pytest.mark.parametrize("traces_config", [None, {"enabled": False}])
+    def test_null_tracer_default(self, traces_config):
+        """The trace plane is on by default (`masterconf.TRACES_DEFAULTS`):
+        a default master's `Tracer` feeds its own `TraceStore`; only
+        `traces: {enabled: false}` with no other sink gives `NullTracer`."""
+        from determined_tpu.master.tracing import NullTracer
 
-            assert isinstance(master.tracer, NullTracer)
+        master = Master(traces_config=traces_config)
+        try:
+            if traces_config is None:
+                assert isinstance(master.tracer, Tracer)
+                with master.tracer.span("plane.default"):
+                    pass
+                master.tracer.flush()
+                assert master.tracestore.stats()["spans"] == 1
+            else:
+                assert isinstance(master.tracer, NullTracer)
+                with master.tracer.span("plane.off"):
+                    pass
+                assert master.tracestore.stats()["spans"] == 0
         finally:
             master.shutdown()
 
