@@ -1436,8 +1436,8 @@ def main() -> None:
     mfu, tokens_per_sec = _measure_mfu(config, batch_size, inner, rounds, dev)
     # Kernel-shape provenance for the perf trajectory: the flash blocks the
     # headline config actually runs (fitted to its sequence) and the
-    # fraction of forward-grid blocks the causal skip keeps live (1.0 =
-    # monolithic single-block path; see docs/perf.md).
+    # fraction of forward-grid blocks the causal skip keeps live (on the
+    # monolithic path: of its row chunks; see docs/perf.md).
     from determined_tpu.ops.flash_attention import block_skip_stats, fit_block
 
     hb_q = fit_block(config.seq_len, config.flash_block_q)

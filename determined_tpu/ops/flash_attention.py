@@ -302,10 +302,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, window, kv_offset,
         lse_ref[0] = lse.reshape(1, block_q)
 
 
-def _mono_fwd_call(q, k, v, *, scale, causal, interpret):
-    bh, s_q, d = q.shape
-    s_k = k.shape[1]
-    o, lse = pl.pallas_call(
+@functools.lru_cache(maxsize=None)
+def _mono_fwd_fn(bh, s_q, s_k, d, dtype, scale, causal, interpret):
+    """The monolithic forward for one shape, built once a process: every
+    layer of a model then calls the same jitted callable, so jax traces the
+    kernel body and lowers it to Mosaic once a program and not once a
+    layer. On the chip's host that is seconds of set-up, and the unrolled
+    chunks would have added to them (PERF.md section 6, PR 26)."""
+    return pl.pallas_call(
         functools.partial(
             _fwd_kernel_mono, scale=scale, causal=causal
         ),
@@ -320,11 +324,17 @@ def _mono_fwd_call(q, k, v, *, scale, causal, interpret):
             pl.BlockSpec((1, 1, s_q), lambda b: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, s_q, d), dtype),
             jax.ShapeDtypeStruct((bh, 1, s_q), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )
+
+
+def _mono_fwd_call(q, k, v, *, scale, causal, interpret):
+    bh, s_q, d = q.shape
+    o, lse = _mono_fwd_fn(bh, s_q, k.shape[1], d, q.dtype, scale, causal,
+                          interpret)(q, k, v)
     return o, lse.reshape(bh, s_q)
 
 
@@ -346,14 +356,12 @@ def _flash_fwd_pallas(
     s_k = k.shape[1]
     if _mono_ok(s_q, s_k, block_q, block_k, window=window,
                 has_segments=segs is not None, kv_offset=kv_offset):
-        # Causal-split band schedules (skipping the never-attended upper
-        # quarter of the score matrix) were tried both as two pallas calls
-        # and as a 2-band grid with resident K/V — the XLA glue
-        # (slice/concat/pad) respectively the band dispatch cost more than
-        # the quarter saved at these sizes. Plain monolithic wins. The
-        # blocked kernels' dead-block skipping doesn't change that choice
-        # here: the autotuner probes the mono candidate against blocked
-        # ones and keeps whichever times best.
+        # One program a head, K/V resident; the dead upper triangle is
+        # skipped inside it by static row chunks (`_mono_chunks`). Skipping
+        # it from outside lost: two pallas calls with XLA glue
+        # (slice/concat/pad), and a 2-band grid with `pl.when` dispatch,
+        # each cost more than the quarter they saved. The autotuner still
+        # probes this candidate against the blocked ones.
         return _mono_fwd_call(
             q, k, v, scale=scale, causal=causal, interpret=interpret,
         )
@@ -418,15 +426,36 @@ def _flash_fwd_pallas(
 # sequence — the GPT-2-class regime, S ≤ ~1k — the blocked kernels' online
 # softmax machinery (m/l scratch read-modify-writes, correction multiplies,
 # @pl.when dispatch) is pure overhead, and the two-pass backward recomputes
-# p twice. These specializations do plain softmax in registers, and the
-# fused backward produces dq/dk/dv in ONE pass: 5 MXU dots + 1 exp over
-# the score matrix instead of 7 dots + 2 exps. Measured on v5e at GPT-2
-# shapes: ~30% off the attention share of the train step.
+# p twice. These do plain softmax, and the fused backward produces
+# dq/dk/dv in ONE pass: 5 MXU dots + 1 exp a score instead of 7 + 2.
+#
+# Under the causal mask they walk the score matrix in static row chunks
+# (a Python loop, unrolled at trace time) and give chunk r0..r1 only the
+# keys 0..r1 it can see: every row's whole live range is in its chunk, so
+# there is no running max or sum. Scores are held transposed, [keys, rows]:
+# the row statistics are then lane vectors like the lse block, p^T feeds
+# dv and ds^T feeds dk without a transpose of a score-sized array, and
+# the products that stream the long key axis keep one small weight tile.
+# Not causal, or s_q within one chunk, is the one-chunk case of the same
+# body. Measured on a v5e at S = 1024, D = 64, in GPT-2 small's train step
+# (PERF.md section 6, PR 26), a head: forward 4.15 -> 2.38 us, backward
+# 7.00 -> 4.45 us, against the whole-square kernels these replace. Chunks
+# alone, both passes timed outside a step: the forward wants 512 rows
+# (3.06 us; 256: 4.15, 384: 3.71, 768: 3.55 — a narrower score block
+# leaves MXUs idle, a wider one computes more of the dead triangle), the
+# backward 256 (6.27 us with its row sums; 128: 6.43, 512 and one chunk
+# more; the order of its five products moves it by as much). D = 128 reads
+# the same way (forward 5.09 -> 3.72, backward 8.71 -> 6.06).
 # ---------------------------------------------------------------------------
 #: Largest s_q*s_k (score-matrix elements) the monolithic path may buy:
 #: ~3 fp32 [s_q, s_k] temporaries must fit VMEM alongside the q/k/v/do
-#: blocks. 2^21 elements = 8 MB per temporary.
+#: blocks when a call is one chunk. 2^21 elements = 8 MB per temporary.
 _MONO_MAX_SCORES = 2 ** 21
+
+#: Query rows a causal chunk takes, forward and backward (timings above):
+#: two module constants chosen on the chip, not options.
+_MONO_CHUNK_FWD = 512
+_MONO_CHUNK_BWD = 256
 
 
 def _mono_ok(s_q, s_k, block_q, block_k, *, window=None, has_segments=False,
@@ -443,65 +472,91 @@ def _mono_ok(s_q, s_k, block_q, block_k, *, window=None, has_segments=False,
     )
 
 
-def _fwd_kernel_mono(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal):
-    q = q_ref[0]  # [s_q, d]
-    k = k_ref[0]  # [s_k, d]
-    v = v_ref[0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    if causal:
-        mask = _band_mask(0, 0, q.shape[0], k.shape[0], causal=True,
-                          window=None, kv_offset=0)
-        s = jnp.where(mask, s, NEG_INF)
-    m = jnp.max(s, axis=1, keepdims=True)
-    p = jnp.exp(s - m)  # masked entries underflow to exactly 0
-    l = jnp.sum(p, axis=1, keepdims=True)
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    acc = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+def _mono_chunks(s_q: int, s_k: int, causal: bool, chunk: int):
+    """Static (r0, r1, k1) row chunks covering the live scores: query rows
+    r0..r1 against keys 0..k1, all a causal row below r1 can see. Not
+    causal: one chunk, every key."""
+    if not causal:
+        return [(0, s_q, s_k)]
+    return [(r0, min(r0 + chunk, s_q), min(r0 + chunk, s_q, s_k))
+            for r0 in range(0, s_q, chunk)]
+
+
+def _dot(a, b, contract_a: int, contract_b: int):
+    """2-D MXU product in the operands' own dtype, fp32 accumulation."""
+    return jax.lax.dot_general(
+        a, b, (((contract_a,), (contract_b,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
-    lse_ref[0] = (m + jnp.log(l_safe)).reshape(1, q.shape[0])
+
+
+def _mono_scores(k, q, r0: int, *, scale, causal):
+    """[keys, rows] fp32 scores of query rows r0.. against keys 0..; what
+    the causal mask hides is NEG_INF, so exp() of it is exactly 0."""
+    st = _dot(k, q, 1, 1) * scale
+    if causal:
+        keys = lax.broadcasted_iota(jnp.int32, st.shape, 0)
+        rows = r0 + lax.broadcasted_iota(jnp.int32, st.shape, 1)
+        st = jnp.where(rows >= keys, st, NEG_INF)
+    return st
+
+
+def _fwd_kernel_mono(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal):
+    s_q, s_k = q_ref.shape[1], k_ref.shape[1]
+    vt = v_ref[0].T  # [d, s_k]
+    for r0, r1, k1 in _mono_chunks(s_q, s_k, causal, _MONO_CHUNK_FWD):
+        st = _mono_scores(k_ref[0, :k1, :], q_ref[0, r0:r1, :], r0,
+                          scale=scale, causal=causal)
+        # Mono never sees a row without a live key (no offset, no
+        # segments): m is a real score and l >= 1.
+        m = jnp.max(st, axis=0, keepdims=True)  # [1, rows]
+        pt = jnp.exp(st - m)
+        l = jnp.sum(pt, axis=0, keepdims=True)
+        acc = _dot(vt[:, :k1], pt.astype(vt.dtype), 1, 0)  # [d, rows]
+        o_ref[0, r0:r1, :] = (acc / l).T.astype(o_ref.dtype)
+        lse_ref[0, :, r0:r1] = m + jnp.log(l)
 
 
 def _bwd_kernel_mono(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dlse_ref, dq_ref, dk_ref, dv_ref, *, scale, causal):
-    """Fused single-pass backward: s and p are computed ONCE and feed all
-    three gradients (the blocked split recomputes them per pass)."""
-    q = q_ref[0]    # [s_q, d] bf16
-    k = k_ref[0]    # [s_k, d]
-    v = v_ref[0]
-    do = do_ref[0]  # [s_q, d]
-    s_q = q.shape[0]
-    lse = lse_ref[0].reshape(s_q, 1)    # fp32
-    delta = delta_ref[0].reshape(s_q, 1)
-    dlse = dlse_ref[0].reshape(s_q, 1)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    if causal:
-        mask = _band_mask(0, 0, s_q, k.shape[0], causal=True,
-                          window=None, kv_offset=0)
-        s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse)                # [s_q, s_k] fp32; masked → 0
-    pt = p.astype(do.dtype)
-    dv_ref[0] = jax.lax.dot_general(
-        pt, do, (((0,), (0,)), ((), ())),   # pᵀ·do → [s_k, d]
-        preferred_element_type=jnp.float32,
-    ).astype(dv_ref.dtype)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    ds = (p * (dp - delta + dlse) * scale).astype(q.dtype)
-    dq_ref[0] = jax.lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ).astype(dq_ref.dtype)
-    dk_ref[0] = jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())),    # dsᵀ·q → [s_k, d]
-        preferred_element_type=jnp.float32,
-    ).astype(dk_ref.dtype)
+                     dlse_ref, dq_ref, dk_ref, dv_ref, *scratch, scale,
+                     causal):
+    """Fused single-pass backward: s and p are computed ONCE a chunk and
+    feed all three gradients (the blocked split recomputes them per pass).
+    dq is written once a chunk; dk/dv sum over the chunks in the fp32
+    `scratch` pair and are cast once (one chunk: written straight out,
+    and the call allocates no scratch)."""
+    s_q, s_k = q_ref.shape[1], k_ref.shape[1]
+    kt = k_ref[0].T  # [d, s_k]
+    seen = 0  # keys whose dk/dv rows hold a partial sum already
+    for r0, r1, k1 in _mono_chunks(s_q, s_k, causal, _MONO_CHUNK_BWD):
+        q = q_ref[0, r0:r1, :]    # [rows, d] bf16
+        do = do_ref[0, r0:r1, :]
+        # Both score-shaped products first: the MXU runs the second while
+        # the VPU is still on the first's softmax (measured: of four
+        # orders this one was fastest at both head widths).
+        dpt = _dot(v_ref[0, :k1, :], do, 1, 1)            # [keys, rows]
+        st = _mono_scores(k_ref[0, :k1, :], q, r0, scale=scale, causal=causal)
+        pt = jnp.exp(st - lse_ref[0, :, r0:r1])           # fp32
+        # dL/ds = p∘(dp − delta + dlse); the two row terms meet first.
+        shift = delta_ref[0, :, r0:r1] - dlse_ref[0, :, r0:r1]
+        dst = (pt * (dpt - shift) * scale).astype(q.dtype)
+        parts = (_dot(pt.astype(do.dtype), do, 1, 0), _dot(dst, q, 1, 0))
+        dq_ref[0, r0:r1, :] = _dot(kt[:, :k1], dst, 1, 0).T.astype(dq_ref.dtype)
+        for ref, acc, part in zip((dv_ref, dk_ref), scratch or (None,) * 2,
+                                  parts):                  # [keys, d] fp32
+            if acc is None:
+                ref[0, :k1, :] = part.astype(ref.dtype)
+                continue
+            if seen:
+                acc[:seen, :] += part[:seen]
+            if k1 > seen:
+                acc[seen:k1, :] = part[seen:]
+        seen = k1
+    for ref, scr in zip((dv_ref, dk_ref), scratch):
+        ref[0, :seen, :] = scr[:seen, :].astype(ref.dtype)
+    if seen < s_k:  # causal with more keys than queries: no row sees them
+        dk_ref[0, seen:, :] = jnp.zeros_like(dk_ref[0, seen:, :])
+        dv_ref[0, seen:, :] = jnp.zeros_like(dv_ref[0, seen:, :])
 
 
 def _bwd_fused_blocked_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -718,13 +773,16 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _mono_bwd_call(q, k, v, do, lse3, delta3, dlse3, *, scale, causal,
-                   interpret):
-    bh, s_q, d = q.shape
-    s_k = k.shape[1]
+@functools.lru_cache(maxsize=None)
+def _mono_bwd_fn(bh, s_q, s_k, d, dtypes, scale, causal, interpret):
+    """The monolithic backward for one shape and one (q, k, v) dtype
+    triple, built once a process (as `_mono_fwd_fn`)."""
+    from jax.experimental.pallas import tpu as pltpu
+
     row = pl.BlockSpec((1, s_q, d), lambda b: (b, 0, 0))
     col = pl.BlockSpec((1, s_k, d), lambda b: (b, 0, 0))
     vec = pl.BlockSpec((1, 1, s_q), lambda b: (b, 0, 0))
+    n_chunks = len(_mono_chunks(s_q, s_k, causal, _MONO_CHUNK_BWD))
     return pl.pallas_call(
         functools.partial(
             _bwd_kernel_mono, scale=scale, causal=causal
@@ -733,11 +791,22 @@ def _mono_bwd_call(q, k, v, do, lse3, delta3, dlse3, *, scale, causal,
         in_specs=[row, col, col, row, vec, vec, vec],
         out_specs=[row, col, col],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, s_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s_k, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, s, d), dtype)
+            for s, dtype in zip((s_q, s_k, s_k), dtypes)
         ],
+        # dk and dv, summed over the chunks in fp32
+        scratch_shapes=[pltpu.VMEM((s_k, d), jnp.float32)]
+        * (2 if n_chunks > 1 else 0),
         interpret=interpret,
+    )
+
+
+def _mono_bwd_call(q, k, v, do, lse3, delta3, dlse3, *, scale, causal,
+                   interpret):
+    bh, s_q, d = q.shape
+    return _mono_bwd_fn(
+        bh, s_q, k.shape[1], d, (q.dtype, k.dtype, v.dtype), scale, causal,
+        interpret,
     )(q, k, v, do, lse3, delta3, dlse3)
 
 
@@ -1042,12 +1111,15 @@ def block_skip_stats(s_q: int, s_k: int, block_q: int, block_k: int, *,
                      kv_offset: int = 0) -> Tuple[int, int]:
     """(live_blocks, total_blocks) of the blocked forward grid — the pure
     numpy mirror of `_mask_dispatch`'s liveness predicate, so the bench can
-    report the causal-skip ratio without running a kernel. The mono path
-    is a single fully-live block by construction."""
+    report the causal-skip ratio without running a kernel. On the mono
+    path the blocks are the forward kernel's row chunks by as many keys."""
     block_q = fit_block(s_q, block_q)
     block_k = fit_block(s_k, block_k)
     if _mono_ok(s_q, s_k, block_q, block_k, window=window, kv_offset=kv_offset):
-        return 1, 1
+        chunks = _mono_chunks(s_q, s_k, causal, _MONO_CHUNK_FWD)
+        side = chunks[0][1]  # rows of a whole chunk; s_q when there is one
+        return (sum(-(-k1 // side) for _, _, k1 in chunks),
+                len(chunks) * -(-s_k // side))
     nq = -(-s_q // block_q)
     nk = -(-s_k // block_k)
     if not causal and window is None:

@@ -124,11 +124,15 @@ def test_flash_pallas_interpret_matches():
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_pallas_monolithic_interpret_matches(causal):
+@pytest.mark.parametrize("s", [64, 128, 256, 384, 512, 640, 1024])
+def test_flash_pallas_monolithic_interpret_matches(s, causal):
     """The monolithic single-block kernels (block == seq, the GPT-2-class
-    fast path: plain softmax forward + fused single-pass backward) against
-    the blockwise reference — including the lse output and the dlse
-    cotangent path that ring attention feeds."""
+    fast path: plain softmax forward + fused single-pass backward, causal
+    row chunks inside) against the blockwise reference — including the lse
+    output and the dlse cotangent path that ring attention feeds. Causal
+    chunks (512 rows forward, 256 backward): 64 to 256 are one chunk in
+    both passes, 384 has a ragged last chunk backward (256 + 128), 640
+    one forward (512 + 128), 1024 is the benchmark's 2 and 4."""
     from determined_tpu.ops.flash_attention import (
         _blockwise_bwd_ref,
         _blockwise_fwd_ref,
@@ -137,7 +141,7 @@ def test_flash_pallas_monolithic_interpret_matches(causal):
         _mono_ok,
     )
 
-    b, s, h, d = 1, 64, 2, 16
+    b, h, d = 1, 2, 16
     assert _mono_ok(s, s, s, s)
     q, k, v = _rand_qkv(jax.random.PRNGKey(7), b, s, h, d)
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -164,48 +168,6 @@ def test_flash_pallas_monolithic_interpret_matches(causal):
     got = _flash_bwd_pallas(qf, kf, vf, o_want, lse_want, do, scale=scale,
                             causal=causal, block_q=s, block_k=s,
                             interpret=True, dlse=dlse)
-    for name, a, b_ in zip(("dq", "dk", "dv"), got, want):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b_), atol=5e-5, rtol=5e-5,
-            err_msg=name,
-        )
-
-
-def test_flash_pallas_monolithic_causal_s256_matches():
-    """A second monolithic size (s=256, causal): forward, lse, and the
-    fused backward against the blockwise reference."""
-    from determined_tpu.ops.flash_attention import (
-        _blockwise_bwd_ref,
-        _blockwise_fwd_ref,
-        _flash_bwd_pallas,
-        _flash_fwd_pallas,
-    )
-
-    b, s, h, d = 1, 256, 2, 16
-    q, k, v = _rand_qkv(jax.random.PRNGKey(11), b, s, h, d)
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    scale = 1.0 / d ** 0.5
-
-    o, lse = _flash_fwd_pallas(
-        qf, kf, vf, scale=scale, causal=True,
-        block_q=s, block_k=s, interpret=True,
-    )
-    o_want, lse_want = _blockwise_fwd_ref(
-        qf, kf, vf, scale=scale, causal=True, block_k=64
-    )
-    np.testing.assert_allclose(np.asarray(o), np.asarray(o_want),
-                               atol=2e-5, rtol=2e-5)
-    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_want),
-                               atol=2e-5, rtol=2e-5)
-
-    do = jax.random.normal(jax.random.PRNGKey(12), qf.shape)
-    want = _blockwise_bwd_ref(qf, kf, vf, o_want, lse_want, do, scale=scale,
-                              causal=True, block_k=64)
-    got = _flash_bwd_pallas(qf, kf, vf, o_want, lse_want, do, scale=scale,
-                            causal=True, block_q=s, block_k=s,
-                            interpret=True)
     for name, a, b_ in zip(("dq", "dk", "dv"), got, want):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b_), atol=5e-5, rtol=5e-5,
@@ -518,3 +480,28 @@ def test_block_skip_stats_counts():
         )
         assert total == nq * nk
         assert live == brute, (s, bq, bk, window, off, live, brute)
+
+
+@pytest.mark.parametrize("s,causal,want", [
+    (1024, True, (3, 4)),    # two chunks of 512 rows: 1 + 2 of 2 x 2
+    (1408, True, (6, 9)),    # near the largest square the mono path takes
+    (640, True, (3, 4)),     # ragged last chunk: 512 + 128 rows
+    (512, True, (1, 1)),     # s <= chunk: one chunk
+    (64, True, (1, 1)),
+    (1024, False, (1, 1)),   # not causal: one chunk, every key
+])
+def test_block_skip_stats_counts_mono_chunks(s, causal, want):
+    """On the mono path (block == seq) the blocks are the forward kernel's
+    causal row chunks by as many keys, and every chunk's live keys are what
+    the kernel slices for it."""
+    from determined_tpu.ops.flash_attention import (
+        _MONO_CHUNK_FWD,
+        _mono_chunks,
+        _mono_ok,
+        block_skip_stats,
+    )
+
+    assert _MONO_CHUNK_FWD == 512 and _mono_ok(s, s, s, s)
+    assert block_skip_stats(s, s, s, s, causal=causal) == want
+    for r0, r1, k1 in _mono_chunks(s, s, causal, _MONO_CHUNK_FWD):
+        assert k1 == (r1 if causal else s)  # no live key is left out

@@ -69,11 +69,11 @@ def _for_the_chip(monkeypatch):
     compilation_cache.reset_cache()
 
 
-def _flash_train(s):
+def _flash_train(s, heads=12, head_dim=64):
     """Forward + all three gradients at batch 1 x 12 heads x 64 (1k rides
     the GPT-2 bench batch of 24 instead: BH 288)."""
     b = 24 if s == 1024 else 1
-    qkv = [((b, s, 12, 64), BF16)] * 3
+    qkv = [((b, s, heads, head_dim), BF16)] * 3
 
     def step(q, k, v):
         def loss(q, k, v):
@@ -148,6 +148,7 @@ def _paged_decode(n_heads, head_dim, block_h=None):
 
 CASES = {
     "flash_train_1k_mono": lambda: _flash_train(1024),
+    "flash_train_1k_mono_d128": lambda: _flash_train(1024, 16, 128),
     "flash_train_16k_fused_blocked": lambda: _flash_train(16384),
     "flash_train_32k_split": lambda: _flash_train(32768),
     "packed_prefill_4x512_segments": _packed_prefill,
