@@ -26,10 +26,90 @@ from jax import shard_map
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
-from determined_tpu.ops.flash_attention import fit_block, flash_attention
+from determined_tpu.ops.flash_attention import (
+    fit_block,
+    flash_attention,
+    flash_attention_qkv,
+)
 from determined_tpu.parallel.ring import reference_attention, ring_attention
 
 BATCH_AXES = ("data", "fsdp")
+
+
+def _resolve_impl(impl: str, mesh: Optional[Mesh], seq: int) -> str:
+    if impl != "auto":
+        return impl
+    if mesh is not None and mesh.shape.get("context", 1) > 1:
+        return "ring"
+    if jax.default_backend() == "tpu" and seq % 128 == 0:
+        return "flash"
+    return "dense"
+
+
+def _flash(ops, *, mesh, causal, block_q, block_k, window, segment_ids):
+    """The flash kernels over `ops`: (q, k, v), each [B, S, H, D], or the
+    one-tuple (qkv,) [B, S, 3, H, D] of a fused projection."""
+    fused = len(ops) == 1
+    kernel = flash_attention_qkv if fused else flash_attention
+    # Fit the tuned block sizes to this sequence (block | seq is a hard
+    # kernel requirement; a 1024-tuned block must degrade, not raise,
+    # for a 1536-long sequence).
+    block_q = fit_block(ops[0].shape[1], block_q)
+    block_k = fit_block(ops[-1].shape[1], block_k)
+
+    def local(*args):  # the operands, then the segment ids if there are any
+        return kernel(
+            *args[:len(ops)], causal=causal, block_q=block_q,
+            block_k=block_k, window=window,
+            segment_ids=args[len(ops)] if len(args) > len(ops) else None,
+        )
+
+    args = ops if segment_ids is None else (*ops, segment_ids)
+    if mesh is None:
+        out = local(*args)
+    else:
+        spec = P(BATCH_AXES, None, "tensor", None)
+        in_spec = P(BATCH_AXES, None, None, "tensor", None) if fused else spec
+        seg_specs = () if segment_ids is None else (P(BATCH_AXES, None),)
+        out = shard_map(
+            local, mesh=mesh, in_specs=(in_spec,) * len(ops) + seg_specs,
+            out_specs=spec, check_vma=False,
+        )(*args)
+    # Remat boundary marker: "dots saveable" policies don't recognize a
+    # pallas_call as a dot, so without this name the whole flash forward
+    # re-runs inside the backward (models/gpt.py combines the dots
+    # policy with save_only_these_names("flash_out")).
+    return checkpoint_name(out, "flash_out")
+
+
+def attention_qkv(
+    qkv: jax.Array,
+    *,
+    mesh: Optional[Mesh] = None,
+    causal: bool = True,
+    impl: str = "auto",
+    block_q: int = 512,
+    block_k: int = 512,
+    layout: str = "contiguous",
+    window: Optional[int] = None,
+    segment_ids: Optional[jax.Array] = None,
+) -> jax.Array:
+    """`attention` of a fused projection qkv [B, S, 3, H, D] (self
+    attention: one sequence). The flash path hands the kernels the one
+    array, which they read in place where they can
+    (`ops.flash_attention.flash_attention_qkv`); every other impl gets
+    the three slices."""
+    impl = _resolve_impl(impl, mesh, qkv.shape[1])
+    if impl == "flash" and layout != "zigzag":
+        return _flash(
+            (qkv,), mesh=mesh, causal=causal, block_q=block_q,
+            block_k=block_k, window=window, segment_ids=segment_ids,
+        )
+    return attention(
+        qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], mesh=mesh, causal=causal,
+        impl=impl, block_q=block_q, block_k=block_k, layout=layout,
+        window=window, segment_ids=segment_ids,
+    )
 
 
 def attention(
@@ -63,13 +143,7 @@ def attention(
     segment_ids: [B, S] int ids for packed sequences; attention only
     within equal ids.
     """
-    if impl == "auto":
-        if mesh is not None and mesh.shape.get("context", 1) > 1:
-            impl = "ring"
-        elif jax.default_backend() == "tpu" and q.shape[1] % 128 == 0:
-            impl = "flash"
-        else:
-            impl = "dense"
+    impl = _resolve_impl(impl, mesh, q.shape[1])
 
     if layout == "zigzag" and impl != "ring":
         raise ValueError(
@@ -84,42 +158,10 @@ def attention(
         )
 
     if impl == "flash":
-        # Fit the tuned block sizes to this sequence (block | seq is a hard
-        # kernel requirement; a 1024-tuned block must degrade, not raise,
-        # for a 1536-long sequence).
-        block_q = fit_block(q.shape[1], block_q)
-        block_k = fit_block(k.shape[1], block_k)
-        if mesh is None:
-            out = flash_attention(
-                q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-                window=window, segment_ids=segment_ids,
-            )
-        else:
-            spec = P(BATCH_AXES, None, "tensor", None)
-            seg_spec = P(BATCH_AXES, None)
-
-            def local(q_, k_, v_, seg_=None):
-                return flash_attention(
-                    q_, k_, v_, causal=causal, block_q=block_q,
-                    block_k=block_k, window=window, segment_ids=seg_,
-                )
-
-            if segment_ids is not None:
-                out = shard_map(
-                    local, mesh=mesh,
-                    in_specs=(spec, spec, spec, seg_spec), out_specs=spec,
-                    check_vma=False,
-                )(q, k, v, segment_ids)
-            else:
-                out = shard_map(
-                    local, mesh=mesh, in_specs=(spec, spec, spec),
-                    out_specs=spec, check_vma=False,
-                )(q, k, v)
-        # Remat boundary marker: "dots saveable" policies don't recognize a
-        # pallas_call as a dot, so without this name the whole flash forward
-        # re-runs inside the backward (models/gpt.py combines the dots
-        # policy with save_only_these_names("flash_out")).
-        return checkpoint_name(out, "flash_out")
+        return _flash(
+            (q, k, v), mesh=mesh, causal=causal, block_q=block_q,
+            block_k=block_k, window=window, segment_ids=segment_ids,
+        )
 
     if impl == "ring":
         if mesh is None:

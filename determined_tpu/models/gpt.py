@@ -414,8 +414,9 @@ class GPT(Model):
                 jnp.einsum("bsd,dthk->bsthk", h, blk["wqkv"].astype(c.dtype))
                 + blk["bqkv"].astype(c.dtype)
             )
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         if manual:
+            with jax.named_scope("attn"):
+                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             ctx = (
                 self.mesh.shape.get("context", 1)
                 if self.mesh is not None else 1
@@ -479,8 +480,8 @@ class GPT(Model):
                     window=c.attn_window,
                 )
         else:
-            o = attn_mod.attention(
-                q, k, v, mesh=self.mesh, causal=True, impl=c.attn_impl,
+            o = attn_mod.attention_qkv(
+                qkv, mesh=self.mesh, causal=True, impl=c.attn_impl,
                 block_q=block_q, block_k=block_k,
                 layout=c.sequence_layout, window=c.attn_window,
                 segment_ids=segment_ids,

@@ -4,6 +4,7 @@ from determined_tpu.ops.flash_attention import (
     fit_block,
     flash_attention,
     flash_attention_lse,
+    flash_attention_qkv,
 )
 from determined_tpu.ops.paged_attention import paged_attention
 
@@ -12,5 +13,6 @@ __all__ = [
     "fit_block",
     "flash_attention",
     "flash_attention_lse",
+    "flash_attention_qkv",
     "paged_attention",
 ]

@@ -302,42 +302,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, window, kv_offset,
         lse_ref[0] = lse.reshape(1, block_q)
 
 
-@functools.lru_cache(maxsize=None)
-def _mono_fwd_fn(bh, s_q, s_k, d, dtype, scale, causal, interpret):
-    """The monolithic forward for one shape, built once a process: every
-    layer of a model then calls the same jitted callable, so jax traces the
-    kernel body and lowers it to Mosaic once a program and not once a
-    layer. On the chip's host that is seconds of set-up, and the unrolled
-    chunks would have added to them (PERF.md section 6, PR 26)."""
-    return pl.pallas_call(
-        functools.partial(
-            _fwd_kernel_mono, scale=scale, causal=causal
-        ),
-        grid=(bh,),
-        in_specs=[
-            pl.BlockSpec((1, s_q, d), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, s_k, d), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, s_k, d), lambda b: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, s_q, d), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 1, s_q), lambda b: (b, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s_q, d), dtype),
-            jax.ShapeDtypeStruct((bh, 1, s_q), jnp.float32),
-        ],
-        interpret=interpret,
-    )
-
-
-def _mono_fwd_call(q, k, v, *, scale, causal, interpret):
-    bh, s_q, d = q.shape
-    o, lse = _mono_fwd_fn(bh, s_q, k.shape[1], d, q.dtype, scale, causal,
-                          interpret)(q, k, v)
-    return o, lse.reshape(bh, s_q)
-
-
 def _seg3(segs, s_q, s_k):
     """([BH, Sq], [BH, Sk]) fp32 segment ids → the [BH, 1, S] layout the
     kernels' (1, 1, block) BlockSpecs want (same TPU-tiling trick as lse)."""
@@ -350,21 +314,10 @@ def _flash_fwd_pallas(
     q: jax.Array, k: jax.Array, v: jax.Array, *, scale, causal, block_q,
     block_k, interpret, window=None, kv_offset=0, segs=None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """q/k/v: [BH, S, D] (+ optional segs ([BH, Sq], [BH, Sk]) fp32)
-    → (o [BH, S, D], lse [BH, S])."""
+    """The blocked forward. q/k/v: [BH, S, D] (+ optional segs ([BH, Sq],
+    [BH, Sk]) fp32) → (o [BH, S, D], lse [BH, S])."""
     bh, s_q, d = q.shape
     s_k = k.shape[1]
-    if _mono_ok(s_q, s_k, block_q, block_k, window=window,
-                has_segments=segs is not None, kv_offset=kv_offset):
-        # One program a head, K/V resident; the dead upper triangle is
-        # skipped inside it by static row chunks (`_mono_chunks`). Skipping
-        # it from outside lost: two pallas calls with XLA glue
-        # (slice/concat/pad), and a 2-band grid with `pl.when` dispatch,
-        # each cost more than the quarter they saved. The autotuner still
-        # probes this candidate against the blocked ones.
-        return _mono_fwd_call(
-            q, k, v, scale=scale, causal=causal, interpret=interpret,
-        )
     nq = pl.cdiv(s_q, block_q)
     nk = pl.cdiv(s_k, block_k)
     kernel = functools.partial(
@@ -446,6 +399,27 @@ def _flash_fwd_pallas(
 # backward 256 (6.27 us with its row sums; 128: 6.43, 512 and one chunk
 # more; the order of its five products moves it by as much). D = 128 reads
 # the same way (forward 5.09 -> 3.72, backward 8.71 -> 6.06).
+#
+# Layout. The operands are [B, H*D, S]: heads side by side on the rows, a
+# head's D rows one block, the sequence on the lanes (any head count tiles:
+# 25 x 64 as 12 x 64). That is the layout XLA's TPU compiler gives what a
+# `bsd,dthk->bsthk` projection writes and a `bshk,hkd->bsd` one reads in
+# GPT's train step (sequence minor; seen in the compiled programs of both
+# benchmark cells), so `flash_attention_lse`'s transposes to and from
+# [B, S, H, D] are bitcasts there, where folding to a head a row,
+# [BH, S, D], cost a copy an operand and a result: eight a layer, 6.8 % of
+# GPT-2 small's step. `flash_attention_qkv` goes one further and hands the
+# kernels the fused projection itself, [B, 3, H*D, S], a block of which
+# is a head's q^T, k^T and v^T (and in the backward the three gradients):
+# then the three slices and the gradient's assembly are no passes over
+# memory either. With the sequence on the lanes q^T, do^T, o^T, dq^T,
+# dk^T and dv^T are what the products above take and give, K and V are
+# transposed once a call, and a [d, keys] fp32 accumulator has no padded
+# lanes. A head alone, S = 1024, D = 64 (PERF.md section 6, PR 29):
+# forward 2.93 -> 2.64 us, backward with its row sums 5.96 -> 4.46.
+# Every other call (segments, a window, `kv_offset`, blocks smaller than
+# the sequence, a head width that is not whole sublane tiles) folds to
+# [BH, S, D] and takes the blocked kernels below, as before.
 # ---------------------------------------------------------------------------
 #: Largest s_q*s_k (score-matrix elements) the monolithic path may buy:
 #: ~3 fp32 [s_q, s_k] temporaries must fit VMEM alongside the q/k/v/do
@@ -472,6 +446,14 @@ def _mono_ok(s_q, s_k, block_q, block_k, *, window=None, has_segments=False,
     )
 
 
+def _mono_tiles(head_dim: int, *dtypes) -> bool:
+    """A head is `head_dim` rows of the monolithic kernels' operands
+    ([B, H*D, S]): a block of whole sublane tiles (8 rows of 32 bits) of
+    the one dtype q, k and v share."""
+    return len(set(dtypes)) == 1 and not head_dim % (
+        32 // jnp.dtype(dtypes[0]).itemsize)
+
+
 def _mono_chunks(s_q: int, s_k: int, causal: bool, chunk: int):
     """Static (r0, r1, k1) row chunks covering the live scores: query rows
     r0..r1 against keys 0..k1, all a causal row below r1 can see. Not
@@ -490,10 +472,11 @@ def _dot(a, b, contract_a: int, contract_b: int):
     )
 
 
-def _mono_scores(k, q, r0: int, *, scale, causal):
-    """[keys, rows] fp32 scores of query rows r0.. against keys 0..; what
-    the causal mask hides is NEG_INF, so exp() of it is exactly 0."""
-    st = _dot(k, q, 1, 1) * scale
+def _mono_scores(k, qt, r0: int, *, scale, causal):
+    """[keys, rows] fp32 scores of query rows r0.. (`qt` [d, rows]) against
+    keys 0.. (`k` [keys, d]); what the causal mask hides is NEG_INF, so
+    exp() of it is exactly 0."""
+    st = _dot(k, qt, 1, 0) * scale
     if causal:
         keys = lax.broadcasted_iota(jnp.int32, st.shape, 0)
         rows = r0 + lax.broadcasted_iota(jnp.int32, st.shape, 1)
@@ -501,62 +484,67 @@ def _mono_scores(k, q, r0: int, *, scale, causal):
     return st
 
 
-def _fwd_kernel_mono(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal):
-    s_q, s_k = q_ref.shape[1], k_ref.shape[1]
-    vt = v_ref[0].T  # [d, s_k]
+def _fwd_kernel_mono(*refs, scale, causal, fused):
+    """One head: q^T [d, s_q], k^T and v^T [d, s_k] in, o^T and lse out."""
+    (qt_ref, kt_ref, vt_ref), (ot_ref, lse_ref) = _qkv_refs(refs, fused)
+    s_q, s_k = qt_ref.shape[2], kt_ref.shape[2]
+    k = kt_ref[0].T  # [s_k, d]
     for r0, r1, k1 in _mono_chunks(s_q, s_k, causal, _MONO_CHUNK_FWD):
-        st = _mono_scores(k_ref[0, :k1, :], q_ref[0, r0:r1, :], r0,
-                          scale=scale, causal=causal)
+        st = _mono_scores(k[:k1], qt_ref[0, :, r0:r1], r0, scale=scale,
+                          causal=causal)
         # Mono never sees a row without a live key (no offset, no
         # segments): m is a real score and l >= 1.
         m = jnp.max(st, axis=0, keepdims=True)  # [1, rows]
         pt = jnp.exp(st - m)
         l = jnp.sum(pt, axis=0, keepdims=True)
-        acc = _dot(vt[:, :k1], pt.astype(vt.dtype), 1, 0)  # [d, rows]
-        o_ref[0, r0:r1, :] = (acc / l).T.astype(o_ref.dtype)
+        acc = _dot(vt_ref[0, :, :k1], pt.astype(vt_ref.dtype), 1, 0)
+        ot_ref[0, :, r0:r1] = (acc / l).astype(ot_ref.dtype)  # [d, rows]
         lse_ref[0, :, r0:r1] = m + jnp.log(l)
 
 
-def _bwd_kernel_mono(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dlse_ref, dq_ref, dk_ref, dv_ref, *scratch, scale,
-                     causal):
-    """Fused single-pass backward: s and p are computed ONCE a chunk and
-    feed all three gradients (the blocked split recomputes them per pass).
-    dq is written once a chunk; dk/dv sum over the chunks in the fp32
-    `scratch` pair and are cast once (one chunk: written straight out,
-    and the call allocates no scratch)."""
-    s_q, s_k = q_ref.shape[1], k_ref.shape[1]
-    kt = k_ref[0].T  # [d, s_k]
-    seen = 0  # keys whose dk/dv rows hold a partial sum already
+def _bwd_kernel_mono(*refs, scale, causal, fused):
+    """Fused single-pass backward of one head, operands as the forward's:
+    s and p are computed ONCE a chunk and feed all three gradients (the
+    blocked split recomputes them per pass). dq^T is written once a
+    chunk; dk^T/dv^T sum over the chunks in the fp32 `scratch` pair and
+    are cast once (one chunk: written straight out, and the call
+    allocates no scratch)."""
+    (qt_ref, kt_ref, vt_ref), refs = _qkv_refs(refs, fused)
+    dot_ref, lse_ref, delta_ref, dlse_ref, *refs = refs
+    (dqt_ref, dkt_ref, dvt_ref), scratch = _qkv_refs(refs, fused)
+    s_q, s_k = qt_ref.shape[2], kt_ref.shape[2]
+    k, v = kt_ref[0].T, vt_ref[0].T  # [s_k, d]
+    seen = 0  # keys whose dk/dv columns hold a partial sum already
     for r0, r1, k1 in _mono_chunks(s_q, s_k, causal, _MONO_CHUNK_BWD):
-        q = q_ref[0, r0:r1, :]    # [rows, d] bf16
-        do = do_ref[0, r0:r1, :]
+        qt = qt_ref[0, :, r0:r1]    # [d, rows] bf16
+        dot = dot_ref[0, :, r0:r1]
         # Both score-shaped products first: the MXU runs the second while
         # the VPU is still on the first's softmax (measured: of four
         # orders this one was fastest at both head widths).
-        dpt = _dot(v_ref[0, :k1, :], do, 1, 1)            # [keys, rows]
-        st = _mono_scores(k_ref[0, :k1, :], q, r0, scale=scale, causal=causal)
+        dpt = _dot(v[:k1], dot, 1, 0)                     # [keys, rows]
+        st = _mono_scores(k[:k1], qt, r0, scale=scale, causal=causal)
         pt = jnp.exp(st - lse_ref[0, :, r0:r1])           # fp32
         # dL/ds = p∘(dp − delta + dlse); the two row terms meet first.
         shift = delta_ref[0, :, r0:r1] - dlse_ref[0, :, r0:r1]
-        dst = (pt * (dpt - shift) * scale).astype(q.dtype)
-        parts = (_dot(pt.astype(do.dtype), do, 1, 0), _dot(dst, q, 1, 0))
-        dq_ref[0, r0:r1, :] = _dot(kt[:, :k1], dst, 1, 0).T.astype(dq_ref.dtype)
-        for ref, acc, part in zip((dv_ref, dk_ref), scratch or (None,) * 2,
-                                  parts):                  # [keys, d] fp32
+        dst = (pt * (dpt - shift) * scale).astype(qt.dtype)
+        parts = (_dot(dot, pt.astype(dot.dtype), 1, 1), _dot(qt, dst, 1, 1))
+        dqt_ref[0, :, r0:r1] = _dot(kt_ref[0, :, :k1], dst, 1, 0).astype(
+            dqt_ref.dtype)
+        for ref, acc, part in zip((dvt_ref, dkt_ref), scratch or (None,) * 2,
+                                  parts):                  # [d, keys] fp32
             if acc is None:
-                ref[0, :k1, :] = part.astype(ref.dtype)
+                ref[0, :, :k1] = part.astype(ref.dtype)
                 continue
             if seen:
-                acc[:seen, :] += part[:seen]
+                acc[:, :seen] += part[:, :seen]
             if k1 > seen:
-                acc[seen:k1, :] = part[seen:]
+                acc[:, seen:k1] = part[:, seen:]
         seen = k1
-    for ref, scr in zip((dv_ref, dk_ref), scratch):
-        ref[0, :seen, :] = scr[:seen, :].astype(ref.dtype)
+    for ref, acc in zip((dvt_ref, dkt_ref), scratch):
+        ref[0, :, :seen] = acc[:, :seen].astype(ref.dtype)
     if seen < s_k:  # causal with more keys than queries: no row sees them
-        dk_ref[0, seen:, :] = jnp.zeros_like(dk_ref[0, seen:, :])
-        dv_ref[0, seen:, :] = jnp.zeros_like(dv_ref[0, seen:, :])
+        dkt_ref[0, :, seen:] = jnp.zeros_like(dkt_ref[0, :, seen:])
+        dvt_ref[0, :, seen:] = jnp.zeros_like(dvt_ref[0, :, seen:])
 
 
 def _bwd_fused_blocked_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -773,48 +761,83 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+def _mono_specs(h, d, s_q, s_k, fused):
+    """Block specs of the monolithic kernels, one head a step of the grid
+    (B, h). `head(s)`: head j's [d, s] rows of a [B, h*d, s] operand.
+    `qkv`: the specs of q^T, k^T, v^T or of their gradients, three such,
+    or with `fused` the one of a [B, 3, h*d, s] projection, a block of
+    which holds head j's rows of all three (`_qkv_refs`). `vec`: the
+    head's row of the per-row statistics [B*h, 1, s_q] (lse, delta, dlse:
+    lane vectors, as the blocked kernels keep them)."""
+    def head(s):
+        return pl.BlockSpec((1, d, s), lambda b, j: (b, j, 0))
+
+    qkv = [head(s_q), head(s_k), head(s_k)]
+    if fused:
+        qkv = [pl.BlockSpec((1, 3, d, s_q), lambda b, j: (b, 0, j, 0))]
+    vec = pl.BlockSpec((1, 1, s_q), lambda b, j: (b * h + j, 0, 0))
+    return qkv, head, vec
+
+
+def _qkv_refs(refs, fused):
+    """(the q^T, k^T, v^T blocks [1, d, s] at the head of `refs`, the
+    rest): three refs, or the three parts of one fused block."""
+    if fused:
+        return [refs[0].at[:, part] for part in range(3)], refs[1:]
+    return refs[:3], refs[3:]
+
+
 @functools.lru_cache(maxsize=None)
-def _mono_bwd_fn(bh, s_q, s_k, d, dtypes, scale, causal, interpret):
-    """The monolithic backward for one shape and one (q, k, v) dtype
-    triple, built once a process (as `_mono_fwd_fn`)."""
+def _mono_fwd_fn(b, h, d, s_q, s_k, fused, dtype, scale, causal, interpret):
+    """The monolithic forward for one shape, built once a process: every
+    layer of a model then calls the same jitted callable, so jax traces the
+    kernel body and lowers it to Mosaic once a program and not once a
+    layer. On the chip's host that is seconds of set-up, and the unrolled
+    chunks would have added to them (PERF.md section 6, PR 26)."""
+    qkv, head, vec = _mono_specs(h, d, s_q, s_k, fused)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel_mono, scale=scale, causal=causal,
+                          fused=fused),
+        grid=(b, h),
+        in_specs=qkv,
+        out_specs=[head(s_q), vec],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h * d, s_q), dtype),
+            jax.ShapeDtypeStruct((b * h, 1, s_q), jnp.float32),
+        ],
+        interpret=interpret,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _mono_bwd_fn(b, h, d, s_q, s_k, fused, dtype, scale, causal, interpret):
+    """The monolithic backward for one shape, built once a process (as
+    `_mono_fwd_fn`)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    row = pl.BlockSpec((1, s_q, d), lambda b: (b, 0, 0))
-    col = pl.BlockSpec((1, s_k, d), lambda b: (b, 0, 0))
-    vec = pl.BlockSpec((1, 1, s_q), lambda b: (b, 0, 0))
+    qkv, head, vec = _mono_specs(h, d, s_q, s_k, fused)
     n_chunks = len(_mono_chunks(s_q, s_k, causal, _MONO_CHUNK_BWD))
+    shapes = [(b, 3, h * d, s_q)] if fused else [
+        (b, h * d, s) for s in (s_q, s_k, s_k)]
     return pl.pallas_call(
-        functools.partial(
-            _bwd_kernel_mono, scale=scale, causal=causal
-        ),
-        grid=(bh,),
-        in_specs=[row, col, col, row, vec, vec, vec],
-        out_specs=[row, col, col],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), dtype)
-            for s, dtype in zip((s_q, s_k, s_k), dtypes)
-        ],
+        functools.partial(_bwd_kernel_mono, scale=scale, causal=causal,
+                          fused=fused),
+        grid=(b, h),
+        in_specs=qkv + [head(s_q), vec, vec, vec],
+        out_specs=qkv,
+        out_shape=[jax.ShapeDtypeStruct(shape, dtype) for shape in shapes],
         # dk and dv, summed over the chunks in fp32
-        scratch_shapes=[pltpu.VMEM((s_k, d), jnp.float32)]
+        scratch_shapes=[pltpu.VMEM((d, s_k), jnp.float32)]
         * (2 if n_chunks > 1 else 0),
         interpret=interpret,
     )
 
 
-def _mono_bwd_call(q, k, v, do, lse3, delta3, dlse3, *, scale, causal,
-                   interpret):
-    bh, s_q, d = q.shape
-    return _mono_bwd_fn(
-        bh, s_q, k.shape[1], d, (q.dtype, k.dtype, v.dtype), scale, causal,
-        interpret,
-    )(q, k, v, do, lse3, delta3, dlse3)
-
-
 def _flash_bwd_pallas(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
                       interpret=False, dlse=None, window=None, kv_offset=0,
                       segs=None):
-    """q/k/v/o/do: [BH, S, D], lse (+optional dlse): [BH, S] fp32 →
-    (dq, dk, dv)."""
+    """The blocked backward. q/k/v/o/do: [BH, S, D], lse (+optional dlse):
+    [BH, S] fp32 → (dq, dk, dv)."""
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s_q, d = q.shape
@@ -830,13 +853,6 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
     delta3 = delta.reshape(bh, 1, s_q)
     dlse3 = dlse.astype(jnp.float32).reshape(bh, 1, s_q)
     has_segments = segs is not None
-
-    if _mono_ok(s_q, s_k, block_q, block_k, window=window,
-                has_segments=has_segments, kv_offset=kv_offset):
-        return _mono_bwd_call(
-            q, k, v, do, lse3, delta3, dlse3,
-            scale=scale, causal=causal, interpret=interpret,
-        )
 
     qmap = functools.partial(
         _remap_q_index, block_q=block_q, block_k=block_k, causal=causal,
@@ -1201,6 +1217,66 @@ def _flash_lse_bwd(scale, causal, block_q, block_k, window, kv_offset, res,
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
+# -- the monolithic path: heads where the projections leave them ------------
+def _mono_shape(ops, h):
+    """(B, head width, S_q, S_k, fused) of `_mono_lse`'s operands."""
+    b, s_q = ops[0].shape[0], ops[0].shape[-1]
+    return b, ops[0].shape[-2] // h, s_q, ops[-1].shape[-1], len(ops) == 1
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def _mono_lse(ops, h, scale, causal):
+    """Differentiable (o^T, lse) of the monolithic kernels (on the chip
+    only: off it every call takes `_flash_lse`'s reference). `ops` is
+    (q^T, k^T, v^T), each [B, h*D, S] with the sequence on the lanes, or
+    the one-tuple (qkv^T,) [B, 3, h*D, S] of a fused projection, which the
+    kernels then read, and whose gradient they write, in place. That is
+    where XLA's TPU layouts put what a `bsd,dthk->bsthk` projection writes
+    and a `bshk,hkd->bsd` one reads, so nothing is copied on the way in or
+    out, forward or backward; the residuals are the operands themselves.
+    o^T is [B, h*D, S_q], lse [B*h, S_q] fp32."""
+    return _mono_fwd_pallas(ops, h=h, scale=scale, causal=causal,
+                            interpret=False)
+
+
+def _mono_lse_fwd(ops, h, scale, causal):
+    ot, lse = _mono_fwd_pallas(ops, h=h, scale=scale, causal=causal,
+                               interpret=False)
+    return (ot, lse), (ops, ot, lse)
+
+
+def _mono_lse_bwd(h, scale, causal, res, cts):
+    ops, ot, lse = res
+    dot, dlse = cts
+    return (tuple(_mono_bwd_pallas(ops, ot, lse, dot, dlse, h=h, scale=scale,
+                                   causal=causal, interpret=False)),)
+
+
+_mono_lse.defvjp(_mono_lse_fwd, _mono_lse_bwd)
+
+
+def _mono_fwd_pallas(ops, *, h, scale, causal, interpret):
+    """(o^T [B, h*D, S_q], lse [B*h, S_q]) of `_mono_lse`'s operands."""
+    b, d, s_q, s_k, fused = _mono_shape(ops, h)
+    ot, lse = _mono_fwd_fn(b, h, d, s_q, s_k, fused, ops[0].dtype, scale,
+                           causal, interpret)(*ops)
+    return ot, lse.reshape(b * h, s_q)
+
+
+def _mono_bwd_pallas(ops, ot, lse, dot, dlse, *, h, scale, causal, interpret):
+    """The gradients of `_mono_lse`'s operands, in their form."""
+    b, d, s_q, s_k, fused = _mono_shape(ops, h)
+    # rowsum(do ∘ o), a head's d rows at a time: [B*h, 1, S_q]
+    delta = jnp.sum(
+        (dot.astype(jnp.float32) * ot.astype(jnp.float32)).reshape(
+            b * h, d, s_q), axis=1, keepdims=True)
+    vec = (b * h, 1, s_q)
+    return _mono_bwd_fn(
+        b, h, d, s_q, s_k, fused, ops[0].dtype, scale, causal, interpret,
+    )(*ops, dot, lse.reshape(vec), delta,
+      dlse.astype(jnp.float32).reshape(vec))
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -1313,10 +1389,56 @@ def flash_attention_lse(
         kv_seg = kv_segment_ids if kv_segment_ids is not None else segment_ids
         segs = (fold_seg(segment_ids, s_q), fold_seg(kv_seg, s_k))
 
-    o, lse = _flash_lse(
-        fold(q), fold(k), fold(v), segs, scale, causal, block_q, block_k,
-        window, kv_offset,
-    )
-    o = o.reshape(b, h, s_q, d).transpose(0, 2, 1, 3)
+    if _use_pallas() and segs is None and _mono_ok(
+            s_q, s_k, block_q, block_k, window=window, kv_offset=kv_offset,
+    ) and _mono_tiles(d, q.dtype, k.dtype, v.dtype):
+        # One program a head, K/V resident; the dead upper triangle is
+        # skipped inside it by static row chunks (`_mono_chunks`). Skipping
+        # it from outside lost: two pallas calls with XLA glue
+        # (slice/concat/pad), and a 2-band grid with `pl.when` dispatch,
+        # each cost more than the quarter they saved. The autotuner still
+        # probes this candidate against the blocked ones.
+        ot, lse = _mono_lse(
+            tuple(x.transpose(0, 2, 3, 1).reshape(b, h * d, x.shape[1])
+                  for x in (q, k, v)), h, scale, causal)
+        o = ot.reshape(b, h, d, s_q).transpose(0, 3, 1, 2)
+    else:
+        o, lse = _flash_lse(
+            fold(q), fold(k), fold(v), segs, scale, causal, block_q, block_k,
+            window, kv_offset,
+        )
+        o = o.reshape(b, h, s_q, d).transpose(0, 2, 1, 3)
     lse = lse.reshape(b, h, s_q).transpose(0, 2, 1)
     return o, lse
+
+
+def flash_attention_qkv(
+    qkv: jax.Array,
+    *,
+    causal: bool = True,
+    scale: Optional[float] = None,
+    block_q: int = 512,
+    block_k: int = 512,
+    window: Optional[int] = None,
+    segment_ids: Optional[jax.Array] = None,
+) -> jax.Array:
+    """`flash_attention` of a fused projection qkv [B, S, 3, H, D] →
+    o [B, S, H, D]. Where the monolithic kernels take the call they read
+    q, k and v out of the one array in place and hand back one gradient
+    for it, so neither the three slices nor their gradient's assembly
+    exist as passes over memory; any other call slices and goes through
+    `flash_attention`."""
+    b, s, three, h, d = qkv.shape
+    assert three == 3, qkv.shape
+    bq, bk = min(block_q, s), min(block_k, s)
+    if _use_pallas() and segment_ids is None and _mono_ok(
+            s, s, bq, bk, window=window) and _mono_tiles(d, qkv.dtype):
+        ot, _ = _mono_lse(
+            (qkv.transpose(0, 2, 3, 4, 1).reshape(b, 3, h * d, s),), h,
+            scale if scale is not None else 1.0 / (d ** 0.5), causal)
+        return ot.reshape(b, h, d, s).transpose(0, 3, 1, 2)
+    return flash_attention(
+        qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], causal=causal, scale=scale,
+        block_q=block_q, block_k=block_k, window=window,
+        segment_ids=segment_ids,
+    )

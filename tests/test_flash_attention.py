@@ -123,6 +123,70 @@ def test_flash_pallas_interpret_matches():
         np.testing.assert_allclose(np.asarray(o), np.asarray(wf), atol=2e-5, rtol=2e-5)
 
 
+def _heads_to_rows(x, h):
+    """[BH, S, D] → [B, H*D, S], the monolithic kernels' operand."""
+    bh, s, d = x.shape
+    return x.transpose(0, 2, 1).reshape(bh // h, h * d, s)
+
+
+def _rows_to_heads(xt, h):
+    b, hd, s = xt.shape
+    return xt.reshape(b * h, hd // h, s).transpose(0, 2, 1)
+
+
+def _mono_case(s, causal, h, d, fused, b=1):
+    """The monolithic kernels (interpret mode) on [B, H*D, S] operands, or
+    on the one fused [B, 3, H*D, S] projection, against the blockwise
+    reference on [BH, S, D]: o, lse, and dq / dk / dv with an lse
+    cotangent (the path ring attention feeds)."""
+    from determined_tpu.ops.flash_attention import (
+        _blockwise_bwd_ref,
+        _blockwise_fwd_ref,
+        _mono_bwd_pallas,
+        _mono_fwd_pallas,
+        _mono_ok,
+        _mono_tiles,
+    )
+
+    assert _mono_ok(s, s, s, s) and _mono_tiles(d, jnp.float32)
+    q, k, v = _rand_qkv(jax.random.PRNGKey(7), b, s, h, d)
+    qf, kf, vf = (x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+                  for x in (q, k, v))
+    scale = 1.0 / d ** 0.5
+    if fused:  # as GPT's `bsd,dthk->bsthk` leaves it, sequence last
+        ops = (jnp.stack([q, k, v], axis=2).transpose(0, 2, 3, 4, 1)
+               .reshape(b, 3, h * d, s),)
+    else:
+        ops = tuple(_heads_to_rows(x, h) for x in (qf, kf, vf))
+
+    ot, lse = _mono_fwd_pallas(ops, h=h, scale=scale, causal=causal,
+                               interpret=True)
+    o_want, lse_want = _blockwise_fwd_ref(
+        qf, kf, vf, scale=scale, causal=causal, block_k=16
+    )
+    np.testing.assert_allclose(np.asarray(_rows_to_heads(ot, h)),
+                               np.asarray(o_want), atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_want),
+                               atol=2e-5, rtol=2e-5)
+
+    do = jax.random.normal(jax.random.PRNGKey(8), qf.shape)
+    dlse = jax.random.normal(jax.random.PRNGKey(9), lse.shape)
+    want = _blockwise_bwd_ref(qf, kf, vf, o_want, lse_want, do, scale=scale,
+                              causal=causal, block_k=16, dlse=dlse)
+    got = _mono_bwd_pallas(ops, _heads_to_rows(o_want, h), lse_want,
+                           _heads_to_rows(do, h), dlse, h=h, scale=scale,
+                           causal=causal, interpret=True)
+    if fused:
+        (got,) = got
+        assert got.shape == ops[0].shape
+        got = [got[:, part] for part in range(3)]
+    for name, a, b_ in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(
+            np.asarray(_rows_to_heads(a, h)), np.asarray(b_), atol=5e-5,
+            rtol=5e-5, err_msg=name,
+        )
+
+
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("s", [64, 128, 256, 384, 512, 640, 1024])
 def test_flash_pallas_monolithic_interpret_matches(s, causal):
@@ -133,46 +197,76 @@ def test_flash_pallas_monolithic_interpret_matches(s, causal):
     chunks (512 rows forward, 256 backward): 64 to 256 are one chunk in
     both passes, 384 has a ragged last chunk backward (256 + 128), 640
     one forward (512 + 128), 1024 is the benchmark's 2 and 4."""
-    from determined_tpu.ops.flash_attention import (
-        _blockwise_bwd_ref,
-        _blockwise_fwd_ref,
-        _flash_bwd_pallas,
-        _flash_fwd_pallas,
-        _mono_ok,
-    )
+    _mono_case(s, causal, h=2, d=16, fused=False)
 
-    b, h, d = 1, 2, 16
-    assert _mono_ok(s, s, s, s)
-    q, k, v = _rand_qkv(jax.random.PRNGKey(7), b, s, h, d)
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    scale = 1.0 / d ** 0.5
 
-    o, lse = _flash_fwd_pallas(
-        qf, kf, vf, scale=scale, causal=causal,
-        block_q=s, block_k=s, interpret=True,
-    )
-    o_want, lse_want = _blockwise_fwd_ref(
-        qf, kf, vf, scale=scale, causal=causal, block_k=16
-    )
-    np.testing.assert_allclose(np.asarray(o), np.asarray(o_want),
-                               atol=2e-5, rtol=2e-5)
-    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_want),
-                               atol=2e-5, rtol=2e-5)
+@pytest.mark.parametrize("causal,fused", [(True, True), (False, False)],
+                         ids=["causal-fused", "whole-split"])
+@pytest.mark.parametrize("s,h,d", [
+    (256, 12, 64), (1024, 12, 64),    # GPT-2 small's heads
+    (256, 4, 128), (1024, 4, 128),    # a head that fills the lanes
+    (256, 2, 64), (1024, 2, 64),
+    (256, 25, 64),                    # GPT-2 XL: 1600 rows, no 128 divides
+])
+def test_flash_mono_reads_heads_where_the_projection_leaves_them(
+        s, h, d, causal, fused):
+    """A head is d rows of [B, H*D, S] (or of each part of the fused
+    [B, 3, H*D, S]): any head count tiles, 25 x 64 as well as 12 x 64."""
+    _mono_case(s, causal, h, d, fused)
 
-    do = jax.random.normal(jax.random.PRNGKey(8), qf.shape)
-    dlse = jax.random.normal(jax.random.PRNGKey(9), lse.shape)
-    want = _blockwise_bwd_ref(qf, kf, vf, o_want, lse_want, do, scale=scale,
-                              causal=causal, block_k=16, dlse=dlse)
-    got = _flash_bwd_pallas(qf, kf, vf, o_want, lse_want, do, scale=scale,
-                            causal=causal, block_q=s, block_k=s,
-                            interpret=True, dlse=dlse)
-    for name, a, b_ in zip(("dq", "dk", "dv"), got, want):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b_), atol=5e-5, rtol=5e-5,
-            err_msg=name,
-        )
+
+@pytest.mark.parametrize("fused", [False, True], ids=["split", "fused"])
+def test_flash_mono_public_path_differentiates_o_and_lse(monkeypatch, fused):
+    """`flash_attention_lse` / `flash_attention_qkv` end to end through the
+    monolithic kernels (interpret mode), gradients of a loss that reads o
+    AND lse (ring attention's contract) against the dense reference; a
+    head width the kernels cannot tile (12: not whole sublane tiles) takes
+    the folded [BH, S, D] kernels and agrees too."""
+    import importlib
+
+    fa = importlib.import_module("determined_tpu.ops.flash_attention")
+    calls = []
+
+    def interpreted(fn):
+        def call(*args, interpret=False, **kw):
+            calls.append(fn.__name__)
+            return fn(*args, interpret=True, **kw)
+        return call
+
+    for name in ("_mono_fwd_pallas", "_mono_bwd_pallas", "_flash_fwd_pallas",
+                 "_flash_bwd_pallas"):
+        monkeypatch.setattr(fa, name, interpreted(getattr(fa, name)))
+    monkeypatch.setattr(fa, "_use_pallas", lambda: True)
+    b, s, h = 2, 128, 3
+
+    def ref_lse(q, k, v):
+        sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) / q.shape[-1] ** 0.5
+        sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc, -jnp.inf)
+        return jax.nn.logsumexp(sc, axis=-1).transpose(0, 2, 1)
+
+    for d, kernels in ((16, "_mono"), (12, "_flash")):
+        del calls[:]
+        q, k, v = _rand_qkv(jax.random.PRNGKey(d), b, s, h, d)
+
+        def loss(q, k, v):
+            if fused:
+                o = fa.flash_attention_qkv(
+                    jnp.stack([q, k, v], axis=2), block_q=s, block_k=s)
+                return jnp.sum(o ** 2)
+            o, lse = fa.flash_attention_lse(q, k, v, block_q=s, block_k=s)
+            return jnp.sum(o ** 2) + jnp.sum(jnp.sin(lse))
+
+        def want(q, k, v):
+            o = reference_attention(q, k, v, causal=True)
+            extra = 0.0 if fused else jnp.sum(jnp.sin(ref_lse(q, k, v)))
+            return jnp.sum(o ** 2) + extra
+
+        got = jax.grad(loss, (0, 1, 2))(q, k, v)
+        assert calls and all(c.startswith(kernels) for c in calls), calls
+        for name, a, b_ in zip("qkv", got, jax.grad(want, (0, 1, 2))(q, k, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       atol=1e-4, rtol=1e-4,
+                                       err_msg=f"d{name} at d={d}")
 
 
 # ---------------------------------------------------------------------------

@@ -192,6 +192,15 @@ def _gpt_grad(mesh, layer_loop):
     return grad, params, jax.ShapeDtypeStruct((4, 256), jnp.int32)
 
 
+def _gpt_grad_text(mesh, layer_loop, sharding):
+    """`_gpt_grad`'s program as compiled for the described chips."""
+    grad, params, tokens = _gpt_grad(mesh, layer_loop)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        (params, tokens))
+    return jax.jit(grad).lower(*args).compile().as_text()
+
+
 @pytest.mark.parametrize("n_chips,layer_loop,want", [
     (1, "unroll", {"flash_forward", "flash_backward"}),   # small-train-1k
     (4, "scan", {"flash_sharded"}),                       # xl-train-fsdp4
@@ -214,11 +223,7 @@ def test_flash_kernels_keep_the_names_the_benchmark_matches(
     else:
         mesh = make_mesh(MeshConfig(data=1, fsdp=n_chips), chips)
         sharding = NamedSharding(mesh, PartitionSpec())
-    grad, params, tokens = _gpt_grad(mesh, layer_loop)
-    args = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
-        (params, tokens))
-    text = jax.jit(grad).lower(*args).compile().as_text()
+    text = _gpt_grad_text(mesh, layer_loop, sharding)
     # as the benchmark prints a trace's events: `mosaic:<instruction>`
     ops = {trace_reduce.op_name(line.strip()) for line in text.splitlines()
            if trace_reduce.MOSAIC in line}
@@ -229,3 +234,52 @@ def test_flash_kernels_keep_the_names_the_benchmark_matches(
              for op in ops}
     assert all(found.values()), found
     assert set().union(*found.values()) == want, found
+
+
+# -- what stands between the projections and the flash kernels ---------------
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = .*? ([a-z][a-z\-]*)\(((?:%|\)).*)$")
+_FREE = ("bitcast", "reshape", "get-tuple-element")   # move no data
+
+
+def _layout_ops_at_kernels(text):
+    """The `copy` / `transpose` instructions of a compiled program's entry
+    computation that feed a Mosaic kernel or read what one wrote, looking
+    through the instructions that move no data."""
+    from benchmark import trace_reduce
+
+    entry = text[text.index("ENTRY "):]
+    ops, operands = {}, {}
+    for line in entry.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m:
+            name, op, rest = m.groups()
+            ops[name] = "kernel" if trace_reduce.MOSAIC in line else op
+            operands[name] = re.findall(r"%([\w.\-]+)", rest.split(")")[0])
+    users = {}
+    for name, args in operands.items():
+        for arg in args:
+            users.setdefault(arg, []).append(name)
+
+    def reach(name, edges):
+        for nxt in edges.get(name, ()):
+            if ops.get(nxt) in _FREE:
+                yield from reach(nxt, edges)
+            elif ops.get(nxt) in ("copy", "transpose"):
+                yield nxt
+
+    return sorted({found for name, op in ops.items() if op == "kernel"
+                   for edges in (operands, users)
+                   for found in reach(name, edges)})
+
+
+def test_nothing_is_copied_between_projections_and_flash_kernels(chips):
+    """GPT's attention half hands the monolithic kernels its fused
+    projection as XLA lays it out ([B, 3, H*D, S]: the sequence on the
+    lanes) and takes o^T and the one gradient back the same way, so the
+    compiled train step has no layout copy at a flash kernel. Through PR
+    26 it had eight a layer (q, k, v in and o out of [BH, S, D], and the
+    same for their gradients): 16 in this two-layer program."""
+    text = _gpt_grad_text(None, "unroll", SingleDeviceSharding(chips[0]))
+    assert text.count("tpu_custom_call") == 4, "two layers, two passes"
+    assert _layout_ops_at_kernels(text) == []
