@@ -1,7 +1,7 @@
 """Where compiled programs and tuned kernel shapes are kept.
 
 Every entry point that compiles for the device — the trial harness, the
-serving service, `bench.py`, `chip_smoke.py`'s children — calls
+serving service, the benchmark's drivers, `chip_smoke.py`'s children — calls
 `enable()` once before its first jit. The directory, in order:
 
 1. `JAX_COMPILATION_CACHE_DIR`: jax reads it by itself, so nothing is set

@@ -31,8 +31,8 @@ transparent retry — so an admission shed (429 + Retry-After,
 master/overload.py) is COUNTED as outcome="shed" rather than absorbed,
 and ``retry_after_seen`` in the report proves the header contract.
 
-CLI: `dtpu loadtest run|report` (cli/cli.py). Bench: control_plane_rung
-(bench.py). Scenario-mix config and verdict semantics:
+CLI: `dtpu loadtest run|report` (cli/cli.py). Scenario-mix config and
+verdict semantics:
 docs/operations.md "Load harness & overload control".
 """
 from __future__ import annotations

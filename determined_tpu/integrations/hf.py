@@ -88,7 +88,7 @@ class HFFlaxModel(Model):
 
 class HFFlaxClassifier(Model):
     """Flax transformers sequence classifier as a platform Model — the
-    BERT-fine-tune rung of BASELINE.md's platform ladder (mnist → cifar →
+    BERT-fine-tune rung of the platform ladder (mnist → cifar →
     **BERT fine-tune** → GPT-2 dtrain → GPT-NeoX FSDP). Config-built
     (random init, offline) or from_pretrained where weights are local.
 

@@ -46,6 +46,22 @@ def _resolve_impl(impl: str, mesh: Optional[Mesh], seq: int) -> str:
     return "dense"
 
 
+def _check_impl(impl: str, layout: str, window, segment_ids) -> None:
+    """What an implementation cannot do, for both dispatchers below."""
+    if layout == "zigzag" and impl != "ring":
+        raise ValueError(
+            "layout='zigzag' requires ring attention (a sharded context "
+            f"axis); resolved impl is {impl!r} — dense/flash causal masks "
+            "assume contiguous order, and ulysses re-gathers the full "
+            "sequence: either would be silently wrong"
+        )
+    if impl == "ulysses" and (window is not None or segment_ids is not None):
+        raise ValueError(
+            "window/segment_ids are not supported with ulysses "
+            "attention; use ring (sharded context) or flash/dense"
+        )
+
+
 def _flash(ops, *, mesh, causal, block_q, block_k, window, segment_ids):
     """The flash kernels over `ops`: (q, k, v), each [B, S, H, D], or the
     one-tuple (qkv,) [B, S, 3, H, D] of a fused projection."""
@@ -131,9 +147,10 @@ def attention(
     impl: "auto" | "dense" | "flash" | "ring". "auto" selects ring when the
     mesh's context axis is sharded, flash on TPU, dense elsewhere.
     block_q/block_k: flash kernel tile sizes, fitted down to divisors of the
-    sequence as needed. GPTConfig tunes these (1024/1024 measured best for
-    the GPT-2 bench on v5e, or the autotuner's probed winner with
-    flash_autotune on); 512 is a neutral default for direct callers.
+    sequence as needed. GPTConfig sets these (1024/1024: equal to GPT-2's
+    sequence, which selects the monolithic kernels; or the autotuner's
+    probed winner with flash_autotune on); 512 is a neutral default for
+    direct callers.
     layout: "zigzag" = the sequence dim is ALREADY in zigzag device order
     (data/tokens.py native emission) — only the ring impl understands that
     placement, and it then runs gather-free.
@@ -144,13 +161,7 @@ def attention(
     within equal ids.
     """
     impl = _resolve_impl(impl, mesh, q.shape[1])
-
-    if layout == "zigzag" and impl != "ring":
-        raise ValueError(
-            "layout='zigzag' requires ring attention (a sharded context "
-            f"axis); resolved impl is {impl!r} — dense/flash causal masks "
-            "assume contiguous order and would be silently wrong"
-        )
+    _check_impl(impl, layout, window, segment_ids)
 
     if impl == "dense":
         return reference_attention(
@@ -184,11 +195,6 @@ def attention(
         # (determined_tpu.parallel.ulysses). Heads stay sharded over tensor
         # like the other impls — omitting it would silently replicate
         # activations across the tensor axis.
-        if window is not None or segment_ids is not None:
-            raise ValueError(
-                "window/segment_ids are not supported with ulysses "
-                "attention; use ring (sharded context) or flash/dense"
-            )
         if mesh is None:
             raise ValueError("ulysses attention needs a mesh")
         ctx = mesh.shape.get("context", 1)
@@ -213,3 +219,45 @@ def attention(
         )(q, k, v)
 
     raise ValueError(f"unknown attention impl {impl!r}")
+
+
+def attention_manual(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    mesh: Optional[Mesh] = None,
+    causal: bool = True,
+    impl: str = "auto",
+    block_q: int = 512,
+    block_k: int = 512,
+    layout: str = "contiguous",
+    window: Optional[int] = None,
+) -> jax.Array:
+    """`attention` from INSIDE a shard_map manual region (a pipeline
+    stage, parallel/pipeline.py): q/k/v are the local shards and no
+    shard_map may be nested, so the sequence-parallel impls are called
+    per shard over the manual ``context`` axis and nothing else is left
+    but dense.
+
+    With a sharded context axis the pipeline's shard_map is manual on
+    BOTH axes and each stage attends over its sequence shard directly:
+    ring by default (and mandatory for zigzag layouts), ulysses when
+    `impl` names it. Without one: dense (contiguous order only).
+    """
+    ctx = mesh.shape.get("context", 1) if mesh is not None else 1
+    if ctx == 1:
+        impl = "dense"
+    elif impl != "ulysses":
+        impl = "ring"
+    _check_impl(impl, layout, window, None)
+    if impl == "dense":
+        return reference_attention(q, k, v, causal=causal, window=window)
+    if impl == "ulysses":
+        from determined_tpu.parallel.ulysses import ulysses_attention
+
+        return ulysses_attention(q, k, v, axis_name="context", causal=causal)
+    return ring_attention(
+        q, k, v, axis_name="context", causal=causal, block_q=block_q,
+        block_k=block_k, window=window, layout=layout,
+    )

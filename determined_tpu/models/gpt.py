@@ -13,10 +13,9 @@ a torch model:
   config surface (pytorch/deepspeed/_mpu.py).
 - blocks are stacked along a leading `layers` axis and applied either
   unrolled (default up to 24 layers: XLA keeps backward residuals live
-  instead of stashing them into [L, ...] buffers — +21% tokens/s on the
-  GPT-2 bench) or with `lax.scan` (one compiled block program regardless
-  of depth; ASHA searches re-use the compilation cache across rungs) —
-  the `layer_loop` knob.
+  instead of stashing them into [L, ...] buffers) or with `lax.scan` (one
+  compiled block program regardless of depth; ASHA searches re-use the
+  compilation cache across rungs) — the `layer_loop` knob.
 - attention dispatches to the Pallas flash kernel or ring attention via
   determined_tpu.models.attention; matmuls run in bfloat16 with fp32 master
   params and fp32 layernorm/softmax.
@@ -58,10 +57,6 @@ class GPTConfig:
     # whole forward kernel when rematted — saving them (~60MB/layer at the
     # bench shapes) is far cheaper than the recompute (~8ms/step).
     remat_attention: bool = False
-    #: lax.scan unroll factor over the layer stack: >1 lets XLA overlap
-    #: consecutive blocks' HBM prefetch with MXU work at the cost of a
-    #: proportionally larger program (compile time + icache).
-    scan_unroll: int = 1
     # How the (non-pipelined) trunk iterates its layer stack:
     #   "scan"   — lax.scan over stacked [L, ...] weights: one compiled
     #              block regardless of depth (compile-time win; the original
@@ -85,18 +80,18 @@ class GPTConfig:
     #              scale with S).
     layer_loop: str = "auto"
     attn_impl: str = "auto"            # see models.attention
-    # Flash kernel tile sizes. 1024/1024 measured best on v5e for the GPT-2
-    # bench shapes (43.0% vs 41.6% MFU at 512/512; sweep in BENCH notes) —
-    # larger tiles amortize the scratch init/epilogue and keep the MXU fed;
-    # the kernel clamps to the sequence when shorter.
+    # Flash kernel tile sizes, fitted down to the sequence where it is
+    # shorter. A tile equal to the sequence (1024 at GPT-2's context) is
+    # what selects the monolithic kernels, the ones both training cells
+    # run (ops/flash_attention.py `_mono_ok`; ROADMAP D3).
     flash_block_q: int = 1024
     flash_block_k: int = 1024
     # Replace the constants above with a measurement: probe a small
     # candidate set (ops/flash_autotune.py) at this config's exact
     # attention shapes ONCE at model-build time (outside jit; the winner is
     # cached on disk per device kind / jax version / shape / mask mode).
-    # Off by default so the measured-best bench constants stay the bench
-    # constants; long-context recipes turn it on. Off-TPU this is a no-op.
+    # Off by default and on in no cell of the benchmark (ROADMAP D3);
+    # off-TPU it is a no-op.
     flash_autotune: bool = False
     # Sliding-window attention: position p attends (p − attn_window, p].
     # None = full causal. The flash kernels skip out-of-band blocks
@@ -206,7 +201,7 @@ class GPT(Model):
         # winner (flash_autotune). Resolved EAGERLY here because the probe
         # runs real device work, which must not happen mid-trace when the
         # train step first calls into attention — model build
-        # (trial.build_model / bench setup) is always outside jit.
+        # (trial.build_model) is always outside jit.
         self._resolved_flash_blocks: Optional[Tuple[int, int]] = None
         if config.flash_autotune:
             self._flash_blocks()
@@ -385,113 +380,73 @@ class GPT(Model):
         aux = e * jnp.sum(frac * mean_gate)
         return y.reshape(b, s, d), aux
 
-    def _block(
-        self, x: jax.Array, blk: Dict[str, jax.Array], *, manual: bool = False,
+    def _attend(
+        self, *, manual: bool = False,
         segment_ids: Optional[jax.Array] = None,
+    ):
+        """Training's `attend` for `_attn_half`: the dispatcher of
+        models/attention.py over this config's implementation, tiles,
+        layout and window. `manual` = inside a shard_map manual region
+        (a pipeline stage), where the dispatcher is `attention_manual`
+        and takes the three slices."""
+        c = self.config
+        block_q, block_k = self._flash_blocks()
+        how = dict(
+            mesh=self.mesh, causal=True, impl=c.attn_impl, block_q=block_q,
+            block_k=block_k, layout=c.sequence_layout, window=c.attn_window,
+        )
+        if not manual:
+            return functools.partial(
+                attn_mod.attention_qkv, segment_ids=segment_ids, **how
+            )
+
+        def attend(qkv):
+            with jax.named_scope("attn"):
+                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            return attn_mod.attention_manual(q, k, v, **how)
+
+        return attend
+
+    def _block(
+        self, x: jax.Array, blk: Dict[str, jax.Array], attend, *,
+        manual: bool = False,
     ) -> Tuple[jax.Array, jax.Array]:
         """One transformer block → (x, moe_aux). `manual` = running inside a
         shard_map manual region (pipeline stage): no sharding constraints, no
-        nested shard_map (dense attention)."""
-        x = self._attn_half(x, blk, manual=manual, segment_ids=segment_ids)
+        nested shard_map (`attention_manual`)."""
+        x = self._attn_half(x, blk, attend, manual=manual)
         return self._mlp_half(x, blk, manual=manual)
 
     def _attn_half(
-        self, x: jax.Array, blk: Dict[str, jax.Array], *, manual: bool = False,
-        segment_ids: Optional[jax.Array] = None,
+        self, x: jax.Array, blk: Dict[str, jax.Array], attend, *,
+        manual: bool = False,
     ) -> jax.Array:
-        """The `attn` scope is opened around the projections and closed
+        """LayerNorm → fused QKV projection → `attend` → output projection
+        → residual: the one attention half, for training and serving alike.
+        `attend(qkv) -> o` is the attention between the projections, qkv
+        [B, S, 3, H, D] the fused projection, o [B, S, H, D]: training's
+        is `_attend`, each serving entry point brings its own (which is
+        also where it takes the layer's K/V).
+
+        The `attn` scope is opened around the projections and closed
         around the attention call between them: a Pallas kernel's name in
         a trace is the innermost component of its name stack, and the
         flash kernels keep the ones they have under no scope
         (`ops/flash_attention.py`)."""
         c = self.config
-        block_q, block_k = self._flash_blocks()
-        act_spec = P(("data", "fsdp"), "context", None)
-
         with jax.named_scope("attn"):
             h = _layernorm(x, blk["ln1_scale"], blk["ln1_bias"])
             qkv = (
                 jnp.einsum("bsd,dthk->bsthk", h, blk["wqkv"].astype(c.dtype))
                 + blk["bqkv"].astype(c.dtype)
             )
-        if manual:
-            with jax.named_scope("attn"):
-                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            ctx = (
-                self.mesh.shape.get("context", 1)
-                if self.mesh is not None else 1
-            )
-            if ctx > 1:
-                # Pipeline × sequence parallelism: the pipeline shard_map is
-                # manual on BOTH axes, so each stage runs sequence-parallel
-                # attention over its seq shard directly. Ring by default
-                # (and mandatory for zigzag layouts — Ulysses re-gathers
-                # the full sequence per head subset and its dense causal
-                # mask assumes contiguous order); Ulysses when configured.
-                if c.attn_impl == "ulysses":
-                    if c.sequence_layout == "zigzag":
-                        # Same error the non-pipeline dispatcher raises
-                        # (attention.py): silently overriding an explicit
-                        # impl choice hides a misconfiguration.
-                        raise ValueError(
-                            "layout='zigzag' requires ring attention; "
-                            "Ulysses re-gathers the full sequence and its "
-                            "dense causal mask assumes contiguous order"
-                        )
-                    if c.attn_window is not None:
-                        # Same guard the dispatcher enforces: ulysses has
-                        # no window support, and this manual path bypasses
-                        # the dispatcher.
-                        raise ValueError(
-                            "attn_window is not supported with ulysses "
-                            "attention"
-                        )
-                    from determined_tpu.parallel.ulysses import (
-                        ulysses_attention,
-                    )
-
-                    o = ulysses_attention(
-                        q, k, v, axis_name="context", causal=True
-                    )
-                else:
-                    from determined_tpu.parallel.ring import ring_attention
-
-                    o = ring_attention(
-                        q, k, v, axis_name="context", causal=True,
-                        block_q=block_q, block_k=block_k,
-                        window=c.attn_window,
-                        layout=(
-                            "zigzag" if c.sequence_layout == "zigzag"
-                            else "contiguous"
-                        ),
-                    )
-            else:
-                if c.sequence_layout == "zigzag":
-                    # Same guard the attention dispatcher enforces: a dense
-                    # causal mask over zigzag-PERMUTED order is silently
-                    # wrong, and this manual path bypasses the dispatcher.
-                    raise ValueError(
-                        "sequence_layout='zigzag' inside a pipeline needs "
-                        "a sharded context axis (ring attention); dense "
-                        "causal attention assumes contiguous order"
-                    )
-                o = attn_mod.attention(
-                    q, k, v, mesh=None, causal=True, impl="dense",
-                    window=c.attn_window,
-                )
-        else:
-            o = attn_mod.attention_qkv(
-                qkv, mesh=self.mesh, causal=True, impl=c.attn_impl,
-                block_q=block_q, block_k=block_k,
-                layout=c.sequence_layout, window=c.attn_window,
-                segment_ids=segment_ids,
-            )
+        o = attend(qkv)
         with jax.named_scope("attn"):
             o = jnp.einsum("bshk,hkd->bsd", o, blk["wo"].astype(c.dtype))
             o = o + blk["bo"].astype(c.dtype)
             x = x + o
             if not manual:
-                x = self._constrain(x, act_spec)
+                x = self._constrain(x, P(("data", "fsdp"), "context", None))
         return x
 
     @jax.named_scope("mlp")
@@ -572,7 +527,9 @@ class GPT(Model):
         stage_fn for every pipeline schedule (see the fp32 carry note in
         _apply_pipelined)."""
         c = self.config
-        block_fn = functools.partial(self._block, manual=True)
+        block_fn = functools.partial(
+            self._block, attend=self._attend(manual=True), manual=True
+        )
         if c.remat:
             block_fn = jax.checkpoint(block_fn, policy=_remat_policy())
 
@@ -700,21 +657,17 @@ class GPT(Model):
         remat_attn = c.remat_attention or (
             c.layer_loop == "auto" and c.seq_len > 16384
         )
+        attend = self._attend(segment_ids=segment_ids)
         if c.remat and not remat_attn:
-            attn_fn = functools.partial(
-                self._attn_half, manual=False, segment_ids=segment_ids
-            )
             mlp_fn = jax.checkpoint(
                 functools.partial(self._mlp_half, manual=False),
                 policy=_remat_policy(),
             )
 
             def block_fn(x, blk):
-                return mlp_fn(attn_fn(x, blk), blk)
+                return mlp_fn(self._attn_half(x, blk, attend), blk)
         else:
-            block_fn = functools.partial(
-                self._block, manual=False, segment_ids=segment_ids
-            )
+            block_fn = functools.partial(self._block, attend=attend)
             if c.remat:
                 block_fn = jax.checkpoint(block_fn, policy=_remat_policy())
 
@@ -741,8 +694,7 @@ class GPT(Model):
             return (x, aux + blk_aux), None
 
         (x, aux), _ = lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), params["blocks"],
-            unroll=c.scan_unroll,
+            body, (x, jnp.zeros((), jnp.float32)), params["blocks"]
         )
         return x, aux
 
@@ -904,11 +856,35 @@ class GPT(Model):
     # -- serving: kv-cache-aware forward ------------------------------------
     # The generation service (determined_tpu/serving) runs two step shapes,
     # both static so the engine never recompiles as requests come and go:
-    # a packed prefill over pack_sequences batches, and a single-token
-    # decode over a paged KV pool. Both lean on the flash kernels' masking
-    # model — segment_ids isolate packed prompts, and decode runs
-    # causal + kv_offset (the bottom-aligned short-q geometry) with
-    # segment masking trimming each row's dead cache tail.
+    # a packed prefill over pack_sequences batches, and a decode over a
+    # paged KV pool (one query row per slot, or a draft's worth). Both lean
+    # on the flash kernels' masking model — segment_ids isolate packed
+    # prompts, and decode runs causal + kv_offset (the bottom-aligned
+    # short-q geometry) with segment masking trimming each row's dead
+    # cache tail. Every entry point is `_serve` (the trunk: the blocks
+    # training runs) around an `attend` of its own, which is also where
+    # it takes each layer's K/V.
+    def _serve(
+        self,
+        params: Dict[str, Any],
+        tokens: jax.Array,
+        positions: jax.Array,
+        attend,
+    ) -> jax.Array:
+        """Embed at explicit `positions` [B, S], run every block with
+        `attend(i, qkv) -> o` as layer i's attention, → logits [B, S, V]
+        (compute dtype). No sharding constraints are applied: serving
+        replicas are single-device (mesh=None, `_constrain` a no-op)."""
+        c = self.config
+        if c.pipeline_stages > 1:
+            raise ValueError("serving does not support pipeline stages")
+        x = self._embed(params, tokens, positions)
+        for i in range(c.n_layers):
+            blk = jax.tree_util.tree_map(lambda a, i=i: a[i], params["blocks"])
+            x = self._attn_half(x, blk, functools.partial(attend, i))
+            x, _aux = self._mlp_half(x, blk)
+        return self._head(params, x)
+
     def prefill_kv(
         self,
         params: Dict[str, Any],
@@ -932,34 +908,21 @@ class GPT(Model):
         constraints: serving replicas are single-device (mesh=None).
         """
         c = self.config
-        if c.pipeline_stages > 1:
-            raise ValueError("prefill_kv does not support pipeline stages")
-        b, s = tokens.shape
-        x = (
-            params["tok_embed"].astype(c.dtype)[tokens]
-            + params["pos_embed"].astype(c.dtype)[positions]
-        )
+        s = tokens.shape[1]
         bq = fit_block(s, c.flash_block_q)
         bk = fit_block(s, c.flash_block_k)
         ks, vs = [], []
-        for i in range(c.n_layers):
-            blk = jax.tree_util.tree_map(lambda a, i=i: a[i], params["blocks"])
-            h = _layernorm(x, blk["ln1_scale"], blk["ln1_bias"])
-            qkv = (
-                jnp.einsum("bsd,dthk->bsthk", h, blk["wqkv"].astype(c.dtype))
-                + blk["bqkv"].astype(c.dtype)
-            )
+
+        def attend(i, qkv):
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             ks.append(k)
             vs.append(v)
-            o = flash_attention(
+            return flash_attention(
                 q, k, v, causal=True, block_q=bq, block_k=bk,
                 segment_ids=segment_ids,
             )
-            o = jnp.einsum("bshk,hkd->bsd", o, blk["wo"].astype(c.dtype))
-            x = x + o + blk["bo"].astype(c.dtype)
-            x, _aux = self._mlp_half(x, blk, manual=False)
-        logits = self._head(params, x)
+
+        logits = self._serve(params, tokens, positions, attend)
         return logits, jnp.stack(ks), jnp.stack(vs)
 
     def prefill_kv_cached(
@@ -997,165 +960,29 @@ class GPT(Model):
         greedy streams are identical to the cache-off path.
         """
         c = self.config
-        if c.pipeline_stages > 1:
-            raise ValueError(
-                "prefill_kv_cached does not support pipeline stages"
-            )
-        b, s = tokens.shape
+        s = tokens.shape[1]
         sp = prefix_k.shape[2]
-        x = (
-            params["tok_embed"].astype(c.dtype)[tokens]
-            + params["pos_embed"].astype(c.dtype)[positions]
-        )
         bq = fit_block(s, c.flash_block_q)
         bk = fit_block(sp + s, c.flash_block_k)
         kv_seg = jnp.concatenate([prefix_seg, segment_ids], axis=1)
         ks, vs = [], []
-        for i in range(c.n_layers):
-            blk = jax.tree_util.tree_map(lambda a, i=i: a[i], params["blocks"])
-            h = _layernorm(x, blk["ln1_scale"], blk["ln1_bias"])
-            qkv = (
-                jnp.einsum("bsd,dthk->bsthk", h, blk["wqkv"].astype(c.dtype))
-                + blk["bqkv"].astype(c.dtype)
-            )
+
+        def attend(i, qkv):
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             ks.append(k)
             vs.append(v)
-            o = flash_attention(
+            return flash_attention(
                 q,
                 jnp.concatenate([prefix_k[i].astype(k.dtype), k], axis=1),
                 jnp.concatenate([prefix_v[i].astype(v.dtype), v], axis=1),
                 causal=True, kv_offset=sp, block_q=bq, block_k=bk,
                 segment_ids=segment_ids, kv_segment_ids=kv_seg,
             )
-            o = jnp.einsum("bshk,hkd->bsd", o, blk["wo"].astype(c.dtype))
-            x = x + o + blk["bo"].astype(c.dtype)
-            x, _aux = self._mlp_half(x, blk, manual=False)
-        logits = self._head(params, x)
+
+        logits = self._serve(params, tokens, positions, attend)
         return logits, jnp.stack(ks), jnp.stack(vs)
 
     def decode_kv(
-        self,
-        params: Dict[str, Any],
-        last_tokens: jax.Array,
-        lengths: jax.Array,
-        active: jax.Array,
-        cache_k: jax.Array,
-        cache_v: jax.Array,
-        page_table: jax.Array,
-        *,
-        q_pad: int = 1,
-        kernel: str = "gather",
-        block_h: Optional[int] = None,
-        interpret: bool = False,
-    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-        """One iteration-level decode step over the paged KV cache.
-
-        last_tokens [B] int32 — the token each slot processes this
-        iteration (it sits at position lengths[b]); lengths [B] int32 —
-        tokens already cached per slot; active [B] bool — live slots;
-        cache_k/cache_v [L, n_pages, page_size, H, Dh] — the page pool
-        (page 0 is the engine's scratch page); page_table [B, P] int32 —
-        each slot's pages in order.
-
-        → (logits [B, V] fp32 for the NEXT token, cache_k, cache_v) with
-        the processed token's K/V written at its position. Every shape is
-        static in (B, P, pool geometry): requests joining/leaving the
-        batch between iterations never trigger a recompile.
-
-        Two kernels, one contract (`kernel`):
-
-        - ``"paged"`` — ops/paged_attention.py reads K/V straight out of
-          the pool through the page table (scalar-prefetch index_map);
-          the bottom-aligned masking and dead-tail trimming live inside
-          the kernel, and NO contiguous [B, S_max, H, Dh] buffer ever
-          materializes. `block_h` (heads per grid step) comes from
-          ops/flash_autotune.tune_paged_block_h; `interpret` runs the
-          kernel in Pallas interpret mode (the CPU parity/test path).
-        - ``"gather"`` — the fallback: gather each slot's pages into a
-          contiguous K/V and run the flash kernel at causal +
-          ``kv_offset = S_max − 1`` (the bottom-aligned short-q
-          geometry) with segment ids trimming each row's dead cache
-          tail; inactive rows carry a q-segment matching nothing.
-
-        Both write the processed token's K/V at its position first
-        (inactive rows route to the scratch page so the scatter stays
-        unconditional), and `q_pad` pads the query block to a
-        lane-friendly row count on TPU (rows past 0 are dropped).
-        """
-        c = self.config
-        if kernel not in ("paged", "gather"):
-            raise ValueError(
-                f"decode_kv kernel must be 'paged' or 'gather', "
-                f"got {kernel!r}"
-            )
-        n_layers, _n_pages, page_size, h, hd = cache_k.shape
-        b = last_tokens.shape[0]
-        s_max = page_table.shape[1] * page_size
-        positions = jnp.clip(lengths, 0, c.seq_len - 1)
-        x = (
-            params["tok_embed"].astype(c.dtype)[last_tokens][:, None, :]
-            + params["pos_embed"].astype(c.dtype)[positions][:, None, :]
-        )  # [B, 1, D]
-        # Write coordinates for this iteration's token; inactive rows are
-        # routed to the scratch page so the scatter stays unconditional.
-        widx = page_table[jnp.arange(b), lengths // page_size]
-        widx = jnp.where(active, widx, 0)
-        woff = lengths % page_size
-        qpad = max(1, int(q_pad))
-        if kernel == "gather":
-            kv_pos = jnp.arange(s_max)[None, :]
-            kv_seg = (
-                (kv_pos <= lengths[:, None]) & active[:, None]
-            ).astype(jnp.int32)  # [B, S_max]: live cache rows incl. token
-            # q row 0 matches live keys (id 1); inactive slots and pad
-            # rows get ids matching nothing kv-side (never 0 — pad is 0).
-            q_seg = jnp.where(active, 1, 2).astype(jnp.int32)[:, None]
-            if qpad > 1:
-                q_seg = jnp.concatenate(
-                    [q_seg, jnp.full((b, qpad - 1), 2, jnp.int32)], axis=1
-                )
-            bq = fit_block(qpad, 128)
-            bk = fit_block(s_max, c.flash_block_k)
-        else:
-            from determined_tpu.ops.paged_attention import paged_attention
-        for i in range(n_layers):
-            blk = jax.tree_util.tree_map(lambda a, i=i: a[i], params["blocks"])
-            hn = _layernorm(x, blk["ln1_scale"], blk["ln1_bias"])
-            qkv = (
-                jnp.einsum("bsd,dthk->bsthk", hn, blk["wqkv"].astype(c.dtype))
-                + blk["bqkv"].astype(c.dtype)
-            )
-            q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            cache_k = cache_k.at[i, widx, woff].set(k_new[:, 0])
-            cache_v = cache_v.at[i, widx, woff].set(v_new[:, 0])
-            if qpad > 1:
-                q = jnp.concatenate(
-                    [q, jnp.zeros((b, qpad - 1, h, hd), q.dtype)], axis=1
-                )
-            if kernel == "paged":
-                # K/V stay in the pool: the kernel DMAs each slot's live
-                # pages through the page table (dead pages cost neither
-                # DMA nor compute) and masks the length boundary inside.
-                o = paged_attention(
-                    q, cache_k[i], cache_v[i], page_table, lengths,
-                    active, block_h=block_h, interpret=interpret,
-                )[:, :1]
-            else:
-                k_full = cache_k[i][page_table].reshape(b, s_max, h, hd)
-                v_full = cache_v[i][page_table].reshape(b, s_max, h, hd)
-                o = flash_attention(
-                    q, k_full, v_full, causal=True, kv_offset=s_max - 1,
-                    segment_ids=q_seg, kv_segment_ids=kv_seg,
-                    block_q=bq, block_k=bk,
-                )[:, :1]
-            o = jnp.einsum("bshk,hkd->bsd", o, blk["wo"].astype(c.dtype))
-            x = x + o + blk["bo"].astype(c.dtype)
-            x, _aux = self._mlp_half(x, blk, manual=False)
-        logits = self._head(params, x)  # [B, 1, V]
-        return logits[:, 0].astype(jnp.float32), cache_k, cache_v
-
-    def decode_kv_spec(
         self,
         params: Dict[str, Any],
         tokens: jax.Array,
@@ -1171,51 +998,70 @@ class GPT(Model):
         block_h: Optional[int] = None,
         interpret: bool = False,
     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-        """Draft-verify decode: score Q positions per slot in ONE step.
+        """One iteration-level decode step over the paged KV cache: Q
+        positions per slot scored in ONE step. Q = 1 is the plain
+        one-token decode, Q > 1 the draft-verify step of speculative
+        decoding; the engine compiles one program for each.
 
-        The speculative-decoding verify geometry: tokens [B, Q] int32
-        carries each slot's last committed token (row 0, at position
-        lengths[b]) followed by its draft (rows 1..q_lens[b]−1, at
-        positions lengths[b]+r); rows past q_lens[b] are padding the
-        engine ignores. lengths/active/cache/page_table are exactly
-        decode_kv's. q_lens [B] int32 — real rows per slot (≥ 1); a
-        plain slot rides the same compiled step with q_lens = 1, so
-        speculating and non-speculating slots mix in one iteration with
-        every shape static.
+        tokens [B, Q] int32 — row 0 is the token each slot processes
+        this iteration (its last committed token: it sits at position
+        lengths[b]), followed by the slot's draft (rows 1..q_lens[b]−1,
+        at positions lengths[b]+r); rows past q_lens[b] are padding the
+        engine ignores. lengths [B] int32 — tokens already cached per
+        slot; q_lens [B] int32 — real rows per slot (≥ 1): a plain slot
+        rides the verify step with q_lens = 1, so speculating and
+        non-speculating slots mix in one iteration with every shape
+        static; active [B] bool — live slots; cache_k/cache_v
+        [L, n_pages, page_size, H, Dh] — the page pool (page 0 is the
+        engine's scratch page); page_table [B, P] int32 — each slot's
+        pages in order.
 
         → (logits [B, Q, V] fp32, cache_k, cache_v): logits[b, r]
-        predicts position lengths[b]+r+1, so greedy acceptance walks
-        drafts against argmax(logits[:, :-1]) and the accepted prefix's
-        emissions come straight off the same array. ALL Q rows' K/V are
-        written at their positions first (live rows through the page
-        table, dead/pad rows to the scratch page): an accepted prefix is
-        already committed in the pool, and a rejected tail sits at
-        positions past the rewound length — invisible to both kernels'
-        masks and overwritten before those positions ever go live.
+        predicts position lengths[b]+r+1 (at Q = 1: the NEXT token), so
+        greedy acceptance walks drafts against argmax(logits[:, :-1])
+        and the accepted prefix's emissions come straight off the same
+        array. ALL Q rows' K/V are written at their positions first
+        (live rows through the page table, inactive/dead/pad rows to the
+        scratch page, so the scatter stays unconditional): an accepted
+        prefix is already committed in the pool, and a rejected tail
+        sits at positions past the rewound length — invisible to both
+        kernels' masks and overwritten before those positions ever go
+        live. Every shape is static in (B, Q, P, pool geometry):
+        requests joining/leaving the batch between iterations never
+        trigger a recompile.
 
-        Kernel dispatch mirrors decode_kv:
+        Two kernels, one contract (`kernel`):
 
-        - ``"paged"`` — the in-kernel page-table path with per-row
-          bottom-aligned masking (paged_attention's ``q_lens``): row r's
-          page regimes/masks are the single-token kernel's at length+r.
-        - ``"gather"`` — the committed window [B, S_max] is gathered
-          with STRICT segment masking (pos < lengths: row 0's token is
-          NOT read from the pool) and the Q fresh rows' K/V concatenate
-          behind it at ``kv_offset = S_max`` — causal over the tail
-          gives row r exactly tail rows ≤ r, i.e. positions ≤
-          lengths[b]+r: the prefill_kv_cached concat geometry at decode
-          scale.
+        - ``"paged"`` — ops/paged_attention.py reads K/V straight out of
+          the pool through the page table (scalar-prefetch index_map);
+          the per-row bottom-aligned masking (``q_lens``: row r's page
+          regimes/masks are the single-token kernel's at length+r) and
+          dead-tail trimming live inside the kernel: dead pages cost
+          neither DMA nor compute, and NO contiguous [B, S_max, H, Dh]
+          buffer ever materializes. `block_h` (heads per grid step)
+          comes from ops/flash_autotune.tune_paged_block_h; `interpret`
+          runs the kernel in Pallas interpret mode (the CPU parity/test
+          path).
+        - ``"gather"`` — the fallback: the committed window [B, S_max]
+          is gathered contiguous with STRICT segment masking (pos <
+          lengths: row 0's token is NOT read from the pool; inactive
+          rows carry a q-segment matching nothing) and the Q fresh
+          rows' K/V concatenate behind it at ``kv_offset = S_max`` —
+          causal over the tail gives row r exactly tail rows ≤ r, i.e.
+          positions ≤ lengths[b]+r: the prefill_kv_cached concat
+          geometry at decode scale.
 
-        `q_pad` rounds Q up to a lane-friendly row count (the extra rows
-        are dropped before return).
+        `q_pad` rounds Q up to a lane-friendly row count on TPU (the
+        extra rows are dropped before return); above 1 the gather path
+        also pads its fresh tail so the keys are whole 128-lane blocks.
         """
         c = self.config
         if kernel not in ("paged", "gather"):
             raise ValueError(
-                f"decode_kv_spec kernel must be 'paged' or 'gather', "
+                f"decode_kv kernel must be 'paged' or 'gather', "
                 f"got {kernel!r}"
             )
-        n_layers, _n_pages, page_size, h, hd = cache_k.shape
+        _n_layers, _n_pages, page_size, h, hd = cache_k.shape
         b, q_n = tokens.shape
         n_page_slots = page_table.shape[1]
         s_max = n_page_slots * page_size
@@ -1224,11 +1070,6 @@ class GPT(Model):
         r = jnp.arange(q_n)
         pos = lengths[:, None] + r[None, :]            # [B, Q]
         live = active[:, None] & (r[None, :] < q_lens[:, None])
-        positions = jnp.clip(pos, 0, c.seq_len - 1)
-        x = (
-            params["tok_embed"].astype(c.dtype)[tokens]
-            + params["pos_embed"].astype(c.dtype)[positions]
-        )  # [B, Q, D]
         # Write coordinates for every row's K/V; dead and padding rows
         # route to the scratch page so the scatter stays unconditional.
         widx = page_table[
@@ -1238,70 +1079,65 @@ class GPT(Model):
         widx = jnp.where(live, widx, 0)
         woff = pos % page_size
         if kernel == "gather":
-            kv_pos = jnp.arange(s_max)[None, :]
             # STRICT boundary: the committed window ends at lengths−1 —
             # row 0's token (and the draft) ride in the fresh tail, so
             # the just-scattered pool rows are never double-counted.
             kv_seg_win = (
-                (kv_pos < lengths[:, None]) & active[:, None]
+                (jnp.arange(s_max)[None, :] < lengths[:, None])
+                & active[:, None]
             ).astype(jnp.int32)  # [B, S_max]
-            tail_r = jnp.arange(qp)[None, :]
-            kv_seg_tail = (
-                (tail_r < q_lens[:, None]) & active[:, None]
-            ).astype(jnp.int32)  # [B, qp]
-            kv_seg = jnp.concatenate([kv_seg_win, kv_seg_tail], axis=1)
-            q_seg = jnp.where(
-                (tail_r < q_lens[:, None]) & active[:, None], 1, 2
-            ).astype(jnp.int32)  # [B, qp]
+            # On the chip (q_pad > 1) the key axis is cut into whole
+            # 128-lane blocks: S_max + Q is never one (8·odd at 128-row
+            # pages), so the fresh tail is padded until window + tail is.
+            kp = qp if qpad == 1 else -(-(s_max + qp) // 128) * 128 - s_max
+            tail_live = (
+                (jnp.arange(kp)[None, :] < q_lens[:, None]) & active[:, None]
+            )  # [B, kp]
+            kv_seg = jnp.concatenate(
+                [kv_seg_win, tail_live.astype(jnp.int32)], axis=1
+            )
+            # live q rows match live keys (id 1); inactive slots and pad
+            # rows get ids matching nothing kv-side (never 0 — pad is 0).
+            q_seg = jnp.where(tail_live[:, :qp], 1, 2).astype(jnp.int32)
             bq = fit_block(qp, 128)
-            bk = fit_block(s_max + qp, c.flash_block_k)
+            bk = fit_block(s_max + kp, c.flash_block_k)
         else:
             from determined_tpu.ops.paged_attention import paged_attention
-        for i in range(n_layers):
-            blk = jax.tree_util.tree_map(lambda a, i=i: a[i], params["blocks"])
-            hn = _layernorm(x, blk["ln1_scale"], blk["ln1_bias"])
-            qkv = (
-                jnp.einsum("bsd,dthk->bsthk", hn, blk["wqkv"].astype(c.dtype))
-                + blk["bqkv"].astype(c.dtype)
+
+        def lane_pad(a, rows):
+            if rows == q_n:
+                return a
+            return jnp.concatenate(
+                [a, jnp.zeros((b, rows - q_n, h, hd), a.dtype)], axis=1
             )
+
+        def attend(i, qkv):
+            nonlocal cache_k, cache_v
             q, k_new, v_new = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             cache_k = cache_k.at[i, widx, woff].set(k_new)
             cache_v = cache_v.at[i, widx, woff].set(v_new)
-            if qp > q_n:
-                q = jnp.concatenate(
-                    [q, jnp.zeros((b, qp - q_n, h, hd), q.dtype)], axis=1
-                )
             if kernel == "paged":
                 o = paged_attention(
-                    q, cache_k[i], cache_v[i], page_table, lengths,
-                    active, q_lens=q_lens, block_h=block_h,
+                    lane_pad(q, qp), cache_k[i], cache_v[i], page_table,
+                    lengths, active, q_lens=q_lens, block_h=block_h,
                     interpret=interpret,
-                )[:, :q_n]
+                )
             else:
                 k_full = cache_k[i][page_table].reshape(b, s_max, h, hd)
                 v_full = cache_v[i][page_table].reshape(b, s_max, h, hd)
-                k_tail, v_tail = k_new, v_new
-                if qp > q_n:
-                    k_tail = jnp.concatenate(
-                        [k_new, jnp.zeros((b, qp - q_n, h, hd), k_new.dtype)],
-                        axis=1,
-                    )
-                    v_tail = jnp.concatenate(
-                        [v_new, jnp.zeros((b, qp - q_n, h, hd), v_new.dtype)],
-                        axis=1,
-                    )
                 o = flash_attention(
-                    q,
-                    jnp.concatenate([k_full, k_tail], axis=1),
-                    jnp.concatenate([v_full, v_tail], axis=1),
+                    lane_pad(q, qp),
+                    jnp.concatenate([k_full, lane_pad(k_new, kp)], axis=1),
+                    jnp.concatenate([v_full, lane_pad(v_new, kp)], axis=1),
                     causal=True, kv_offset=s_max,
                     segment_ids=q_seg, kv_segment_ids=kv_seg,
                     block_q=bq, block_k=bk,
-                )[:, :q_n]
-            o = jnp.einsum("bshk,hkd->bsd", o, blk["wo"].astype(c.dtype))
-            x = x + o + blk["bo"].astype(c.dtype)
-            x, _aux = self._mlp_half(x, blk, manual=False)
-        logits = self._head(params, x)  # [B, Q, V]
+                )
+            return o[:, :q_n]
+
+        logits = self._serve(
+            params, tokens, jnp.clip(pos, 0, c.seq_len - 1), attend
+        )
         return logits.astype(jnp.float32), cache_k, cache_v
 
     # -- 1F1B training path ------------------------------------------------

@@ -479,10 +479,13 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
+        # The verify step at Q = 1: every slot brings its one token.
         logits, ck, cv = self.model.decode_kv(
-            params, last, lengths, active, ck, cv, pt, q_pad=q_pad,
-            kernel=kernel, block_h=block_h, interpret=interpret,
+            params, last[:, None], lengths, jnp.ones_like(lengths), active,
+            ck, cv, pt, q_pad=q_pad, kernel=kernel, block_h=block_h,
+            interpret=interpret,
         )
+        logits = logits[:, 0]
         greedy = jnp.argmax(logits, axis=-1)
         sampled = jax.random.categorical(
             key, logits / jnp.maximum(temps, 1e-6)[:, None]
@@ -504,7 +507,7 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        logits, ck, cv = self.model.decode_kv_spec(
+        logits, ck, cv = self.model.decode_kv(
             params, toks, lengths, q_lens, active, ck, cv, pt,
             q_pad=q_pad, kernel=kernel, block_h=block_h,
             interpret=interpret,
@@ -1277,64 +1280,6 @@ class GenerationEngine:
                     name, trace_id=trace_id, span_id=trace_mod.new_span_id(),
                     parent_span_id=root, start=start, end=end,
                 )
-
-    # -- bench support ------------------------------------------------------
-    def decode_latency_compare(self, iters: int = 5) -> Dict[str, float]:
-        """Per-iteration decode latency of BOTH kernel paths over the
-        SAME pool state (full batch at max context utilization — the
-        regime the paged kernel exists for). Runs on copies without
-        donation, so the live engine state is untouched; the bench
-        serving rung publishes the two numbers side by side. Call from
-        the engine's own thread or while the engine is stopped."""
-        import jax
-        import jax.numpy as jnp
-
-        from determined_tpu.ops.paged_attention import LANE_GRANULE
-
-        cfg = self.cfg
-        c = self.model.config
-        on_tpu = jax.default_backend() == "tpu"
-        # A lane-misaligned pool has no compilable paged kernel on TPU
-        # (the engine itself degraded to gather at build) — publish the
-        # gather numbers alone rather than crash the comparison.
-        kernels = (
-            ("gather",) if on_tpu and cfg.page_size % LANE_GRANULE
-            else ("paged", "gather")
-        )
-        b = cfg.max_batch_size
-        per = cfg.max_pages_per_request
-        # Distinct live pages per slot, wrapped over the allocatable pool
-        # (slots may share pages under oversubscription — harmless for a
-        # read-only timing probe).
-        pt = (
-            np.arange(b * per, dtype=np.int32) % (cfg.num_pages - 1) + 1
-        ).reshape(b, per)
-        s_max = per * cfg.page_size
-        lengths = np.full((b,), min(s_max, self.max_total) - 2, np.int32)
-        active = np.ones((b,), bool)
-        last = np.full((b,), 1, np.int32)
-        temps = np.zeros((b,), np.float32)
-        key = jax.random.PRNGKey(0)
-        out: Dict[str, float] = {"s_max": float(s_max), "batch": float(b)}
-        for kernel in kernels:
-            interpret = kernel == "paged" and not on_tpu
-            step = jax.jit(functools.partial(
-                self._decode_step, q_pad=self._q_pad, kernel=kernel,
-                block_h=self._paged_block_h, interpret=interpret,
-            ))
-            args = (
-                self.params, jnp.asarray(last), jnp.asarray(lengths),
-                jnp.asarray(active), self.cache_k, self.cache_v,
-                jnp.asarray(pt), jnp.asarray(temps), key,
-            )
-            jax.block_until_ready(step(*args))  # compile outside timing
-            best = float("inf")
-            for _ in range(max(1, iters)):
-                t0 = time.perf_counter()
-                jax.block_until_ready(step(*args))
-                best = min(best, time.perf_counter() - t0)
-            out[f"decode_iter_ms_{kernel}"] = best * 1e3
-        return out
 
     # -- introspection ------------------------------------------------------
     def stats(self) -> Dict[str, Any]:

@@ -28,8 +28,7 @@ Ledger semantics:
   process restart resumes the SAME ledger and the save→restore gap —
   scheduler queue, reschedule, re-init — is charged as restart loss.
 
-Kill switch: ``DTPU_TIMELINE=0`` (bench.py measures the instrumentation
-overhead against it; acceptance < 1% of step time).
+Kill switch: ``DTPU_TIMELINE=0``.
 
 `Timeline.phase(name)` is the one way the trainer marks a phase: it tags
 the thread for the sampling profiler, opens a `TraceAnnotation`

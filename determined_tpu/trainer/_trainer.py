@@ -1049,8 +1049,8 @@ class Trainer:
             agg["batches_per_second"] = len(host) / dt if dt > 0 else 0.0
             self._last_throughput = agg["batches_per_second"]
             # Robustness tax, cumulative: how many updates the guard
-            # dropped and how often the sentinel rolled back (bench.py
-            # and the metrics history both read these).
+            # dropped and how often the sentinel rolled back (the metrics
+            # history reads these).
             agg["steps_skipped"] = float(self._steps_skipped)
             agg["rollbacks"] = float(self._rollbacks)
             steps_now = self.steps_completed

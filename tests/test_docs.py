@@ -138,7 +138,7 @@ class TestGuides:
             for m in re.finditer(r"\(([\w\-./]+\.(?:md|json))\)", text):
                 target = m.group(1)
                 # links resolve relative to docs/, or to the repo root
-                # (SURVEY.md, BASELINE.md live there)
+                # (SURVEY.md, PERF.md live there)
                 assert (
                     os.path.exists(os.path.join(DOCS, target))
                     or os.path.exists(os.path.join(repo, target))

@@ -71,7 +71,8 @@ TINY_BERT = {
 
 
 class TestHFClassifier:
-    """The BERT-fine-tune rung of BASELINE.md's platform ladder."""
+    """The BERT-fine-tune rung of the platform ladder
+    (`integrations/hf.py`)."""
 
     def test_model_structure(self):
         from determined_tpu.integrations.hf import HFFlaxClassifier

@@ -9,7 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from determined_tpu.ops.flash_attention import flash_attention
+from determined_tpu.ops.flash_attention import fit_block, flash_attention
 from determined_tpu.ops.paged_attention import (
     LANE_GRANULE,
     default_paged_block_h,
@@ -42,27 +42,34 @@ def _pool_state(rng, *, num_pages, page_size, n_heads, head_dim, batch,
 
 
 def _gather_flash(q, kp, vp, pt, lengths, active, *, block_k):
-    """The decode_kv gather path, verbatim geometry: pool pages gathered
-    contiguous, flash at causal + kv_offset = S_max − 1, segment ids
-    trimming each slot's dead tail and inactive slots entirely."""
+    """GPT.decode_kv's gather path with one live row per slot, verbatim
+    geometry: the committed window (positions < lengths) gathered
+    contiguous from the pool, the row's own K/V (in the pool at position
+    lengths) concatenated behind it at kv_offset = S_max, segment ids
+    trimming each slot's dead tail, the pad rows and inactive slots
+    entirely."""
     b, qr = q.shape[:2]
     ps = kp.shape[1]
     s_max = pt.shape[1] * ps
     k_full = kp[pt].reshape(b, s_max, *kp.shape[2:])
     v_full = vp[pt].reshape(b, s_max, *vp.shape[2:])
-    kv_pos = jnp.arange(s_max)[None, :]
-    kv_seg = (
-        (kv_pos <= lengths[:, None]) & (active[:, None] != 0)
+
+    def with_tail(full):
+        fresh = full[jnp.arange(b), lengths][:, None]
+        pad = jnp.zeros((b, qr - 1, *full.shape[2:]), full.dtype)
+        return jnp.concatenate([full, fresh, pad], axis=1)
+
+    live = active[:, None] != 0
+    tail_live = (jnp.arange(qr)[None, :] < 1) & live
+    kv_seg = jnp.concatenate(
+        [(jnp.arange(s_max)[None, :] < lengths[:, None]) & live, tail_live],
+        axis=1,
     ).astype(jnp.int32)
-    q_seg = jnp.where(active != 0, 1, 2).astype(jnp.int32)[:, None]
-    if qr > 1:
-        q_seg = jnp.concatenate(
-            [q_seg, jnp.full((b, qr - 1), 2, jnp.int32)], axis=1
-        )
+    q_seg = jnp.where(tail_live, 1, 2).astype(jnp.int32)
     return flash_attention(
-        q, k_full, v_full, causal=True, kv_offset=s_max - 1,
-        segment_ids=q_seg, kv_segment_ids=kv_seg,
-        block_q=qr, block_k=block_k,
+        q, with_tail(k_full), with_tail(v_full), causal=True,
+        kv_offset=s_max, segment_ids=q_seg, kv_segment_ids=kv_seg,
+        block_q=qr, block_k=fit_block(s_max + qr, block_k),
     )
 
 

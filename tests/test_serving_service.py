@@ -93,7 +93,7 @@ class TestServingDrill:
         # the serving metrics are scrapeable THROUGH the proxy, and the
         # decode ran the flash kv_offset path (Pallas on TPU; this CPU
         # suite runs the blockwise reference of the same kernel math —
-        # bench.py asserts "pallas" on real hardware).
+        # `chip_smoke.py` asserts "pallas" on the chip).
         text = requests.get(f"{proxy_url}/metrics", timeout=10).text
         samples = parse_exposition(text)
         assert sample_value(samples, "dtpu_serving_tokens_total") >= 320

@@ -238,7 +238,7 @@ class TestRollback:
         ("gather", False), ("paged", True),
     ])
     def test_rewind_state_model_level(self, kernel, interpret):
-        """decode_kv_spec with a corrupted draft: the accepted-prefix
+        """decode_kv with a corrupted draft: the accepted-prefix
         rows are undisturbed, and continuing PLAIN decode from the
         spec-written cache at the rewound length reproduces the
         never-speculated stream exactly — lengths + page table after a
@@ -277,11 +277,12 @@ class TestRollback:
             stream = [[], []]
             for _ in range(steps):
                 lg, ckx, cvx = model.decode_kv(
-                    params, jnp.asarray(last), jnp.asarray(lengths),
+                    params, jnp.asarray(last)[:, None],
+                    jnp.asarray(lengths), jnp.ones((B,), jnp.int32),
                     jnp.asarray(active), ckx, cvx, jnp.asarray(pt),
                     q_pad=1, kernel=kernel, interpret=interpret,
                 )
-                nxt = np.argmax(np.asarray(lg), -1)
+                nxt = np.argmax(np.asarray(lg)[:, 0], -1)
                 stream[0].append(int(nxt[0]))
                 stream[1].append(int(nxt[1]))
                 last = nxt.astype(np.int32)
@@ -299,7 +300,7 @@ class TestRollback:
         toks[0, 1:] = base[0][:3]
         toks[1, 0] = last1
         q_lens = np.array([4, 1, 1], np.int32)
-        lg, cks, cvs = model.decode_kv_spec(
+        lg, cks, cvs = model.decode_kv(
             params, jnp.asarray(toks),
             jnp.asarray(np.array([4, 2, 0], np.int32)),
             jnp.asarray(q_lens), jnp.asarray(np.array([1, 1, 0], bool)),
@@ -311,7 +312,7 @@ class TestRollback:
         assert int(g[1, 0]) == base[1][0]        # plain slot in mix
         toks2 = toks.copy()
         toks2[0, 2] = (toks[0, 2] + 1) % 256
-        lg2, cks2, cvs2 = model.decode_kv_spec(
+        lg2, cks2, cvs2 = model.decode_kv(
             params, jnp.asarray(toks2),
             jnp.asarray(np.array([4, 2, 0], np.int32)),
             jnp.asarray(q_lens), jnp.asarray(np.array([1, 1, 0], bool)),
@@ -328,6 +329,38 @@ class TestRollback:
         )
         assert cont[0] == base[0][1:4]
         assert cont[1] == base[1][1:4]
+
+    @pytest.mark.parametrize("q_n", [1, 3])
+    @pytest.mark.parametrize("kernel,interpret", [
+        ("gather", False), ("paged", True),
+    ])
+    def test_q_pad_rows_change_nothing(self, kernel, interpret, q_n):
+        """The chip's step (`q_pad=8`: padded query rows, and on the
+        gather path a fresh tail padded to whole 128-key blocks) scores
+        and writes what the unpadded step does."""
+        model, params = shared_model()
+        cfg = model.config
+        rng = np.random.RandomState(0)
+        shape = (cfg.n_layers, 9, 16, cfg.n_heads, cfg.head_dim)
+        ck = jnp.asarray(rng.standard_normal(shape), cfg.dtype)
+        cv = jnp.asarray(rng.standard_normal(shape), cfg.dtype)
+        args = (
+            params, jnp.asarray(rng.randint(1, 200, (3, q_n)), jnp.int32),
+            jnp.asarray([37, 5, 0], jnp.int32),
+            jnp.asarray([q_n, 1, 1], jnp.int32),
+            jnp.asarray([True, True, False]), ck, cv,
+            jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8], [0, 0, 0, 0]], jnp.int32),
+        )
+        plain, padded = (
+            model.decode_kv(
+                *args, q_pad=q_pad, kernel=kernel, interpret=interpret)
+            for q_pad in (1, 8)
+        )
+        # logits of the live slots, then the pool: the same rows written
+        for a, b in zip((padded[0][:2],) + padded[1:],
+                        (plain[0][:2],) + plain[1:]):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-3)
 
 
 class TestSpeculationFault:

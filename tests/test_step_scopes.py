@@ -7,6 +7,7 @@ through `Timeline.phase`, and the benchmark's own copy of those names
 (`benchmark/scopes.json`, `benchmark/kernels/dtpu_paged_attn.json`),
 which it keeps as data because it imports nothing of the program."""
 import dataclasses
+import functools
 import glob
 import importlib
 import json
@@ -89,6 +90,69 @@ def test_lowered_train_step_holds_every_scope(tmp_path, config):
         assert any("rematted_computation" in n
                    and scope_reduce.scope_of(n, STEP_SCOPES) == "mlp"
                    for n in names)
+
+
+# -- the serving programs run the same block --------------------------------
+def _serving_program(which):
+    """(fn, args) of one of the four programs the serving engine
+    compiles, at `gpt.tiny` size, as shapes."""
+    import types
+
+    from determined_tpu.serving.engine import GenerationEngine
+
+    model = gpt_mod.GPT(gpt_mod.tiny())
+    c = model.config
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    sds = jax.ShapeDtypeStruct
+    b, s, sp, pages, page, per, q = 2, 32, 16, 9, 8, 4, 3
+    grid = sds((b, s), jnp.int32)
+    pool = sds((c.n_layers, pages, page, c.n_heads, c.head_dim), c.dtype)
+    slot = sds((b,), jnp.int32)
+    step = dict(q_pad=1, kernel="gather")
+    tail = (sds((b,), bool), pool, pool, sds((b, per), jnp.int32),
+            sds((b,), jnp.float32), sds((2,), jnp.uint32))
+    me = types.SimpleNamespace(model=model)
+    if which == "prefill":
+        return model.prefill_kv, (params, grid, grid, grid)
+    if which == "cached_prefill":
+        prefix = sds((c.n_layers, b, sp, c.n_heads, c.head_dim), c.dtype)
+        return model.prefill_kv_cached, (
+            params, grid, grid, grid, prefix, prefix, sds((b, sp), jnp.int32))
+    if which == "decode":
+        return (functools.partial(GenerationEngine._decode_step, me, **step),
+                (params, slot, slot, *tail))
+    return (functools.partial(GenerationEngine._spec_decode_step, me, **step),
+            (params, sds((b, q), jnp.int32), slot, slot, *tail))
+
+
+@pytest.mark.parametrize(
+    "which", ["prefill", "cached_prefill", "decode", "spec_decode"])
+def test_serving_programs_run_the_training_block(which):
+    """Each serving program's projections and MLP sit under the `attn`
+    and `mlp` scopes, which only `GPT._attn_half` / `_mlp_half` open: the
+    serving entry points run the block training runs, not a copy."""
+    fn, args = _serving_program(which)
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    under = lambda scope, op: any(  # noqa: E731
+        op in n and scope_reduce.scope_of(n, STEP_SCOPES) == scope
+        for n in names)
+    assert under("attn", "bsd,dthk->bsthk") and under("attn", "bshk,hkd->bsd")
+    assert under("mlp", "bsd,df->bsf") and under("mlp", "bsf,fd->bsd")
+    assert under("embed", "") and under("head_loss", "bsd,dv->bsv")
+
+
+def test_the_block_is_written_once():
+    """By source (`tests/test_flash_block_discipline.py`'s manner): one
+    LayerNorm -> QKV -> attention -> out-projection -> residual sequence
+    in `models/gpt.py`; a second `ln1` or projection einsum is a copy of
+    `_attn_half`."""
+    with open(gpt_mod.__file__) as f:
+        src = f.read()
+    for once in ('"bsd,dthk->bsthk"', '"bshk,hkd->bsd"',
+                 '_layernorm(x, blk["ln1_scale"]',
+                 '_layernorm(x, blk["ln2_scale"]'):
+        assert src.count(once) == 1, once
 
 
 def test_scope_is_a_whole_component_of_the_name_stack():

@@ -114,24 +114,33 @@ def _cached_prefill():
     ]
 
 
-def _gather_decode():
-    """the engine's gather fallback: 8 padded query rows against the
-    whole 1024-token window, bottom-aligned."""
-    def step(q, k, v, seg, kv_seg):
-        return fa.flash_attention(
-            q, k, v, causal=True, kv_offset=1023, block_q=8, block_k=1024,
-            segment_ids=seg, kv_segment_ids=kv_seg,
-        )
+def _gather_decode(q_n):
+    """the engine's gather fallback as `GPT.decode_kv` runs it on the
+    chip (`q_pad=8`) at the default serving geometry, 8 pages x 128 a
+    slot: Q = 1 is the plain decode step, Q = 5 a draft of four."""
+    from determined_tpu.models import gpt as gpt_mod
 
-    return step, [
-        ((8, 8, 12, 64), BF16), ((8, 1024, 12, 64), BF16),
-        ((8, 1024, 12, 64), BF16), ((8, 8), jnp.int32),
-        ((8, 1024), jnp.int32),
+    model = gpt_mod.GPT(dataclasses.replace(
+        gpt_mod.tiny(1024), n_layers=2, n_heads=12, d_model=768,
+        attn_impl="flash", dtype=BF16))
+    leaves, tree = jax.tree.flatten(
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))))
+
+    def step(*args):
+        params = jax.tree.unflatten(tree, args[:len(leaves)])
+        return model.decode_kv(
+            params, *args[len(leaves):], q_pad=8, kernel="gather")
+
+    pool = ((2, 129, 128, 12, 64), BF16)
+    slot = ((8,), jnp.int32)
+    return step, [(a.shape, a.dtype) for a in leaves] + [
+        ((8, q_n), jnp.int32), slot, slot, ((8,), jnp.bool_), pool, pool,
+        ((8, 8), jnp.int32),
     ]
 
 
 def _paged_decode(n_heads, head_dim, block_h=None):
-    """bench.py's serving pool (129 pages x 128, batch 8, 8 pages a slot,
+    """A small serving pool (129 pages x 128, batch 8, 8 pages a slot,
     8 query rows) with `q_lens` given — the speculative-verify call."""
     step = functools.partial(paged_attention, block_h=block_h)
     pool = ((129, 128, n_heads, head_dim), BF16)
@@ -153,7 +162,8 @@ CASES = {
     "flash_train_32k_split": lambda: _flash_train(32768),
     "packed_prefill_4x512_segments": _packed_prefill,
     "cached_prefill_kv_offset": _cached_prefill,
-    "gather_decode_q8_s1024": _gather_decode,
+    "gather_decode_q1_8x128": lambda: _gather_decode(1),
+    "gather_decode_q5_8x128": lambda: _gather_decode(5),
     "paged_decode_12x64": lambda: _paged_decode(12, 64),
     "paged_decode_12x64_block_h_2": lambda: _paged_decode(12, 64, 2),
     "paged_decode_16x128": lambda: _paged_decode(16, 128),
