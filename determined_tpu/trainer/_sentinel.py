@@ -7,9 +7,11 @@ into fast, attributable kills via per-step progress heartbeats. This
 module holds the trainer-side pieces of that story:
 
 - **Non-finite guard** (`guarded_update`): folded INTO the jitted train
-  step — a NaN/inf loss or gradient norm skips the optimizer update
-  in-graph (`lax.cond`, `optax.apply_if_finite` semantics) and bumps a
-  consecutive-skip counter that rides the device-resident metrics buffer.
+  step — after a NaN/inf loss or gradient norm every leaf of the
+  parameters and the optimizer state keeps its old value (a leaf-wise
+  select fused into the update itself, `optax.apply_if_finite`
+  semantics; no `conditional` in the step) and a consecutive-skip
+  counter that rides the device-resident metrics buffer is bumped.
   No extra host sync: the host only reads the counter at report
   boundaries, where it already materializes metrics.
 - **Loss-spike detector** (`SpikeDetector`): a robust z-score (median /
@@ -107,12 +109,19 @@ def guarded_update(
     grad_norm: jax.Array,
     skips_in: jax.Array,
 ) -> Tuple[Dict[str, Any], jax.Array, jax.Array]:
-    """Select the post-step state in-graph: `new_state` when loss AND
-    grad norm are finite, else `old_state` with only the step counter
-    advanced (the batch was consumed; params/optimizer must not absorb
-    the poison). `lax.cond` executes one branch — the healthy path pays
-    two `isfinite` reductions and a predicated copy elision, nothing
-    elementwise over the params.
+    """Select the post-step state in-graph, leaf by leaf: `new_state`'s
+    leaf where loss AND grad norm are finite, else `old_state`'s, the
+    step counter advanced either way (the batch was consumed;
+    params/optimizer must not absorb the poison, and a select takes the
+    old value whatever the new one holds, NaN included). Elementwise on
+    purpose: XLA fuses the select into the update that forms each leaf,
+    so the state is read and written once, in the layout it has at the
+    step's boundary. A `lax.cond` over the whole state is not the same
+    program on the chip: the compiler sinks the update into the branch
+    and copies parameters and moments in and out of it (PERF.md section
+    6, PR 31; `tests/test_tpu_compile.py` holds the compiled step to no
+    `conditional` and no such copy). A skipped step runs the update's
+    arithmetic and discards it: skipped steps are faults, not traffic.
 
     Returns (state, ok, skips_out): `ok` is a device bool (1 = applied),
     `skips_out` the consecutive-skip counter (resets on a healthy step).
@@ -120,14 +129,10 @@ def guarded_update(
     step.
     """
     ok = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
-
-    def applied() -> Dict[str, Any]:
-        return new_state
-
-    def skipped() -> Dict[str, Any]:
-        return dict(old_state, step=new_state["step"])
-
-    state = jax.lax.cond(ok, applied, skipped)
+    state = jax.tree.map(
+        lambda new, old: jnp.where(ok, new, old), new_state, old_state
+    )
+    state["step"] = new_state["step"]
     skips_out = jnp.where(ok, jnp.int32(0), skips_in.astype(jnp.int32) + 1)
     return state, ok, skips_out
 

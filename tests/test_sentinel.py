@@ -70,28 +70,50 @@ def _ctx(tmp_path):
     return core._context._dummy_init(checkpoint_storage=str(tmp_path))
 
 
+def _leaves_differ(a, b):
+    """Names of the leaves of two state trees that are not bit-equal."""
+    return [
+        jax.tree_util.keystr(path)
+        for (path, x), y in zip(
+            jax.tree_util.tree_leaves_with_path(jax.device_get(a)),
+            jax.tree_util.tree_leaves(jax.device_get(b)),
+        )
+        if not np.array_equal(x, y, equal_nan=True)
+    ]
+
+
 class TestGuard:
-    def test_nonfinite_step_skips_update_in_graph(self, tmp_path):
-        """A NaN loss leaves params/optimizer untouched (only the step
-        advances) and bumps the on-device skip counter; a healthy step
-        resets it."""
-        trainer = Trainer(_trial([]), _ctx(tmp_path), seed=0)
+    @pytest.mark.parametrize("optimizer", [
+        lambda: optax.adam(1e-2),
+        # the benchmark cells' optimizer (`SyntheticTrial`)
+        lambda: optax.chain(
+            optax.clip_by_global_norm(1.0),
+            optax.adamw(1e-2, weight_decay=0.1),
+        ),
+    ], ids=["adam", "clip_adamw"])
+    def test_nonfinite_step_skips_update_in_graph(
+        self, tmp_path, monkeypatch, optimizer
+    ):
+        """A NaN loss leaves every leaf of params and optimizer state
+        (Adam's count too) bit-equal, only the step advances, and the
+        on-device skip counter is bumped; a healthy step resets it and
+        gives bit for bit the state of the same update with no guard."""
+        trial = _trial([])
+        trial.build_optimizer = optimizer
+        trainer = Trainer(trial, _ctx(tmp_path), seed=0)
         trainer._step_fn = trainer._build_step_fn()
         stream = iter(_IndexedStream([]))
-        p0 = jax.device_get(trainer.state["params"])
+        s0 = jax.device_get(trainer.state)
 
         batch = trainer._put_batch(next(stream))
         state, metrics, skips = trainer._step_fn(
             trainer.state, batch, np.float32(np.nan), jnp.zeros((), jnp.int32)
         )
-        assert int(state["step"]) == 1
+        assert int(state["step"]) == int(s0["step"]) + 1
         assert int(metrics["sentinel_skipped"]) == 1
         assert int(skips) == 1
-        for a, b in zip(
-            jax.tree_util.tree_leaves(p0),
-            jax.tree_util.tree_leaves(jax.device_get(state["params"])),
-        ):
-            np.testing.assert_array_equal(a, b)
+        assert _leaves_differ(s0, state) == ["['step']"]
+        s1 = jax.device_get(state)
 
         batch = trainer._put_batch(next(stream))
         state2, metrics2, skips2 = trainer._step_fn(
@@ -99,14 +121,22 @@ class TestGuard:
         )
         assert int(metrics2["sentinel_skipped"]) == 0
         assert int(skips2) == 0
-        changed = any(
-            not np.array_equal(a, b)
-            for a, b in zip(
-                jax.tree_util.tree_leaves(p0),
-                jax.tree_util.tree_leaves(jax.device_get(state2["params"])),
-            )
+        changed = _leaves_differ(s1, state2)
+        assert any("params" in name for name in changed), (
+            "healthy step must update params"
         )
-        assert changed, "healthy step must update params"
+        assert any("count" in name for name in changed), changed
+
+        # the same step from the same state, the guard's `ok` forced true
+        monkeypatch.setattr(
+            _sentinel, "guarded_update",
+            lambda old, new, loss, gnorm, skips: (
+                new, jnp.bool_(True), jnp.zeros((), jnp.int32)),
+        )
+        unguarded, _, _ = trainer._build_step_fn()(
+            jax.device_put(s1), batch, np.float32(1.0), skips
+        )
+        assert _leaves_differ(unguarded, state2) == []
 
     def test_consecutive_counter_accumulates(self, tmp_path):
         trainer = Trainer(_trial([]), _ctx(tmp_path), seed=0)

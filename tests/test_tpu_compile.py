@@ -9,9 +9,11 @@ a kernel at no chip time. Nothing runs: a pass says the compiler accepts
 the kernel at this shape, not that its numbers are right — the
 interpret-mode parity suites and `chip_smoke.py` say that.
 """
+import collections
 import functools
 import dataclasses
 import importlib
+import math
 import os
 import re
 
@@ -293,3 +295,62 @@ def test_nothing_is_copied_between_projections_and_flash_kernels(chips):
     text = _gpt_grad_text(None, "unroll", SingleDeviceSharding(chips[0]))
     assert text.count("tpu_custom_call") == 4, "two layers, two passes"
     assert _layout_ops_at_kernels(text) == []
+
+
+# -- the non-finite guard in the compiled train step --------------------------
+_F32_COPY = re.compile(r" = f32\[([\d,]*)\]\S* copy\(")
+
+
+def _train_step(monkeypatch, chips, workload, n_layers=2):
+    """A benchmark cell's train step (`Trainer._build_step_fn()` under
+    `SyntheticTrial`'s AdamW + clip, on the cell's mesh) compiled for the
+    described chips by `benchmark/tools/size_cells.py::train` itself, at
+    the cell's widths, `n_layers` deep and on one short row a chip (the
+    update does not depend on the batch; the compile's seconds do): the
+    program's text, and the matrices its optimizer state is made of by
+    the number of elements a chip holds of each (fsdp shards one
+    dimension, whichever it is)."""
+    import types
+
+    from benchmark.model import gpt_config_kwargs
+    from benchmark.run import Cell
+    from benchmark.tools import size_cells
+    from determined_tpu.models.gpt import GPT, GPTConfig
+
+    cell = Cell(workload)
+    cell.config = dict(cell.config, n_layer=n_layers)
+    cell.traffic = dict(cell.traffic, seq_len=128)
+    monkeypatch.setattr(
+        size_cells, "sizes", lambda lowered: lowered.compile().as_text())
+    text = size_cells.train(
+        cell, types.SimpleNamespace(devices=chips), global_batch=cell.chips)
+    assert text.count("tpu_custom_call") == 2 * n_layers, "flash not taken"
+    params = jax.eval_shape(
+        GPT(GPTConfig(**gpt_config_kwargs(cell.config))).init,
+        jax.random.PRNGKey(0))
+    matrices = dict(params["blocks"], tok_embed=params["tok_embed"])
+    return text, {
+        matrices[name].size // cell.chips: name
+        for name in ("wqkv", "wo", "wi", "wo_mlp", "tok_embed")}
+
+
+@pytest.mark.parametrize("workload", ["small-train-1k", "xl-train-fsdp4"])
+def test_guard_leaves_no_conditional_and_no_copy_of_the_state(
+        monkeypatch, chips, workload):
+    """The non-finite guard selects leaf by leaf, fused into the
+    optimizer's update, so the compiled train step has no `conditional`
+    and no computation of it copies an fp32 array the size of a chip's
+    share of a weight matrix or of `tok_embed`. Through PR 30 the guard
+    was a `lax.cond` over the whole state: XLA sank the AdamW update into
+    its branch, ran it in another layout, and copied parameters, moments
+    and gradients in and out of it, 21 such copies a step in
+    `xl-train-fsdp4` and 10 in `small-train-1k` at full depth (15 and 10
+    in these two-layer programs). The compiler's own prefetches
+    (`copy-start`) and `pos_embed`'s transposes are not the subject."""
+    text, by_size = _train_step(monkeypatch, chips, workload)
+    found = collections.Counter({"conditional": text.count(" conditional(")})
+    for shape in _F32_COPY.findall(text):
+        size = math.prod(int(d) for d in shape.split(",") if d)
+        if size in by_size:
+            found[by_size[size]] += 1
+    assert dict(found) == {"conditional": 0}
