@@ -32,7 +32,17 @@ class SyntheticTrial(JAXTrial):
     def build_model(self, mesh):
         name = self.hparams.get("model", "mnist-mlp")
         self._model_name = name
-        return get_model(name, mesh=mesh, **self.hparams.get("model_kw", {}))
+        model = get_model(name, mesh=mesh, **self.hparams.get("model_kw", {}))
+        self._input_contract = model.input_contract
+        return model
+
+    def _contract(self):
+        """The model's `input_contract` (models/base.py); asked of a
+        model built for the purpose where the trainer has built none
+        yet (building allocates nothing)."""
+        if not hasattr(self, "_input_contract"):
+            self._input_contract = self.build_model(None).input_contract
+        return self._input_contract
 
     def build_optimizer(self):
         return optax.chain(
@@ -44,22 +54,17 @@ class SyntheticTrial(JAXTrial):
         rng = np.random.default_rng(seed)
         b = int(self.hparams.get("batch_size", 16))
         sleep_s = float(self.hparams.get("sleep_s", 0.0))
-        name = self.hparams.get("model", "mnist-mlp")
+        contract = self._contract()
         while True:
             if sleep_s:
                 time.sleep(sleep_s)
-            if name.startswith("gpt"):
+            if contract == "tokens":
                 s = int(self.hparams.get("seq_len", 128))
                 vocab = int(self.hparams.get("vocab_size", 256))
                 yield {"tokens": rng.integers(0, vocab, (b, s)).astype(np.int32)}
-            elif name == "cifar-cnn":
-                yield {
-                    "image": rng.normal(size=(b, 32, 32, 3)).astype(np.float32),
-                    "label": rng.integers(0, 10, (b,)).astype(np.int32),
-                }
             else:
                 yield {
-                    "image": rng.normal(size=(b, 28, 28, 1)).astype(np.float32),
+                    "image": rng.normal(size=(b, *contract)).astype(np.float32),
                     "label": rng.integers(0, 10, (b,)).astype(np.int32),
                 }
 

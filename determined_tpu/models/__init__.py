@@ -12,6 +12,7 @@ from determined_tpu.models import gpt as gpt_mod
 from determined_tpu.models.attention import attention
 from determined_tpu.models.base import Model
 from determined_tpu.models.gpt import GPT, GPTConfig
+from determined_tpu.models.qwen3_next import Qwen3Next, Qwen3NextConfig
 from determined_tpu.models.generative import DCGAN, DDPM, DDPMConfig, GANConfig
 from determined_tpu.models.vision import CifarCNN, CNNConfig, MLPConfig, MnistMLP
 
@@ -29,6 +30,10 @@ _REGISTRY: Dict[str, Callable[..., Model]] = {
         gpt_mod.medium() if not kw else GPTConfig(**kw), mesh=mesh
     ),
     "gpt-tiny": lambda mesh=None, **kw: GPT(gpt_mod.tiny(**kw), mesh=mesh),
+    # built from the public config.json's keys (docs/dtrain.md)
+    "qwen3-next": lambda mesh=None, **kw: Qwen3Next(
+        Qwen3NextConfig.from_keys(kw), mesh=mesh
+    ),
     "mnist-mlp": lambda mesh=None, **kw: MnistMLP(
         MLPConfig(**kw) if kw else MLPConfig(), mesh=mesh
     ),
@@ -48,6 +53,8 @@ __all__ = [
     "Model",
     "GPT",
     "GPTConfig",
+    "Qwen3Next",
+    "Qwen3NextConfig",
     "MnistMLP",
     "CifarCNN",
     "DDPM",

@@ -32,9 +32,24 @@ Metrics = Dict[str, jax.Array]
 #: capture splits the step's device time by them whatever the layer loop,
 #: the remat policy or the mesh. `benchmark/scopes.json` holds the same names.
 STEP_SCOPES = ("embed", "attn", "mlp", "head_loss", "optimizer")
+#: Scopes INSIDE those, opened by the layers that only some models have
+#: (`models/qwen3_next.py`, `models/moe.py`, `ops/gated_delta.py`): `gdn`
+#: (the whole gated-delta mixer) and `gated_attn` lie inside `attn`,
+#: `gdn_scan` (the chunked rule alone) inside `gdn`; `moe_route` (router,
+#: top-k, sort, the row permutations), `moe_experts` (the grouped matmuls)
+#: and `moe_shared` inside `mlp`. `benchmark/lm_scopes.json` holds the same
+#: names.
+INNER_SCOPES = (
+    "gdn", "gdn_scan", "gated_attn", "moe_route", "moe_experts", "moe_shared",
+)
 
 
 class Model(abc.ABC):
+    #: What a batch holds, for whoever makes synthetic ones
+    #: (`exec/builtin_trials.py`): "tokens" for {"tokens": int32 [B, S]},
+    #: or an image's (height, width, channels) for {"image", "label"}.
+    input_contract: Any = (28, 28, 1)
+
     @abc.abstractmethod
     def init(self, rng: jax.Array) -> Params:
         """Build the initial parameter pytree."""

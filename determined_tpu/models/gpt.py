@@ -190,9 +190,43 @@ def _layernorm(x: jax.Array, scale: jax.Array, bias: jax.Array) -> jax.Array:
     return (y * scale + bias).astype(x.dtype)
 
 
+# -- the head and the loss sums: every language model's (models/qwen3_next.py
+# calls the same three) ------------------------------------------------------
+def head_logits(h: jax.Array, w_out: jax.Array) -> jax.Array:
+    """The LM head over final-normed hidden states h [B, S, D]; w_out
+    [D, V] already in the compute dtype."""
+    return jnp.einsum("bsd,dv->bsv", h, w_out)
+
+
+def aligned_token_sums(
+    logits: jax.Array, targets: jax.Array, mask: jax.Array
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Objective SUMS (nll, z, correct, n) over fp32 logits ALIGNED with
+    targets (position i predicts targets[i]) — the elementwise core
+    shared by the classic shifted path, the 1F1B objective, and the
+    pre-shifted zigzag-layout path."""
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    target_logit = jnp.take_along_axis(
+        logits, targets[..., None], axis=-1
+    ).squeeze(-1)
+    nll_sum = jnp.sum((lse - target_logit) * mask)
+    z_sum = jnp.sum(jnp.square(lse) * mask)
+    acc_sum = jnp.sum((jnp.argmax(logits, -1) == targets) * mask)
+    return nll_sum, z_sum, acc_sum, jnp.sum(mask)
+
+
+def next_token_sums(
+    logits: jax.Array, tokens: jax.Array, mask: jax.Array
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Classic in-model shift: position i predicts token i+1."""
+    return aligned_token_sums(logits[:, :-1], tokens[:, 1:], mask[:, 1:])
+
+
 class GPT(Model):
     """Decoder-only LM. batch = {"tokens": int32 [B, S]} (next-token loss),
     optional "loss_mask" [B, S] (1.0 = count this target position)."""
+
+    input_contract = "tokens"
 
     def __init__(self, config: GPTConfig, mesh: Optional[Mesh] = None) -> None:
         self.config = config
@@ -496,31 +530,10 @@ class GPT(Model):
     ) -> jax.Array:
         """Final layernorm + LM head shared by _head and the 1F1B last-stage
         loss (no sharding constraints); w_out already in compute dtype."""
-        return jnp.einsum("bsd,dv->bsv", _layernorm(x, lnf_scale, lnf_bias), w_out)
+        return head_logits(_layernorm(x, lnf_scale, lnf_bias), w_out)
 
-    def _aligned_token_sums(
-        self, logits: jax.Array, targets: jax.Array, mask: jax.Array
-    ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-        """Objective SUMS (nll, z, correct, n) over fp32 logits ALIGNED with
-        targets (position i predicts targets[i]) — the elementwise core
-        shared by the classic shifted path, the 1F1B objective, and the
-        pre-shifted zigzag-layout path."""
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        target_logit = jnp.take_along_axis(
-            logits, targets[..., None], axis=-1
-        ).squeeze(-1)
-        nll_sum = jnp.sum((lse - target_logit) * mask)
-        z_sum = jnp.sum(jnp.square(lse) * mask)
-        acc_sum = jnp.sum((jnp.argmax(logits, -1) == targets) * mask)
-        return nll_sum, z_sum, acc_sum, jnp.sum(mask)
-
-    def _next_token_sums(
-        self, logits: jax.Array, tokens: jax.Array, mask: jax.Array
-    ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-        """Classic in-model shift: position i predicts token i+1."""
-        return self._aligned_token_sums(
-            logits[:, :-1], tokens[:, 1:], mask[:, 1:]
-        )
+    _aligned_token_sums = staticmethod(aligned_token_sums)
+    _next_token_sums = staticmethod(next_token_sums)
 
     def _stage_scan_fn(self):
         """fp32-boundary runner over a stack [k, ...] of blocks — the

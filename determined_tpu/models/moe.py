@@ -1,0 +1,119 @@
+"""The system's expert layer: top-k routing over all experts, dropless,
+for the experts this device holds.
+
+A device under expert parallelism holds `n_held` of the layer's
+`n_routed` experts (a contiguous range from `first_expert`). The layer
+routes every token over ALL `n_routed` (the router keeps its published
+width), keeps the k best, normalises their weights over all k chosen (if
+`normalize`), and computes the part of the result its own experts give:
+
+    y = sum_{e in top-k and held} w_e SwiGLU_e(h)   [+ the shared expert]
+
+What the absent experts would add is left out; on one device there is no
+exchange and nothing stands in for it. With all experts held it is the
+uncut layer. No token is dropped: the k*T assignments are sorted by
+expert (those of experts not held last), the rows go through one grouped
+matmul a projection over the experts held (`ops/grouped_matmul.py`),
+and come back through the inverse permutation. The row buffer is sized
+for the worst case (k*T rows: every choice of every token held here);
+the grouped matmul's cost follows the rows actually routed, the
+permutations' and the elementwise passes' follow the buffer.
+
+Scopes (`models/base.py`, `INNER_SCOPES`): `moe_route` (router, top-k,
+sort, the two row permutations), `moe_experts` (the grouped matmuls and
+the activation between them), `moe_shared`.
+"""
+from __future__ import annotations
+
+from typing import Callable, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from determined_tpu.ops.grouped_matmul import (
+    grouped_matmul,
+    rows_of_tokens,
+    tokens_of_rows,
+)
+
+
+def swiglu(h: jax.Array, w_in: jax.Array, w_out: jax.Array) -> jax.Array:
+    """(SiLU(h W_gate) * h W_up) W_down; w_in [D, 2, F] holds gate and up."""
+    gu = jnp.einsum("td,dgf->tgf", h, w_in)
+    return jnp.dot(jax.nn.silu(gu[:, 0]) * gu[:, 1], w_out)
+
+
+def softmax_scores(logits: jax.Array) -> jax.Array:
+    return jax.nn.softmax(logits, axis=-1)
+
+
+def expert_layer(
+    h: jax.Array,
+    router: jax.Array,
+    w_in: jax.Array,
+    w_out: jax.Array,
+    *,
+    top_k: int,
+    first_expert: int = 0,
+    normalize: bool = True,
+    score: Callable[[jax.Array], jax.Array] = softmax_scores,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """h [T, D] (compute dtype); router [D, n_routed] (float32 master);
+    w_in [n_held, D, 2, F] and w_out [n_held, F, D] (compute dtype).
+    -> (y [T, D], counters): `held_rows` (rows routed to held experts,
+    a scalar) and `load_max_over_mean` over the held experts."""
+    t, d = h.shape
+    n_held, f = w_in.shape[0], w_in.shape[-1]
+    with jax.named_scope("moe_route"):
+        # float32 and full precision: a choice between two experts must
+        # not turn on a bf16 rounding of the scores.
+        p = score(jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
+                          precision=lax.Precision.HIGHEST))
+        top_p, top_e = lax.top_k(p, top_k)                     # [T, k]
+        if normalize:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        # assignments choice-major: j*T + t (`ops/grouped_matmul.py`)
+        local = top_e.T.reshape(-1) - first_expert
+        live = (local >= 0) & (local < n_held)                 # [k*T]
+        key = jnp.where(live, local, n_held)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * top_k, dtype=jnp.int32), unique_indices=True)
+        group_sizes = jnp.bincount(key, length=n_held + 1)[:n_held].astype(
+            jnp.int32)
+        # (the `where` also keeps what a dead row holds out of the
+        # router's gradient)
+        weight = jnp.where(live, top_p.T.reshape(-1), 0.0)[order].astype(
+            h.dtype)
+        rows = rows_of_tokens(h, order, inverse, live, top_k)  # [k*T, D]
+    with jax.named_scope("moe_experts"):
+        # Rows past the held experts' are undefined from here on
+        # (`grouped_matmul`) until `tokens_of_rows` leaves them out.
+        gu = grouped_matmul(rows, w_in.reshape(n_held, d, 2 * f), group_sizes)
+        # the combine weight rides the narrow activations (F wide), not
+        # the layer's output (D wide): w (a W_down) = (w a) W_down
+        act = jax.nn.silu(gu[:, :f]) * gu[:, f:] * weight[:, None]
+        y = grouped_matmul(act, w_out, group_sizes)
+    with jax.named_scope("moe_route"):
+        y = tokens_of_rows(y, order, inverse, live, top_k)
+        held = jnp.sum(group_sizes).astype(jnp.float32)
+        counters = {
+            "held_rows": held,
+            "load_max_over_mean": jnp.max(group_sizes).astype(jnp.float32)
+            * n_held / jnp.maximum(held, 1.0),
+        }
+    return y, counters
+
+
+def shared_expert(h: jax.Array, gate: Optional[jax.Array], w_in: jax.Array,
+                  w_out: jax.Array) -> jax.Array:
+    """sigmoid(h . gate) * SwiGLU(h), the expert every token goes through
+    (every device computes it alike: it counts once); gate [D] or None."""
+    with jax.named_scope("moe_shared"):
+        y = swiglu(h, w_in, w_out)
+        if gate is not None:
+            y = y * jax.nn.sigmoid(jnp.dot(
+                h, gate.astype(h.dtype),
+                preferred_element_type=jnp.float32))[:, None].astype(h.dtype)
+        return y

@@ -81,6 +81,8 @@ class CNNConfig:
 class CifarCNN(Model):
     """Conv stack via lax.conv_general_dilated (NHWC, MXU-friendly layouts)."""
 
+    input_contract = (32, 32, 3)
+
     def __init__(self, config: CNNConfig = CNNConfig(), mesh=None) -> None:
         self.config = config
         self.mesh = mesh

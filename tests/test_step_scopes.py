@@ -358,3 +358,59 @@ def test_fit_leaves_the_trainers_spans_on_the_host_plane(tmp_path):
         for a, b in spans["dtpu.trainer." + child]:
             assert any(ra <= a and b <= rb
                        for ra, rb in spans["dtpu.trainer.report"]), child
+
+
+# -- the inner scopes of the layers only some models have (ISSUE 32) ---------
+def test_benchmark_lm_scopes_are_the_programs_inner_scopes():
+    from benchmark import lm_scope_reduce
+    from determined_tpu.models.base import INNER_SCOPES
+
+    assert lm_scope_reduce.inner_scopes() == INNER_SCOPES
+    assert not set(INNER_SCOPES) & set(STEP_SCOPES)
+    stack = "jit(train_step)/transpose(jvp(attn))/gdn/gdn_scan/while/body/dot"
+    assert lm_scope_reduce.scopes_of(stack) == {"gdn", "gdn_scan"}
+    assert lm_scope_reduce.scopes_of("jit(f)/jvp(mlp)/moe_routes/x") == set()
+
+
+def test_lowered_qwen3_next_step_holds_outer_and_inner_scopes(tmp_path):
+    """Both mixers open `attn`, the expert layer `mlp`, and inside them
+    the inner scopes, forward, backward and recomputed; every inner
+    scope lies under the outer one it belongs to."""
+    from benchmark import lm_scope_reduce
+    from determined_tpu.exec.builtin_trials import SyntheticTrial
+    from determined_tpu.models.base import INNER_SCOPES
+
+    kw = dict(
+        vocab_size=96, hidden_size=32, num_hidden_layers=4,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        linear_key_head_dim=8, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_value_head_dim=8, num_experts=4,
+        num_experts_routed=16, num_experts_per_tok=4,
+        moe_intermediate_size=16, shared_expert_intermediate_size=16)
+    trial = SyntheticTrial({"model": "qwen3-next", "model_kw": kw,
+                            "seq_len": 32, "vocab_size": 96, "batch_size": 8})
+    trainer = Trainer(trial, _dummy_core(tmp_path))
+    batch = trainer._put_batch(next(trial.build_training_data()))
+    text = trainer._build_step_fn().lower(
+        trainer.state, batch, np.float32(1.0), trainer._zero_skips()
+    ).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    assert {scope_reduce.scope_of(n, STEP_SCOPES) for n in names} >= set(
+        STEP_SCOPES)
+    outer_of = {"gdn": "attn", "gdn_scan": "attn", "gated_attn": "attn",
+                "moe_route": "mlp", "moe_experts": "mlp", "moe_shared": "mlp"}
+    assert set(outer_of) == set(INNER_SCOPES)
+    seen = set()
+    for n in names:
+        for inner in lm_scope_reduce.scopes_of(n):
+            seen.add(inner)
+            # (the body of the mesh's shard_map starts its own locations)
+            if n.startswith("jit("):
+                assert scope_reduce.scope_of(
+                    n, STEP_SCOPES) == outer_of[inner], n
+    assert seen == set(INNER_SCOPES), sorted(seen)
+    # the chunked rule repeated in the backward is under its scopes too
+    assert any("rematted_computation" in n
+               and "gdn_scan" in lm_scope_reduce.scopes_of(n)
+               and scope_reduce.scope_of(n, STEP_SCOPES) == "attn"
+               for n in names)

@@ -354,3 +354,40 @@ def test_guard_leaves_no_conditional_and_no_copy_of_the_state(
         if size in by_size:
             found[by_size[size]] += 1
     assert dict(found) == {"conditional": 0}
+
+
+# -- the cell of another architecture (ISSUE 32) -------------------------------
+def test_qwen3_next_cells_step_compiles_with_no_conditional(
+        monkeypatch, chips):
+    """`qwen3next-train-8k-ep16share`'s train step (`Trainer`'s own, built
+    as the cell's driver builds it, by `benchmark/tools/size_train_lm.py`)
+    compiled for a described v5e at the published widths, one period deep
+    as the cell is, on one row of 1024 tokens with 4 experts held and 2048
+    rows of vocabulary (the compile's seconds follow those; the program's
+    structure does not): the chip's compiler takes the chunked delta
+    rule, the blocked flash kernels at head width 256 (the sequence lies
+    past one tile, so not the monolithic ones) and the grouped matmul
+    (its own Mosaic kernel, sized by the rows routed at run time), and
+    the step has no `conditional`: PR 31's guard selects, and a dropless
+    expert layer needs none."""
+    import types
+
+    from benchmark.run import Cell
+    from benchmark.tools import size_cells, size_train_lm
+
+    cell = Cell("qwen3next-train-8k-ep16share")
+    cell.config = dict(cell.config, num_experts=4, vocab_size=2048)
+    cell.traffic = dict(cell.traffic, seq_len=1024)
+    monkeypatch.setattr(
+        size_cells, "sizes", lambda lowered: lowered.compile().as_text())
+    text = size_train_lm.train(
+        cell, types.SimpleNamespace(devices=chips), global_batch=1)
+    assert text.count(" conditional(") == 0
+    assert "ragged-dot" in text, "the grouped matmul is not the TPU's own"
+    # one attention layer: a blocked forward, and its backward
+    from benchmark import kernel_events, trace_reduce
+    ops = {trace_reduce.op_name(line.strip()) for line in text.splitlines()
+           if trace_reduce.MOSAIC in line and "ragged-dot" not in line}
+    found = {k for op in ops for k in ("flash_forward", "flash_backward")
+             if re.search(kernel_events.kernel(k)["pattern"], op)}
+    assert found == {"flash_forward", "flash_backward"}, ops
