@@ -12,12 +12,16 @@ width), keeps the k best, normalises their weights over all k chosen (if
 What the absent experts would add is left out; on one device there is no
 exchange and nothing stands in for it. With all experts held it is the
 uncut layer. No token is dropped: the k*T assignments are sorted by
-expert (those of experts not held last), the rows go through one grouped
-matmul a projection over the experts held (`ops/grouped_matmul.py`),
-and come back through the inverse permutation. The row buffer is sized
-for the worst case (k*T rows: every choice of every token held here);
-the grouped matmul's cost follows the rows actually routed, the
-permutations' and the elementwise passes' follow the buffer.
+expert (those of experts not held last, so the live rows are the first
+`n_live` sorted positions), the rows go through one grouped matmul a
+projection over the experts held (`ops/grouped_matmul.py`), and each
+token's rows are summed back. The row buffer is sized for the worst case
+(k*T rows: every choice of every token held here) and is never filled:
+past the live rows it is UNINITIALISED going into the matmuls and
+undefined coming out of them, and nothing reads it there. The grouped
+matmuls' cost and the two permutations' (slabs of the live rows under a
+`while`) follow the rows actually routed; the elementwise passes between
+the matmuls still follow the buffer.
 
 Scopes (`models/base.py`, `INNER_SCOPES`): `moe_route` (router, top-k,
 sort, the two row permutations), `moe_experts` (the grouped matmuls and
@@ -78,26 +82,26 @@ def expert_layer(
         live = (local >= 0) & (local < n_held)                 # [k*T]
         key = jnp.where(live, local, n_held)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(t * top_k, dtype=jnp.int32), unique_indices=True)
         group_sizes = jnp.bincount(key, length=n_held + 1)[:n_held].astype(
             jnp.int32)
         # (the `where` also keeps what a dead row holds out of the
         # router's gradient)
         weight = jnp.where(live, top_p.T.reshape(-1), 0.0)[order].astype(
             h.dtype)
-        rows = rows_of_tokens(h, order, inverse, live, top_k)  # [k*T, D]
+        n_live = jnp.sum(group_sizes)   # the live rows sort first
+        rows = rows_of_tokens(h, order, n_live, top_k)         # [k*T, D]
     with jax.named_scope("moe_experts"):
-        # Rows past the held experts' are undefined from here on
-        # (`grouped_matmul`) until `tokens_of_rows` leaves them out.
+        # Rows past the held experts' are uninitialised coming in and
+        # undefined from here on (`grouped_matmul`), until
+        # `tokens_of_rows` leaves them out.
         gu = grouped_matmul(rows, w_in.reshape(n_held, d, 2 * f), group_sizes)
         # the combine weight rides the narrow activations (F wide), not
         # the layer's output (D wide): w (a W_down) = (w a) W_down
         act = jax.nn.silu(gu[:, :f]) * gu[:, f:] * weight[:, None]
         y = grouped_matmul(act, w_out, group_sizes)
     with jax.named_scope("moe_route"):
-        y = tokens_of_rows(y, order, inverse, live, top_k)
-        held = jnp.sum(group_sizes).astype(jnp.float32)
+        y = tokens_of_rows(y, order, n_live, top_k)
+        held = n_live.astype(jnp.float32)
         counters = {
             "held_rows": held,
             "load_max_over_mean": jnp.max(group_sizes).astype(jnp.float32)

@@ -213,30 +213,94 @@ def test_grouped_matmul_is_the_loop_over_experts(load, what):
     assert _relative(got, want) < RTOL
 
 
-def test_row_permutations_are_each_others_transpose():
-    t, k, d = 12, 3, 5
-    order = jax.random.permutation(jax.random.PRNGKey(0), t * k).astype(jnp.int32)
-    inverse = jnp.argsort(order).astype(jnp.int32)
+# -- the two row permutations: slabs of the live rows -------------------------
+PERM_T, PERM_K, PERM_D, PERM_SLAB = 12, 3, 5, 8     # 36 rows, slabs of 8
+
+
+def _routed(n_live):
+    """`expert_layer`'s own structure: `n_live` of the k*T assignments
+    go to one of 4 experts held, and those sort first."""
+    kt = PERM_T * PERM_K
+    live = jnp.zeros(kt, bool).at[jax.random.permutation(
+        jax.random.PRNGKey(0), kt)[:n_live]].set(True)
+    expert = jax.random.randint(jax.random.PRNGKey(4), (kt,), 0, 4)
+    order = jnp.argsort(jnp.where(live, expert, 4), stable=True).astype(
+        jnp.int32)
+    return order, jnp.argsort(order).astype(jnp.int32), live
+
+
+@pytest.mark.parametrize("n_live", [
+    0, 1, PERM_SLAB - 1, PERM_SLAB, PERM_SLAB + 1, PERM_T * PERM_K])
+def test_row_permutations_are_each_others_transpose(monkeypatch, n_live):
+    """Either permutation touches the sorted positions below `n_live`
+    and nothing else: what lies at or past it (uninitialised rows on the
+    way in, undefined ones on the way out) is NaN here and reaches
+    neither a result nor a gradient, whatever `n_live` is to the slab
+    (none, a part of one, one to the row, one and a row, the whole
+    buffer, which 8 does not divide)."""
+    t, k, d = PERM_T, PERM_K, PERM_D
+    monkeypatch.setattr(gm, "slab_rows", lambda k, t: PERM_SLAB)
+    monkeypatch.setattr(
+        gm.lax, "empty", lambda shape, dtype: jnp.full(shape, jnp.nan, dtype))
+    order, inverse, live = _routed(n_live)
+    n = jnp.int32(n_live)
     h = jax.random.normal(jax.random.PRNGKey(1), (t, d))
     y = jax.random.normal(jax.random.PRNGKey(2), (t * k, d))
-    live = jax.random.bernoulli(jax.random.PRNGKey(3), 0.4, (t * k,))
-    dead_rows = ~live[order]            # by sorted position
-    rows = gm.rows_of_tokens(h, order, inverse, live, k)
-    np.testing.assert_array_equal(rows, h[order % t])
-    # what a dead row holds never reaches a token, forward or backward
-    poisoned = jnp.where(dead_rows[:, None], jnp.nan, y)
-    back = gm.tokens_of_rows(poisoned, order, inverse, live, k)
-    assert np.isfinite(np.asarray(back)).all()
+    dead = jnp.arange(t * k) >= n_live          # by sorted position
+    poisoned = jnp.where(dead[:, None], jnp.nan, y)
+
+    rows, back_of_rows = jax.vjp(
+        lambda h: gm.rows_of_tokens(h, order, n, k), h)
+    np.testing.assert_array_equal(rows[:n_live], h[order % t][:n_live])
+    # the form this one replaced: every row through the inverse
+    # permutation, widened, the dead ones masked, k slabs of [T, D] added
+    want = jnp.sum(jnp.where(live[:, None], y[inverse], 0.0).reshape(
+        k, t, d), axis=0)
+    back, rows_of_back = jax.vjp(
+        lambda y: gm.tokens_of_rows(y, order, n, k), poisoned)
+    np.testing.assert_allclose(back, want, rtol=1e-6, atol=1e-6)
     # <rows_of_tokens(h), y> == <h, tokens_of_rows(y)> over the live rows
     np.testing.assert_allclose(
-        jnp.sum(jnp.where(dead_rows[:, None], 0.0, rows * y)),
-        jnp.sum(h * back), rtol=1e-4, atol=1e-4)
-    got = jax.grad(lambda h: jnp.sum(jnp.where(
-        dead_rows[:, None], 0.0,
-        gm.rows_of_tokens(h, order, inverse, live, k) * poisoned)))(h)
-    want = jax.grad(lambda h: jnp.sum(jnp.where(
-        dead_rows[:, None], 0.0, h[order % t] * y)))(h)
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+        jnp.sum(rows[:n_live] * y[:n_live]), jnp.sum(h * back),
+        rtol=1e-4, atol=1e-4)
+    # and the gradients: each one's is the other, of a poisoned cotangent
+    (d_h,) = back_of_rows(poisoned)
+    np.testing.assert_allclose(d_h, want, rtol=1e-6, atol=1e-6)
+    (d_y,) = rows_of_back(h)
+    np.testing.assert_array_equal(d_y[:n_live], h[order % t][:n_live])
+
+
+def test_expert_layer_reads_nothing_of_the_uninitialised_rows(monkeypatch):
+    """The layer itself with its rows buffer allocated full of NaN and
+    slabs short enough to leave most of it so: the loss and every
+    gradient are the ones a zero-filled buffer gives."""
+    t, d, f, e = 48, 32, 16, 16
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    at = (jax.random.normal(ks[0], (t, d)), jax.random.normal(ks[1], (d, e)),
+          jax.random.normal(ks[2], (4, d, 2, f)) * 0.2,
+          jax.random.normal(ks[3], (4, f, d)) * 0.2)
+    w = jax.random.normal(ks[4], (t, d))
+    monkeypatch.setattr(gm, "slab_rows", lambda k, t: 32)
+
+    def loss(h, router, w_in, w_out):
+        y, c = moe.expert_layer(h, router, w_in, w_out, top_k=4,
+                                first_expert=8)
+        return jnp.sum(y * w), c["held_rows"]
+
+    def run():
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3), has_aux=True)(
+            *at)
+
+    (clean, rows), clean_grads = run()
+    assert 0 < float(rows) < 4 * t - 32, "no row would be left poisoned"
+    monkeypatch.setattr(
+        gm.lax, "empty", lambda shape, dtype: jnp.full(shape, jnp.nan, dtype))
+    (got, _), grads = run()
+    assert np.isfinite(float(got)) and float(got) == float(clean)
+    for name, g, want in zip(("h", "router", "w_in", "w_out"),
+                             grads, clean_grads):
+        assert np.isfinite(np.asarray(g)).all(), name
+        np.testing.assert_array_equal(g, want, err_msg=name)
 
 
 # -- the shares add up to the uncut layer ------------------------------------

@@ -357,31 +357,44 @@ def test_guard_leaves_no_conditional_and_no_copy_of_the_state(
 
 
 # -- the cell of another architecture (ISSUE 32) -------------------------------
-def test_qwen3_next_cells_step_compiles_with_no_conditional(
-        monkeypatch, chips):
+_QWEN_STEP = {}
+
+
+def _qwen_step(monkeypatch, chips):
     """`qwen3next-train-8k-ep16share`'s train step (`Trainer`'s own, built
     as the cell's driver builds it, by `benchmark/tools/size_train_lm.py`)
     compiled for a described v5e at the published widths, one period deep
     as the cell is, on one row of 1024 tokens with 4 experts held and 2048
     rows of vocabulary (the compile's seconds follow those; the program's
-    structure does not): the chip's compiler takes the chunked delta
-    rule, the blocked flash kernels at head width 256 (the sequence lies
-    past one tile, so not the monolithic ones) and the grouped matmul
-    (its own Mosaic kernel, sized by the rows routed at run time), and
-    the step has no `conditional`: PR 31's guard selects, and a dropless
-    expert layer needs none."""
+    structure does not): its text, the bytes of its temporaries and the
+    cell, compiled once a module."""
     import types
 
     from benchmark.run import Cell
     from benchmark.tools import size_cells, size_train_lm
 
-    cell = Cell("qwen3next-train-8k-ep16share")
-    cell.config = dict(cell.config, num_experts=4, vocab_size=2048)
-    cell.traffic = dict(cell.traffic, seq_len=1024)
-    monkeypatch.setattr(
-        size_cells, "sizes", lambda lowered: lowered.compile().as_text())
-    text = size_train_lm.train(
-        cell, types.SimpleNamespace(devices=chips), global_batch=1)
+    if not _QWEN_STEP:
+        cell = Cell("qwen3next-train-8k-ep16share")
+        cell.config = dict(cell.config, num_experts=4, vocab_size=2048)
+        cell.traffic = dict(cell.traffic, seq_len=1024)
+        monkeypatch.setattr(size_cells, "sizes", lambda low: low.compile())
+        compiled = size_train_lm.train(
+            cell, types.SimpleNamespace(devices=chips), global_batch=1)
+        _QWEN_STEP.update(
+            cell=cell, text=compiled.as_text(),
+            temp=compiled.memory_analysis().temp_size_in_bytes)
+    return types.SimpleNamespace(**_QWEN_STEP)
+
+
+def test_qwen3_next_cells_step_compiles_with_no_conditional(
+        monkeypatch, chips):
+    """The chip's compiler takes the chunked delta rule, the blocked
+    flash kernels at head width 256 (the sequence lies past one tile, so
+    not the monolithic ones) and the grouped matmul (its own Mosaic
+    kernel, sized by the rows routed at run time), and the step has no
+    `conditional`: PR 31's guard selects, and a dropless expert layer
+    needs none."""
+    text = _qwen_step(monkeypatch, chips).text
     assert text.count(" conditional(") == 0
     assert "ragged-dot" in text, "the grouped matmul is not the TPU's own"
     # one attention layer: a blocked forward, and its backward
@@ -391,3 +404,28 @@ def test_qwen3_next_cells_step_compiles_with_no_conditional(
     found = {k for op in ops for k in ("flash_forward", "flash_backward")
              if re.search(kernel_events.kernel(k)["pattern"], op)}
     assert found == {"flash_forward", "flash_backward"}, ops
+
+
+def test_qwen3_next_cells_step_moves_no_row_buffer_whole(monkeypatch, chips):
+    """The expert layer's row buffer is k x T rows of the hidden width
+    for the worst case, and no instruction of the step gathers or widens
+    a whole one: the two row permutations move slabs of the rows routed
+    under a `while` (`ops/grouped_matmul.py`). Through PR 32 they were
+    whole-buffer gathers, 5 a layer (20 here, and 20 of [81 920, 2048] in
+    the cell), and the sum back widened the buffer to float32 first (8)."""
+    step = _qwen_step(monkeypatch, chips)
+    width = step.cell.config["hidden_size"]
+    rows = (step.cell.config["num_experts_per_tok"]
+            * step.cell.traffic["seq_len"])
+    whole = re.compile(rf" = \w+\[{rows},{width}\]\S* (gather|convert)\(")
+    found = collections.Counter(m.group(1) for m in whole.finditer(step.text))
+    assert dict(found) == {}
+    assert re.search(rf" = \w+\[\d+,{width}\]\S* gather\(", step.text), (
+        "no gather of rows at all: wrong pattern?")
+    # A `while`'s buffer cannot be rebuilt where it is read, as a gather
+    # fusion's could, so the weights' gradients, which read two such
+    # buffers a layer, must not be put off to the end of the step:
+    # `grouped_matmul` ties them to the rows' gradients. By the
+    # compiler's `memory_analysis()` here: 0.79 GiB of temporaries so,
+    # 1.28 with them put off (0.90 with PR 32's whole-buffer gathers).
+    assert step.temp < 1.0 * 2 ** 30, step.temp / 2 ** 30
