@@ -10,6 +10,8 @@ hparams:
   model:      registry name (default "mnist-mlp")
   model_kw:   dict passed to the registry constructor
   lr:         adam learning rate (default 1e-3)
+  lr_warmup_steps: steps of a linear ramp from 0 to lr, constant after
+              (default 0: no schedule, lr from the first step)
   batch_size: global batch (default 16)
   seq_len:    for LM models (default matches model config)
   sleep_s:    per-batch sleep — the "no-op trial" knob for scheduler tests
@@ -45,10 +47,12 @@ class SyntheticTrial(JAXTrial):
         return self._input_contract
 
     def build_optimizer(self):
-        return optax.chain(
-            optax.clip_by_global_norm(1.0),
-            optax.adamw(float(self.hparams.get("lr", 1e-3))),
-        )
+        lr = float(self.hparams.get("lr", 1e-3))
+        warmup = int(self.hparams.get("lr_warmup_steps", 0))
+        if warmup > 0:
+            # the update of step n (counted from 1) runs at lr * n / warmup
+            lr = optax.linear_schedule(lr / warmup, lr, warmup - 1)
+        return optax.chain(optax.clip_by_global_norm(1.0), optax.adamw(lr))
 
     def _batches(self, seed: int) -> Iterator[Dict[str, Any]]:
         rng = np.random.default_rng(seed)

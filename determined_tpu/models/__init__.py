@@ -12,6 +12,7 @@ from determined_tpu.models import gpt as gpt_mod
 from determined_tpu.models.attention import attention
 from determined_tpu.models.base import Model
 from determined_tpu.models.gpt import GPT, GPTConfig
+from determined_tpu.models.glm4_moe_lite import Glm4MoeLite, Glm4MoeLiteConfig
 from determined_tpu.models.qwen3_next import Qwen3Next, Qwen3NextConfig
 from determined_tpu.models.generative import DCGAN, DDPM, DDPMConfig, GANConfig
 from determined_tpu.models.vision import CifarCNN, CNNConfig, MLPConfig, MnistMLP
@@ -34,6 +35,9 @@ _REGISTRY: Dict[str, Callable[..., Model]] = {
     "qwen3-next": lambda mesh=None, **kw: Qwen3Next(
         Qwen3NextConfig.from_keys(kw), mesh=mesh
     ),
+    "glm4-moe-lite": lambda mesh=None, **kw: Glm4MoeLite(
+        Glm4MoeLiteConfig.from_keys(kw), mesh=mesh
+    ),
     "mnist-mlp": lambda mesh=None, **kw: MnistMLP(
         MLPConfig(**kw) if kw else MLPConfig(), mesh=mesh
     ),
@@ -55,6 +59,8 @@ __all__ = [
     "GPTConfig",
     "Qwen3Next",
     "Qwen3NextConfig",
+    "Glm4MoeLite",
+    "Glm4MoeLiteConfig",
     "MnistMLP",
     "CifarCNN",
     "DDPM",

@@ -37,10 +37,15 @@ STEP_SCOPES = ("embed", "attn", "mlp", "head_loss", "optimizer")
 #: (the whole gated-delta mixer) and `gated_attn` lie inside `attn`,
 #: `gdn_scan` (the chunked rule alone) inside `gdn`; `moe_route` (router,
 #: top-k, sort, the row permutations), `moe_experts` (the grouped matmuls)
-#: and `moe_shared` inside `mlp`. `benchmark/lm_scopes.json` holds the same
-#: names.
+#: and `moe_shared` inside `mlp`; `mla` (`models/glm4_moe_lite.py`: the
+#: latent-attention mixer but its flash kernels) inside `attn`, and `mtp`
+#: (the multi-token-prediction module: its projection inside `embed`, its
+#: block inside `attn` and `mlp`, its head pass and loss inside
+#: `head_loss`). `benchmark/lm_scopes.json` holds the first six names,
+#: `benchmark/mla_scopes.json` the last two.
 INNER_SCOPES = (
     "gdn", "gdn_scan", "gated_attn", "moe_route", "moe_experts", "moe_shared",
+    "mla", "mtp",
 )
 
 

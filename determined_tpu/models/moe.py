@@ -4,8 +4,10 @@ for the experts this device holds.
 A device under expert parallelism holds `n_held` of the layer's
 `n_routed` experts (a contiguous range from `first_expert`). The layer
 routes every token over ALL `n_routed` (the router keeps its published
-width), keeps the k best, normalises their weights over all k chosen (if
-`normalize`), and computes the part of the result its own experts give:
+width), keeps the k best (by score, or by score plus a selection `bias`
+that takes part in the choice alone), normalises their weights over all
+k chosen (if `normalize`), scales them (`scale`), and computes the part
+of the result its own experts give:
 
     y = sum_{e in top-k and held} w_e SwiGLU_e(h)   [+ the shared expert]
 
@@ -29,11 +31,12 @@ the activation between them), `moe_shared`.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 from determined_tpu.ops.grouped_matmul import (
     grouped_matmul,
@@ -52,6 +55,11 @@ def softmax_scores(logits: jax.Array) -> jax.Array:
     return jax.nn.softmax(logits, axis=-1)
 
 
+def sigmoid_scores(logits: jax.Array) -> jax.Array:
+    """An expert's score on its own, not against the others'."""
+    return jax.nn.sigmoid(logits)
+
+
 def expert_layer(
     h: jax.Array,
     router: jax.Array,
@@ -62,9 +70,16 @@ def expert_layer(
     first_expert: int = 0,
     normalize: bool = True,
     score: Callable[[jax.Array], jax.Array] = softmax_scores,
+    bias: Optional[jax.Array] = None,
+    scale: float = 1.0,
+    norm_eps: float = 0.0,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """h [T, D] (compute dtype); router [D, n_routed] (float32 master);
     w_in [n_held, D, 2, F] and w_out [n_held, F, D] (compute dtype).
+    `bias` [n_routed]: a selection bias, added to the scores for the
+    CHOICE of the k experts and for nothing else (the weights are the
+    scores'; no gradient reaches it). `scale` multiplies the k weights
+    after their normalisation, `norm_eps` guards its divisor.
     -> (y [T, D], counters): `held_rows` (rows routed to held experts,
     a scalar) and `load_max_over_mean` over the held experts."""
     t, d = h.shape
@@ -74,9 +89,17 @@ def expert_layer(
         # not turn on a bf16 rounding of the scores.
         p = score(jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
                           precision=lax.Precision.HIGHEST))
-        top_p, top_e = lax.top_k(p, top_k)                     # [T, k]
+        if bias is None:
+            top_p, top_e = lax.top_k(p, top_k)                 # [T, k]
+        else:
+            _, top_e = lax.top_k(
+                p + lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+            top_p = jnp.take_along_axis(p, top_e, axis=-1)
         if normalize:
-            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            total = jnp.sum(top_p, axis=-1, keepdims=True)
+            top_p = top_p / (total + norm_eps if norm_eps else total)
+        if scale != 1.0:
+            top_p = top_p * scale
         # assignments choice-major: j*T + t (`ops/grouped_matmul.py`)
         local = top_e.T.reshape(-1) - first_expert
         live = (local >= 0) & (local < n_held)                 # [k*T]
@@ -121,3 +144,26 @@ def shared_expert(h: jax.Array, gate: Optional[jax.Array], w_in: jax.Array,
                 h, gate.astype(h.dtype),
                 preferred_element_type=jnp.float32))[:, None].astype(h.dtype)
         return y
+
+
+def on_batch_shards(
+    local: Callable[[jax.Array, Any], Tuple[jax.Array, jax.Array]],
+    h: jax.Array, w: Any, mesh: Optional[Mesh], batch_axes: Tuple[str, ...],
+) -> Tuple[jax.Array, jax.Array]:
+    """`local(h [b, S, D], w) -> (y, counters [1, n])` on every batch
+    shard of h with its own copy of `w`: each shard routes its own tokens
+    through the experts held (no exchange), and the counters are the
+    shards' means. One call where the batch is not sharded."""
+    shards = 1
+    if mesh is not None:
+        for axis in batch_axes:
+            shards *= mesh.shape.get(axis, 1)
+    if shards == 1:
+        y, counters = local(h, w)
+    else:
+        spec = P(batch_axes)
+        y, counters = shard_map(
+            local, mesh=mesh,
+            in_specs=(spec, jax.tree.map(lambda _: P(), w)),
+            out_specs=(spec, spec), check_vma=False)(h, w)
+    return y, jnp.mean(counters, axis=0)

@@ -45,7 +45,7 @@ from typing import Any, Dict, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax, shard_map
+from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from determined_tpu.models import moe
@@ -371,21 +371,10 @@ class Qwen3Next(Model):
         mean]). On a mesh each batch shard routes its own tokens through
         its copy of the experts held (no exchange), and the counters are
         the shards' means."""
-        c = self.config
-        h = _rmsnorm0(x, norm, c.rms_norm_eps)
-        shards = 1
-        if self.mesh is not None:
-            for axis in attn_mod.BATCH_AXES:
-                shards *= self.mesh.shape.get(axis, 1)
-        if shards == 1:
-            y, counters = self._moe_local(h, w)
-        else:
-            spec = P(attn_mod.BATCH_AXES)
-            y, counters = shard_map(
-                self._moe_local, mesh=self.mesh,
-                in_specs=(spec, jax.tree.map(lambda _: P(), w)),
-                out_specs=(spec, spec), check_vma=False)(h, w)
-        return self._constrain(x + y, _ACT), jnp.mean(counters, axis=0)
+        h = _rmsnorm0(x, norm, self.config.rms_norm_eps)
+        y, counters = moe.on_batch_shards(
+            self._moe_local, h, w, self.mesh, attn_mod.BATCH_AXES)
+        return self._constrain(x + y, _ACT), counters
 
     def _forward_trunk(self, params: Dict[str, Any], tokens: jax.Array
                        ) -> Tuple[jax.Array, jax.Array]:

@@ -362,14 +362,80 @@ def test_fit_leaves_the_trainers_spans_on_the_host_plane(tmp_path):
 
 # -- the inner scopes of the layers only some models have (ISSUE 32) ---------
 def test_benchmark_lm_scopes_are_the_programs_inner_scopes():
-    from benchmark import lm_scope_reduce
+    """`lm_scopes.json`'s list (ISSUE 32's, which a later PR may not
+    edit) and then `mla_scopes.json`'s (ISSUE 35's) are `INNER_SCOPES`."""
+    from benchmark import lm_scope_reduce, mla_scope_reduce
     from determined_tpu.models.base import INNER_SCOPES
 
-    assert lm_scope_reduce.inner_scopes() == INNER_SCOPES
+    assert (lm_scope_reduce.inner_scopes()
+            + mla_scope_reduce.inner_scopes()) == INNER_SCOPES
+    assert len(set(INNER_SCOPES)) == len(INNER_SCOPES)
     assert not set(INNER_SCOPES) & set(STEP_SCOPES)
     stack = "jit(train_step)/transpose(jvp(attn))/gdn/gdn_scan/while/body/dot"
     assert lm_scope_reduce.scopes_of(stack) == {"gdn", "gdn_scan"}
     assert lm_scope_reduce.scopes_of("jit(f)/jvp(mlp)/moe_routes/x") == set()
+    stack = "jit(train_step)/transpose(jvp(mtp))/attn/mla/dot_general"
+    assert mla_scope_reduce.scopes_of(
+        stack, mla_scope_reduce.inner_scopes()) == {"mtp", "mla"}
+    assert lm_scope_reduce.scopes_of(stack) == set()
+
+
+def _step_names(tmp_path, model, kw):
+    """The locations of `model`'s lowered train step on one device (the
+    cells' `{data: 1}`: on more, the expert layer runs in a shard_map,
+    whose body starts its own locations)."""
+    from determined_tpu.exec.builtin_trials import SyntheticTrial
+    from determined_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    trial = SyntheticTrial({"model": model, "model_kw": kw,
+                            "seq_len": 32, "vocab_size": 96, "batch_size": 8})
+    trainer = Trainer(trial, _dummy_core(tmp_path), mesh=make_mesh(
+        MeshConfig(data=1), devices=jax.devices()[:1]))
+    batch = trainer._put_batch(next(trial.build_training_data()))
+    text = trainer._build_step_fn().lower(
+        trainer.state, batch, np.float32(1.0), trainer._zero_skips()
+    ).as_text(debug_info=True)
+    return set(re.findall(r'loc\("([^"]+)"', text))
+
+
+def _inner_scopes_of(name):
+    from benchmark import lm_scope_reduce, mla_scope_reduce
+
+    return lm_scope_reduce.scopes_of(name) | mla_scope_reduce.scopes_of(
+        name, mla_scope_reduce.inner_scopes())
+
+
+def test_lowered_glm4_moe_lite_step_holds_outer_and_inner_scopes(tmp_path):
+    """Every block opens `attn` and `mlp`; inside them `mla` and the
+    expert layer's three; the MTP module opens `mtp` OUTSIDE the step
+    scopes of its parts (`embed`, `attn`, `mlp`, `head_loss`), so each of
+    its operations still has its one step scope; forward, backward and
+    recomputed alike."""
+    kw = dict(
+        vocab_size=96, hidden_size=32, num_hidden_layers=3,
+        num_attention_heads=4, num_key_value_heads=4, q_lora_rank=16,
+        kv_lora_rank=12, qk_nope_head_dim=12, qk_rope_head_dim=4,
+        v_head_dim=16, intermediate_size=48, moe_intermediate_size=16,
+        n_routed_experts=4, num_experts_routed=16, first_expert=4)
+    names = _step_names(tmp_path, "glm4-moe-lite", kw)
+    assert {scope_reduce.scope_of(n, STEP_SCOPES) for n in names} >= set(
+        STEP_SCOPES)
+    outer_of = {"mla": {"attn"}, "moe_route": {"mlp"}, "moe_experts": {"mlp"},
+                "moe_shared": {"mlp"},
+                "mtp": {"embed", "attn", "mlp", "head_loss"}}
+    under = {inner: set() for inner in outer_of}
+    for n in names:
+        for inner in _inner_scopes_of(n):
+            under[inner].add(scope_reduce.scope_of(n, STEP_SCOPES))
+    assert under == outer_of
+    for inner in ("mla", "mtp"):
+        assert any("rematted_computation" in n and inner in _inner_scopes_of(n)
+                   for n in names), inner
+        assert any("transpose(" in n and inner in _inner_scopes_of(n)
+                   for n in names), inner
+    # the MTP module's block is under its parts' own scopes too
+    assert any({"mtp", "mla"} <= _inner_scopes_of(n) for n in names)
+    assert any({"mtp", "moe_experts"} <= _inner_scopes_of(n) for n in names)
 
 
 def test_lowered_qwen3_next_step_holds_outer_and_inner_scopes(tmp_path):
@@ -399,7 +465,7 @@ def test_lowered_qwen3_next_step_holds_outer_and_inner_scopes(tmp_path):
         STEP_SCOPES)
     outer_of = {"gdn": "attn", "gdn_scan": "attn", "gated_attn": "attn",
                 "moe_route": "mlp", "moe_experts": "mlp", "moe_shared": "mlp"}
-    assert set(outer_of) == set(INNER_SCOPES)
+    assert set(outer_of) == set(lm_scope_reduce.inner_scopes())
     seen = set()
     for n in names:
         for inner in lm_scope_reduce.scopes_of(n):
@@ -408,7 +474,8 @@ def test_lowered_qwen3_next_step_holds_outer_and_inner_scopes(tmp_path):
             if n.startswith("jit("):
                 assert scope_reduce.scope_of(
                     n, STEP_SCOPES) == outer_of[inner], n
-    assert seen == set(INNER_SCOPES), sorted(seen)
+    assert seen == set(outer_of), sorted(seen)
+    assert set(outer_of) < set(INNER_SCOPES)
     # the chunked rule repeated in the backward is under its scopes too
     assert any("rematted_computation" in n
                and "gdn_scan" in lm_scope_reduce.scopes_of(n)

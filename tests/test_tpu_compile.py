@@ -429,3 +429,47 @@ def test_qwen3_next_cells_step_moves_no_row_buffer_whole(monkeypatch, chips):
     # compiler's `memory_analysis()` here: 0.79 GiB of temporaries so,
     # 1.28 with them put off (0.90 with PR 32's whole-buffer gathers).
     assert step.temp < 1.0 * 2 ** 30, step.temp / 2 ** 30
+
+
+# -- the latent-attention cell (ISSUE 35) --------------------------------------
+def test_glm47flash_cells_step_keeps_the_kernels_names(monkeypatch, chips):
+    """`glm47flash-train-8k-ep8share`'s train step (`Trainer`'s own, built
+    as the cell's driver builds it) compiled for a described v5e at the
+    published widths, on one row of 1024 tokens with 2 experts held and
+    2048 rows of vocabulary: the chip's compiler takes latent attention
+    through the blocked flash kernels at 20 heads of 256 under the names
+    `benchmark/kernels/flash_*.json` match (the MLA half's `checkpoint`
+    and scopes close around the attention call; the MTP module's block
+    brings a pair of its own), the grouped matmul is the TPU's own, and
+    the step has no `conditional`."""
+    import types
+
+    from benchmark import kernel_events, trace_reduce
+    from benchmark.drivers import train_lm_models
+    from benchmark.run import Cell
+    from benchmark.tools import size_cells, size_train_lm
+
+    cell = Cell("glm47flash-train-8k-ep8share")
+    cell.config = dict(cell.config, n_routed_experts=2, vocab_size=2048,
+                       num_hidden_layers=2)
+    cell.traffic = dict(cell.traffic, seq_len=1024)
+    monkeypatch.setattr(size_cells, "sizes", lambda low: low.compile())
+    with train_lm_models.as_train_lm():
+        text = size_train_lm.train(
+            cell, types.SimpleNamespace(devices=chips), global_batch=1
+        ).as_text()
+    assert text.count(" conditional(") == 0
+    assert "ragged-dot" in text, "the grouped matmul is not the TPU's own"
+    ops = collections.Counter(
+        trace_reduce.op_name(line.strip()).split(".")[0]
+        for line in text.splitlines()
+        if trace_reduce.MOSAIC in line and " custom-call(" in line
+        and "ragged-dot" not in line)
+    found = collections.Counter()
+    for op, n in ops.items():
+        for k in ("flash_forward", "flash_backward"):
+            if re.search(kernel_events.kernel(k)["pattern"], op):
+                found[k] += n
+    # 2 layers and the MTP module's block: three forwards, three backwards
+    assert sum(ops.values()) == sum(found.values()), ops
+    assert found["flash_forward"] == 3 and found["flash_backward"] >= 3, ops
