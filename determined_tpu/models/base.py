@@ -35,9 +35,10 @@ STEP_SCOPES = ("embed", "attn", "mlp", "head_loss", "optimizer")
 #: Scopes INSIDE those, opened by the layers that only some models have
 #: (`models/qwen3_next.py`, `models/moe.py`, `ops/gated_delta.py`): `gdn`
 #: (the whole gated-delta mixer) and `gated_attn` lie inside `attn`,
-#: `gdn_scan` (the chunked rule alone) inside `gdn`; `moe_route` (router,
-#: top-k, sort, the row permutations), `moe_experts` (the grouped matmuls)
-#: and `moe_shared` inside `mlp`; `mla` (`models/glm4_moe_lite.py`: the
+#: `gdn_scan` (the chunked rule alone: its batched half and, on a TPU, the
+#: kernels `gdn_recurrence_fwd` / `gdn_recurrence_bwd`) inside `gdn`;
+#: `moe_route` (router, top-k, sort, the row permutations), `moe_experts`
+#: (the grouped matmuls) and `moe_shared` inside `mlp`; `mla` (`models/glm4_moe_lite.py`: the
 #: latent-attention mixer but its flash kernels) inside `attn`, and `mtp`
 #: (the multi-token-prediction module: its projection inside `embed`, its
 #: block inside `attn` and `mlp`, its head pass and loss inside

@@ -15,6 +15,7 @@ and is a thousand times below what a left-out term moves (the mutation
 cases below: 1e-1 and more) or bf16 accumulation would (4e-3 a sum).
 """
 import dataclasses
+import functools
 import importlib
 import json
 import os
@@ -141,31 +142,112 @@ def _rule_inputs(s, hk=2, r=2, dk=16, dv=8, b=2):
     return q, k, v, g, beta
 
 
-@pytest.mark.parametrize("seq,chunk", [(150, 64), (64, 64), (37, 16), (5, 64)])
-@pytest.mark.parametrize("what", ["o", "dq", "dk", "dv", "dg", "dbeta"])
-def test_chunked_rule_is_the_recurrence(seq, chunk, what):
+WHAT = ["o", "dq", "dk", "dv", "dg", "dbeta"]
+#: (seq, chunk): 150 = 2 x 64 + 22; one chunk exactly; two, so that the
+#: state crosses a boundary with nothing padded; 37 = 2 x 16 + 5; 5 < one
+#: chunk; and the cell's heads (16 key heads of 128, 2 value heads each)
+#: over two chunks of the cell's 128.
+RULE_CASES = [(150, 64), (64, 64), (128, 64), (37, 16), (5, 64), (256, 128)]
+_CELL_HEADS = dict(hk=16, r=2, dk=128, dv=128, b=1)
+
+
+def _rule_case(seq, chunk):
+    args = _rule_inputs(
+        seq, **(_CELL_HEADS if (seq, chunk) == RULE_CASES[-1] else {}))
+    return args, jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+
+
+def _stepwise(q, k, *rest):
+    """The reference's recurrence, a token at a time: a sequence a call,
+    each key head repeated for the value heads it serves."""
+    r = rest[0].shape[2] // q.shape[2]
+    return jax.vmap(ref.delta_rule)(
+        jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2), *rest)
+
+
+def _o_and_gradients(f, args, weight):
+    grads = jax.grad(lambda *a: jnp.sum(f(*a) * weight),
+                     argnums=(0, 1, 2, 3, 4))(*args)
+    return dict(zip(WHAT, (f(*args),) + grads))
+
+
+@functools.lru_cache(maxsize=None)
+def _rule_outputs(seq, chunk, path):
+    """o and the five gradients of one case: by the token-by-token
+    reference (`path` None), or by the chunked rule with its recurrence
+    as the `lax.scan` or as the Pallas kernels (interpreted here)."""
+    args, weight = _rule_case(seq, chunk)
+    if path is None:
+        return _o_and_gradients(_stepwise, args, weight)
+    chunked = functools.partial(
+        gd.gated_delta_chunked, chunk=chunk, interpret=path == "kernel")
+    return _o_and_gradients(chunked, args, weight)
+
+
+@pytest.mark.parametrize("path", ["scan", "kernel"])
+@pytest.mark.parametrize("seq,chunk", RULE_CASES)
+@pytest.mark.parametrize("what", WHAT)
+def test_chunked_rule_is_the_recurrence(seq, chunk, what, path):
     """Forward and every gradient, at lengths that are and are not
-    multiples of the chunk (150 = 2 x 64 + 22; 5 < one chunk). Float32
-    sums in another order: 1e-5 relative, measured; RTOL."""
-    args = _rule_inputs(seq)
-    weight = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
-    chunked = lambda *a: gd.gated_delta_chunked(*a, chunk=chunk)  # noqa: E731
-
-    def stepwise(q, k, *rest):
-        """The reference's recurrence, a token at a time: a sequence a
-        call, each key head repeated for the value heads it serves."""
-        r = rest[0].shape[2] // q.shape[2]
-        return jax.vmap(ref.delta_rule)(
-            jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2), *rest)
-
-    if what == "o":
-        got, want = chunked(*args), stepwise(*args)
-    else:
-        i = ["dq", "dk", "dv", "dg", "dbeta"].index(what)
-        got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * weight), argnums=i)(
-            *args) for f in (chunked, stepwise))
+    multiples of the chunk, with the recurrence over chunks as the scan
+    and as the kernel pair. Float32 sums in another order: 1e-5
+    relative, measured; RTOL."""
+    got = _rule_outputs(seq, chunk, path)[what]
+    want = _rule_outputs(seq, chunk, None)[what]
     assert got.shape == want.shape
     assert _relative(got, want) < RTOL
+
+
+@pytest.mark.parametrize("seq,chunk", RULE_CASES)
+def test_rule_kernels_are_the_scan(seq, chunk):
+    """The kernels run `_recurrence_step` on the blocks the scan runs it
+    on, and their backward is jax's of that function: here, where both
+    compute in float32, nothing separates the two but the order of the
+    sums inside a product."""
+    for what in WHAT:
+        got = _rule_outputs(seq, chunk, "kernel")[what]
+        want = _rule_outputs(seq, chunk, "scan")[what]
+        assert _relative(got, want) < 1e-5, what
+
+
+def test_rule_kernels_initialise_their_scratch(monkeypatch):
+    """The state and its cotangent live in VMEM scratch that the chip
+    hands out as the last kernel left it: both kernels zero theirs at a
+    head block's first step. Two heads a program makes four head blocks
+    share one scratch buffer here, two different inputs go through one
+    compiled pair, and the interpreter fills what was never written with
+    NaN: the second call's results are a fresh scan's."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(gd, "_HEADS_A_PROGRAM", 2)
+    poison = pltpu.InterpretParams(uninitialized_memory="nan")
+    weight = _rule_case(150, 64)[1]
+
+    def both(interpret, *args):
+        return _o_and_gradients(functools.partial(
+            gd.gated_delta_chunked, chunk=64, interpret=interpret),
+            args, weight)
+
+    compiled = jax.jit(functools.partial(both, poison))
+    compiled(*_rule_inputs(150))
+    q, k, v, g, beta = _rule_inputs(150)
+    second = (k * 16 ** -0.5, q * 16 ** 0.5, -v, g[::-1], beta[::-1])
+    got, want = compiled(*second), both(False, *second)
+    for what in WHAT:
+        assert np.isfinite(got[what]).all(), what
+        assert _relative(got[what], want[what]) < 1e-5, what
+
+
+def test_rule_takes_the_kernels_where_the_backend_is_a_tpu(monkeypatch):
+    """One path by what the code observes: the kernels on a TPU at widths
+    the lanes hold whole, the scan elsewhere (here) and at other widths."""
+    fa = importlib.import_module("determined_tpu.ops.flash_attention")
+    cell, small = _rule_inputs(128, **_CELL_HEADS), _rule_inputs(128)
+    has_kernel = lambda args: "pallas_call" in str(  # noqa: E731
+        jax.make_jaxpr(lambda *a: gd.gated_delta_chunked(*a))(*args))
+    assert not has_kernel(cell)
+    monkeypatch.setattr(fa, "_use_pallas", lambda: True)
+    assert has_kernel(cell) and not has_kernel(small)
 
 
 @pytest.mark.parametrize("c", [16, 64, 128, 96, 7])

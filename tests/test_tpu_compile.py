@@ -157,7 +157,31 @@ def _paged_decode(n_heads, head_dim, block_h=None):
     ]
 
 
+def _gdn_recurrence(grad):
+    """The chunked delta rule's recurrence over chunks at
+    `qwen3next-train-8k-ep16share`'s shape: 32 value heads on 16 key
+    heads of 128, 64 chunks of 128 tokens. The forward as the step's
+    forward runs it, or the forward that stashes the state entering each
+    chunk and the backward, whose body is `jax.vjp` of the chunk step."""
+    from determined_tpu.ops import gated_delta as gd
+
+    def forward(*ops):
+        return gd._recurrence_kernels(*ops, False)
+
+    def gradients(*ops):
+        return jax.grad(lambda *a: jnp.sum(forward(*a).astype(jnp.float32)),
+                        argnums=tuple(range(6)))(*ops)
+
+    hv, hk, n, c, d = 32, 16, 64, 128, 128
+    return gradients if grad else forward, [
+        ((hv, n, c, d), BF16), ((hv, n, c, d), BF16), ((hv, n, c, c), BF16),
+        ((hk, n, c, d), BF16), ((hk, n, c, d), BF16),
+        ((hv, n, 1, c), jnp.float32)]
+
+
 CASES = {
+    "gdn_recurrence_8k_forward": lambda: _gdn_recurrence(False),
+    "gdn_recurrence_8k_stash_and_backward": lambda: _gdn_recurrence(True),
     "flash_train_1k_mono": lambda: _flash_train(1024),
     "flash_train_1k_mono_d128": lambda: _flash_train(1024, 16, 128),
     "flash_train_16k_fused_blocked": lambda: _flash_train(16384),
@@ -364,7 +388,7 @@ def _qwen_step(monkeypatch, chips):
     """`qwen3next-train-8k-ep16share`'s train step (`Trainer`'s own, built
     as the cell's driver builds it, by `benchmark/tools/size_train_lm.py`)
     compiled for a described v5e at the published widths, one period deep
-    as the cell is, on one row of 1024 tokens with 4 experts held and 2048
+    as the cell is, on one row of 4096 tokens with 4 experts held and 2048
     rows of vocabulary (the compile's seconds follow those; the program's
     structure does not): its text, the bytes of its temporaries and the
     cell, compiled once a module."""
@@ -376,7 +400,7 @@ def _qwen_step(monkeypatch, chips):
     if not _QWEN_STEP:
         cell = Cell("qwen3next-train-8k-ep16share")
         cell.config = dict(cell.config, num_experts=4, vocab_size=2048)
-        cell.traffic = dict(cell.traffic, seq_len=1024)
+        cell.traffic = dict(cell.traffic, seq_len=4096)
         monkeypatch.setattr(size_cells, "sizes", lambda low: low.compile())
         compiled = size_train_lm.train(
             cell, types.SimpleNamespace(devices=chips), global_batch=1)
@@ -406,6 +430,45 @@ def test_qwen3_next_cells_step_compiles_with_no_conditional(
     assert found == {"flash_forward", "flash_backward"}, ops
 
 
+def test_qwen3_next_cells_step_runs_the_rule_as_two_named_kernels(
+        monkeypatch, chips):
+    """The chunked delta rule's recurrence over chunks is a Pallas kernel
+    pair a layer (`ops/gated_delta.py`), named: an unnamed `pallas_call`
+    under a `custom_vjp` gets the names `benchmark/kernels/flash_*.json`
+    find the flash kernels by, and would be counted as flash attention.
+    So in the cell's step the Mosaic operations that match no flash
+    pattern (the grouped matmul's apart) are `gdn_recurrence_fwd`, three
+    layers' forward and the same repeated under `jax.checkpoint`, and
+    `gdn_recurrence_bwd`; the one attention layer's forward and backward
+    are found once each; both kernels carry `gdn_scan` in their name
+    stack, for the scope readers; and no `while` is left under it (the
+    `lax.scan` was nine of them, 64 trips each in the cell)."""
+    from benchmark import kernel_events, lm_scope_reduce, trace_reduce
+
+    text = _qwen_step(monkeypatch, chips).text
+    flash, others, stacks = collections.Counter(), collections.Counter(), []
+    for line in text.splitlines():
+        if (trace_reduce.MOSAIC not in line or " custom-call(" not in line
+                or "ragged-dot" in line):
+            continue
+        op = trace_reduce.op_name(line.strip())
+        kinds = [k for k in ("flash_forward", "flash_backward")
+                 if re.search(kernel_events.kernel(k)["pattern"], op)]
+        if kinds:
+            flash.update(kinds)
+        else:
+            others[trace_reduce.op_class(op)] += 1
+            stacks.append(re.search(r'op_name="([^"]*)"', line).group(1))
+    assert dict(flash) == {"flash_forward": 1, "flash_backward": 1}
+    assert dict(others) == {"mosaic:gdn_recurrence_fwd": 6,
+                            "mosaic:gdn_recurrence_bwd": 3}
+    assert all("gdn_scan" in lm_scope_reduce.scopes_of(s) for s in stacks)
+    whiles = [re.search(r'op_name="([^"]*)"', line).group(1)
+              for line in text.splitlines() if " while(" in line]
+    assert whiles, "no `while` at all (the expert layer's slabs): wrong pattern?"
+    assert not [s for s in whiles if "gdn_scan" in s.split("/")]
+
+
 def test_qwen3_next_cells_step_moves_no_row_buffer_whole(monkeypatch, chips):
     """The expert layer's row buffer is k x T rows of the hidden width
     for the worst case, and no instruction of the step gathers or widens
@@ -426,9 +489,13 @@ def test_qwen3_next_cells_step_moves_no_row_buffer_whole(monkeypatch, chips):
     # fusion's could, so the weights' gradients, which read two such
     # buffers a layer, must not be put off to the end of the step:
     # `grouped_matmul` ties them to the rows' gradients. By the
-    # compiler's `memory_analysis()` here: 0.79 GiB of temporaries so,
-    # 1.28 with them put off (0.90 with PR 32's whole-buffer gathers).
-    assert step.temp < 1.0 * 2 ** 30, step.temp / 2 ** 30
+    # compiler's `memory_analysis()` here: 1.96 GiB of temporaries so,
+    # 2.87 with them put off (at 8192 tokens and the cell's 32 experts
+    # 5.19 and 10.35). At 1024 tokens, where this step was compiled
+    # through PR 36, memory is not the scheduler's concern: with the
+    # delta rule's kernels in place of nine `while`s it holds twelve row
+    # buffers there where it held eight, tied or not (1.08 and 1.09 GiB).
+    assert step.temp < 2.4 * 2 ** 30, step.temp / 2 ** 30
 
 
 # -- the latent-attention cell (ISSUE 35) --------------------------------------
