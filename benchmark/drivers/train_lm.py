@@ -21,17 +21,23 @@ have `"kind": "train"`, so the readers of the other training cells read
 them unchanged. (A `benchmark` PR should fold the two drivers into one:
 ROADMAP D13.)
 
-Two things differ, both by the traffic file. `weights_seed` seeds the
+Three things differ, all by the traffic file. `weights_seed` seeds the
 trainer (its initial parameters); `--seed` draws the token batches and
 nothing else, so seeds differ in order and not in work: with random
 weights, WHICH experts a router favours is the weights', and a cell that
-holds 32 of 512 would otherwise do a different amount of work a seed
-(PR 28). And the expert layer's counters, which the model reports with
-its loss, are kept from every report: `moe_held_rows_per_token` says how
-much work the held experts did.
+holds 32 of 512 would otherwise do a different amount of work a seed.
+`lr_warmup_steps` ramps the rate from 0 to `lr` (0 or absent: none); it
+is what keeps the seeds' work equal after the first step: at a constant
+`lr` from step 1 the router retrains within ten steps and where it
+settles follows the data order, so the rows the held experts get, and
+the step's time with them, follow `--seed`. And the expert layer's
+counters, which the model reports with its loss, are kept from every
+report: `moe_held_rows_per_token` says how much work the held experts
+did.
 
 Traffic file: `mesh`, `global_batch`, `seq_len`, `report_period`,
-`warmup_reports`, `lr`, `weights_seed`, `trace_seconds`.
+`warmup_reports`, `lr`, `lr_warmup_steps`, `weights_seed`,
+`trace_seconds`.
 """
 from __future__ import annotations
 
@@ -67,6 +73,7 @@ def trial_hparams(config: Dict[str, Any], traffic: Dict[str, Any],
         "seq_len": int(traffic["seq_len"]),
         "vocab_size": int(config["vocab_size"]),
         "batch_size": global_batch, "lr": float(traffic.get("lr", 1e-3)),
+        "lr_warmup_steps": int(traffic.get("lr_warmup_steps", 0)),
     }
 
 

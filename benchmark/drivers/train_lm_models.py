@@ -1,20 +1,19 @@
 """Driver of `"kind": "train_lm_models"` mixes: `drivers/train_lm.py`'s
 run, stamp for stamp, for any architecture listed in `lm_models.json`.
 
-`train_lm.py` cannot be edited by the PR that added this file, and three
+`train_lm.py` cannot be edited by the PR that added this file, and two
 of its module-level names stop it short of a second architecture:
-`MODELS` (Qwen3-Next alone), `COUNTERS` (no `mtp_loss`) and
-`trial_hparams` (no learning-rate warm-up). `as_train_lm()` puts this
-module's in their place for the length of a call and takes them out
-again; `run` is then `train_lm.run` itself: the same context, searcher,
-windows, records and `correct` (the first loss AND every leaf's gradient
-against the float32 reference at the timed sizes, outside the window).
+`MODELS` (Qwen3-Next alone) and `COUNTERS` (no `mtp_loss`).
+`as_train_lm()` puts this module's in their place for the length of a
+call and takes them out again; `run` is then `train_lm.run` itself: the
+same context, searcher, windows, records and `correct` (the first loss
+AND every leaf's gradient against the float32 reference at the timed
+sizes, outside the window).
 The tools that call `train_lm`'s functions (`tools/size_train_lm.py`,
 `tools/lm_control.py`) run under the same switch:
 `python -m benchmark.tools.lm_models <tool> ...`.
 
-Traffic file: `train_lm.py`'s keys, and `lr_warmup_steps` (steps of the
-trial's linear ramp from 0 to `lr`; 0 or absent: none).
+Traffic file: `train_lm.py`'s keys.
 """
 from __future__ import annotations
 
@@ -41,21 +40,11 @@ def models() -> Dict[str, Any]:
 COUNTERS = (*train_lm.COUNTERS, "mtp_loss")
 
 
-_trial_hparams = train_lm.trial_hparams     # (its own, before any switch)
-
-
-def trial_hparams(config: Dict[str, Any], traffic: Dict[str, Any],
-                  global_batch: int) -> Dict[str, Any]:
-    return {**_trial_hparams(config, traffic, global_batch),
-            "lr_warmup_steps": int(traffic.get("lr_warmup_steps", 0))}
-
-
 @contextlib.contextmanager
 def as_train_lm():
-    """`train_lm`'s functions read this module's table, counters and
-    hyperparameters while the block runs."""
-    with mock.patch.multiple(train_lm, MODELS=models(), COUNTERS=COUNTERS,
-                             trial_hparams=trial_hparams):
+    """`train_lm`'s functions read this module's table and counters
+    while the block runs."""
+    with mock.patch.multiple(train_lm, MODELS=models(), COUNTERS=COUNTERS):
         yield
 
 

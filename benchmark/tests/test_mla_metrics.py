@@ -213,14 +213,42 @@ def test_every_listed_model_type_has_its_reference_and_counters():
         for name in ("loss_and_gradient", "gradient_gaps", "check"):
             assert callable(getattr(ref, name)), (module, name)
     assert train_lm_models.COUNTERS[:2] == train_lm.COUNTERS
-    before = (train_lm.MODELS, train_lm.COUNTERS, train_lm.trial_hparams)
+    before = (train_lm.MODELS, train_lm.COUNTERS)
     with train_lm_models.as_train_lm():
         name, kw, _ = train_lm.model_of(CONFIG)
         hparams = train_lm.trial_hparams(CONFIG, TRAFFIC, 1)
     assert name == "glm4-moe-lite" and "source" not in kw
     assert hparams["lr_warmup_steps"] == 2000 and hparams["lr"] == 0.001
-    assert (train_lm.MODELS, train_lm.COUNTERS,
-            train_lm.trial_hparams) == before
-    assert "lr_warmup_steps" not in train_lm.trial_hparams(
+    assert (train_lm.MODELS, train_lm.COUNTERS) == before
+    # the Qwen cell's traffic gives its trial the same warm-up, unswitched
+    qwen = train_lm.trial_hparams(
         load_json("benchmark", "configs", "qwen3-next-80b-a3b.json"),
         load_json("benchmark", "traffic", "train-8k-ep16share.json"), 1)
+    assert qwen["lr_warmup_steps"] == 2000 and qwen["lr"] == 0.001
+
+
+@pytest.mark.parametrize("config,traffic,warmup", [
+    ("glm-4.7-flash", "train-8k-ep8share", 2000),
+    ("qwen3-next-80b-a3b", "train-8k-ep16share", 2000),
+    ("qwen3-next-80b-a3b", None, 0)])
+def test_trial_hparams_read_the_warmup_from_the_traffic_file(config, traffic,
+                                                            warmup):
+    """`train_lm.trial_hparams` passes the traffic file's
+    `lr_warmup_steps` itself, 0 where the file has none (the optimizer
+    without a schedule), and GLM's trial gets the dictionary that
+    `train_lm_models` built for it before: the rate, the ramp and the
+    sizes."""
+    from benchmark.drivers import train_lm, train_lm_models
+
+    c = load_json("benchmark", "configs", f"{config}.json")
+    mix = load_json("benchmark", "traffic",
+                    f"{traffic or 'train-8k-ep16share'}.json")
+    if traffic is None:
+        del mix["lr_warmup_steps"]
+    with train_lm_models.as_train_lm():
+        name, kw, _ = train_lm.model_of(c)
+        hparams = train_lm.trial_hparams(c, mix, 1)
+    assert hparams == {
+        "model": name, "model_kw": kw, "seq_len": 8192,
+        "vocab_size": c["vocab_size"], "batch_size": 1, "lr": 0.001,
+        "lr_warmup_steps": warmup}
