@@ -37,12 +37,31 @@ capture shows whether the device waited inside it; free when no capture
 runs, and not switched off by the kill switch) and adds the elapsed time
 to the window. ``report`` has two children, ``report.sync`` (the
 boundary's `device_get`s) and ``report.publish`` (the metric reports).
+
+Two spans are no phase (no sampler tag, no `*_frac` key):
+
+- ``dtpu.trainer.boundary``, the whole report boundary, from the start
+  of its `flush_report` until the next step's dispatch returns
+  (`begin_boundary` / `end_boundary`). It encloses the phases that fall
+  in it and its children: ``wait`` (`boundary_wait`, inside
+  ``report.sync``), ``control``, ``step_flops``, ``op_end``
+  (`Timeline.span`). The host reaches a boundary while the device still
+  runs the window's steps (dispatch is asynchronous), so the boundary
+  starts with ``wait``, the first sync, which returns once the device
+  has run the window's last step. ``boundary_s``, in the profiling
+  report of the window the boundary opened, is the rest on `pc`: from
+  the end of ``wait`` to the next dispatch, the host work the device
+  waits through.
+- ``dtpu.trainer.gc``, each pause of Python's collector while
+  `hook_gc` is in force: ``gc_s`` and ``gc_collections`` a window.
 """
 from __future__ import annotations
 
+import contextlib
+import gc
 import os
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Iterator, Optional
 
 from jax.profiler import TraceAnnotation
 
@@ -53,6 +72,9 @@ PHASES = ("data_wait", "h2d_put", "report", "checkpoint")
 ALL_PHASES = PHASES + ("step",)
 #: Prefix of the phases' host spans in a profiler capture.
 SPAN_PREFIX = "dtpu.trainer."
+#: Host spans that are no phase (see the module docstring).
+BOUNDARY = "boundary"
+GC = "gc"
 
 
 class _Phase:
@@ -91,7 +113,20 @@ class Timeline:
         # -- window accumulators (reset every report boundary) --------------
         self.window: Dict[str, float] = {p: 0.0 for p in PHASES}
         self._window_start = self.pc()
-        self._window_steps = 0
+        #: the open report boundary's span (`begin_boundary`), else None
+        self.boundary: Optional[TraceAnnotation] = None
+        self._boundary_t0 = 0.0
+        #: the host part of the boundary that opened this window, once closed
+        self._boundary_s: Optional[float] = None
+        self._gc_hooked = False
+        self._gc_span: Optional[TraceAnnotation] = None
+        self._gc_t0 = 0.0
+        # Lifetime (seconds, collections), replaced whole by the
+        # collecting thread alone (one collection runs at a time); a
+        # window reports them against the totals it started at, so no
+        # collection is lost or counted twice across windows.
+        self._gc_totals = (0.0, 0)
+        self._gc_at_window = self._gc_totals
         # -- cumulative phase totals (lifetime, this process + restores) ----
         self.phase_totals: Dict[str, float] = {p: 0.0 for p in ALL_PHASES}
         # -- goodput ledger --------------------------------------------------
@@ -120,14 +155,73 @@ class Timeline:
         The previous tag comes back at exit, so phases nest."""
         return _Phase(self, name, timed)
 
+    @staticmethod
+    def span(name: str) -> TraceAnnotation:
+        """A host span `dtpu.trainer.<name>` that is no phase: a part of
+        the boundary (``boundary.control``, ...)."""
+        return TraceAnnotation(SPAN_PREFIX + name)
+
+    def begin_boundary(self) -> None:
+        """A report boundary starts (its `flush_report`); `end_boundary`
+        closes it once the next step's dispatch has returned, or on the
+        way out of `fit`. Its clock starts at the end of `boundary_wait`."""
+        self.boundary = TraceAnnotation(SPAN_PREFIX + BOUNDARY)
+        self.boundary.__enter__()
+
+    @contextlib.contextmanager
+    def boundary_wait(self) -> Iterator[None]:
+        """Around the open boundary's first sync, which returns once the
+        device has run the window's last step: the span
+        ``boundary.wait``, and ``boundary_s`` counts from its end.
+        Nothing outside a boundary."""
+        if self.boundary is None:
+            yield
+            return
+        with self.span(BOUNDARY + ".wait"):
+            yield
+        if self.enabled:
+            self._boundary_t0 = self.pc()
+
+    def end_boundary(self) -> None:
+        span, self.boundary = self.boundary, None
+        if span is None:
+            return
+        if self.enabled:
+            self._boundary_s = self.pc() - self._boundary_t0
+        span.__exit__(None, None, None)
+
+    def hook_gc(self) -> None:
+        """Time every collection until `unhook_gc`: ``gc_s`` and
+        ``gc_collections`` in each window, a ``dtpu.trainer.gc`` span on
+        the thread that collects. Nothing under the kill switch."""
+        if self.enabled and not self._gc_hooked:
+            gc.callbacks.append(self._on_gc)
+            self._gc_hooked = True
+
+    def unhook_gc(self) -> None:
+        if self._gc_hooked:
+            gc.callbacks.remove(self._on_gc)
+            self._gc_hooked = False
+
+    def _on_gc(self, phase: str, _info: Dict[str, Any]) -> None:
+        if phase == "start":
+            self._gc_span = TraceAnnotation(SPAN_PREFIX + GC)
+            self._gc_span.__enter__()
+            self._gc_t0 = self.pc()
+            return
+        span, self._gc_span = self._gc_span, None
+        if span is None:
+            return
+        gc_s, gc_n = self._gc_totals
+        self._gc_totals = (gc_s + self.pc() - self._gc_t0, gc_n + 1)
+        span.__exit__(None, None, None)
+
     def reset_window(self) -> None:
         for p in PHASES:
             self.window[p] = 0.0
-        self._window_steps = 0
         self._window_start = self.pc()
-
-    def step_done(self) -> None:
-        self._window_steps += 1
+        self._boundary_s = None
+        self._gc_at_window = self._gc_totals
 
     def close_window(self) -> Dict[str, float]:
         """Settle the window at a report boundary (the caller has already
@@ -146,10 +240,15 @@ class Timeline:
                 out[f"{p}_frac"] = self.window[p] / denom
             self.phase_totals["step"] += step_s
             out["step_frac"] = step_s / denom
-            if self._window_steps:
-                out["step_time_s"] = wall / self._window_steps
+        if self._boundary_s is not None:
+            out["boundary_s"] = self._boundary_s
+        gc_now = self._gc_totals
+        if self._gc_hooked:
+            out["gc_s"] = gc_now[0] - self._gc_at_window[0]
+            out["gc_collections"] = float(gc_now[1] - self._gc_at_window[1])
         self.uncommitted_s += wall
         self.reset_window()
+        self._gc_at_window = gc_now     # the next window starts where this one ended
         return out
 
     # -- ledger -------------------------------------------------------------

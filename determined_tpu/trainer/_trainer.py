@@ -999,7 +999,7 @@ class Trainer:
 
         def flush_report() -> None:
             # One `report` phase (trainer/_timeline.py) around the whole
-            # boundary, untimed: only the publishing calls add to the
+            # flush, untimed: only the publishing calls add to the
             # window, the syncs before them are device time and stay in
             # the `step` residual. Nothing pending (a flush right after a
             # boundary's own): nothing to do, and no empty span.
@@ -1015,7 +1015,8 @@ class Trainer:
             # such a window must not vanish unchecked. The verdict is
             # latched and consumed at the next boundary's rollback gate.
             with timeline.phase("report.sync", timed=False):
-                reason = self._sentinel_check(pending)
+                with timeline.boundary_wait():
+                    reason = self._sentinel_check(pending)
                 if reason and self._sentinel_reason is None:
                     self._sentinel_reason = reason
                 host = (
@@ -1119,16 +1120,18 @@ class Trainer:
         # otherwise run its checkpoint-channel collectives against a core
         # context the caller is already tearing down, and its failure (or a
         # half-registered checkpoint) would go unreported.
+        timeline.hook_gc()
         try:
             fit_error = None
-            for op in searcher.operations():
+            operations = iter(searcher.operations())
+            op = next(operations, None)
+            while op is not None:
                 target = to_batches(op.length, bpe)
                 while step < target:
                     with timeline.phase("data_wait"):
                         raw = next(train_iter)
                     with timeline.phase("h2d_put"):
                         batch = self._put_batch(raw)
-                    timeline.step_done()
                     self._data_consumed += 1
                     # poison: 1.0 outside fault drills (one None check);
                     # np scalar, not python float, so jit sees a stable
@@ -1137,6 +1140,9 @@ class Trainer:
                     self._state, metrics, self._skips = self._step_fn(
                         self.state, batch, poison, self._skips
                     )
+                    if timeline.boundary is not None:
+                        # the dispatch was the boundary's last part
+                        timeline.end_boundary()
                     pending.append(metrics)
                     step += 1
                     if (
@@ -1161,52 +1167,62 @@ class Trainer:
                         # window (same verdict on every rank — the inputs
                         # are replicated outputs of the SPMD step, so no
                         # extra collective); the latched verdict gates
-                        # the rollback below.
+                        # the rollback below. The boundary's span runs
+                        # from here to the next step's dispatch.
+                        timeline.begin_boundary()
                         flush_report()
                         rollback_reason = self._sentinel_reason
                         self._sentinel_reason = None
-                        # Progress beat from EVERY rank: the master's
-                        # stall watchdog kills the gang when this counter
-                        # stops advancing (hung collective → bounded-time
-                        # recovery instead of forever-stuck). The response
-                        # doubles as the elastic resize channel: a pending
-                        # directive rides back when the master resized the
-                        # gang past this rank's generation.
-                        beat_resize = self.core.train.heartbeat_step(step)
-                        if self.core.distributed.is_chief:
-                            op.report_progress(float(step))
-                            if self._step_flops is None:
+                        with timeline.span("boundary.control"):
+                            # Progress beat from EVERY rank: the master's
+                            # stall watchdog kills the gang when this
+                            # counter stops advancing (hung collective →
+                            # bounded-time recovery instead of
+                            # forever-stuck). The response doubles as the
+                            # elastic resize channel: a pending directive
+                            # rides back when the master resized the gang
+                            # past this rank's generation.
+                            beat_resize = self.core.train.heartbeat_step(step)
+                            if self.core.distributed.is_chief:
+                                op.report_progress(float(step))
+                                # Operator-triggered XLA capture rides the
+                                # beat response (chief-only: one trace per
+                                # trial).
+                                cap = self.core.train.take_profile_capture()
+                                if cap is not None:
+                                    self._begin_capture(cap, step)
+                            # Preemption is a collective (ZMQ broadcast) —
+                            # checking every batch would put a TCP
+                            # roundtrip in the hot loop, so it shares the
+                            # report boundary (the reference's analog knob
+                            # is scheduling_unit granularity). Elastic
+                            # resize rides the SAME collective (the chief
+                            # folds the boundary beat's directive hint into
+                            # the broadcast), so every rank reaches the
+                            # same resize verdict at the same boundary —
+                            # and it MUST be the boundary's FIRST
+                            # gather-shaped action: once a peer is dead,
+                            # any other collective (joining an in-flight
+                            # sharded save, a rollback restore's agreement
+                            # round, the divergence audit) would hang on it
+                            # forever. The resize exit is also allowed to
+                            # supersede a latched sentinel rollback: both
+                            # restore the same last verified checkpoint,
+                            # the resize just does it on the new mesh.
+                            preempt_now = self.core.preempt.should_preempt(
+                                resize_hint=beat_resize
+                            )
+                            directive = self.core.preempt.take_resize()
+                        if directive is not None:
+                            self._exit_for_resize(directive, step)
+                        if (
+                            self._step_flops is None
+                            and self.core.distributed.is_chief
+                        ):
+                            with timeline.span("boundary.step_flops"):
                                 self._step_flops = self._compute_step_flops(
                                     batch, poison
                                 )
-                            # Operator-triggered XLA capture rides the beat
-                            # response (chief-only: one trace per trial).
-                            cap = self.core.train.take_profile_capture()
-                            if cap is not None:
-                                self._begin_capture(cap, step)
-                        # Preemption is a collective (ZMQ broadcast) —
-                        # checking every batch would put a TCP roundtrip in
-                        # the hot loop, so it shares the report boundary
-                        # (the reference's analog knob is scheduling_unit
-                        # granularity). Elastic resize rides the SAME
-                        # collective (the chief folds the boundary beat's
-                        # directive hint into the broadcast), so every rank
-                        # reaches the same resize verdict at the same
-                        # boundary — and it MUST be the boundary's FIRST
-                        # gather-shaped action: once a peer is dead, any
-                        # other collective (joining an in-flight sharded
-                        # save, a rollback restore's agreement round, the
-                        # divergence audit) would hang on it forever. The
-                        # resize exit is also allowed to supersede a latched
-                        # sentinel rollback: both restore the same last
-                        # verified checkpoint, the resize just does it on
-                        # the new mesh.
-                        preempt_now = self.core.preempt.should_preempt(
-                            resize_hint=beat_resize
-                        )
-                        directive = self.core.preempt.take_resize()
-                        if directive is not None:
-                            self._exit_for_resize(directive, step)
                         if preempt_now:
                             flush_report()
                             with timeline.phase("checkpoint", timed=False):
@@ -1252,23 +1268,31 @@ class Trainer:
                 if preempted:
                     break
 
-                flush_report()
-                last_val = self._validate()
-                if self.core.distributed.is_chief:
-                    if last_val:
-                        self.core.train.report_validation_metrics(
-                            self.steps_completed, last_val
-                        )
-                        self._tb_scalars(self.steps_completed, last_val, prefix="val_")
-                    # Throughput is a first-class searcher metric (mesh/batch
-                    # autotuning sweeps maximize it); validation metrics win on
-                    # name collision.
-                    completion = {
-                        "batches_per_second": getattr(self, "_last_throughput", 0.0),
-                        **last_val,
-                    }
-                    metric = completion.get(self.searcher_metric, 0.0)
-                    op.report_completed(float(metric))
+                # The op's end and the fetch of the next op lie inside the
+                # boundary the op's last step opened.
+                with timeline.span("boundary.op_end"):
+                    flush_report()
+                    last_val = self._validate()
+                    if self.core.distributed.is_chief:
+                        if last_val:
+                            self.core.train.report_validation_metrics(
+                                self.steps_completed, last_val
+                            )
+                            self._tb_scalars(
+                                self.steps_completed, last_val, prefix="val_"
+                            )
+                        # Throughput is a first-class searcher metric
+                        # (mesh/batch autotuning sweeps maximize it);
+                        # validation metrics win on name collision.
+                        completion = {
+                            "batches_per_second": getattr(
+                                self, "_last_throughput", 0.0
+                            ),
+                            **last_val,
+                        }
+                        metric = completion.get(self.searcher_metric, 0.0)
+                        op.report_completed(float(metric))
+                    op = next(operations, None)
 
             if (
                 (ckpt_period or preempted or self.core.info is not None)
@@ -1290,6 +1314,9 @@ class Trainer:
                 # checkpoint one rather than masking it.
                 logger.exception("background checkpoint failed during teardown")
             finally:
+                # the last boundary has no next dispatch to end it
+                timeline.end_boundary()
+                timeline.unhook_gc()
                 profiling_mod.set_phase(None)
                 if self._capture_dir is not None:
                     # Abandoned mid-capture exit: stop + report so the

@@ -8,6 +8,7 @@ through `Timeline.phase`, and the benchmark's own copy of those names
 which it keeps as data because it imports nothing of the program."""
 import dataclasses
 import functools
+import gc
 import glob
 import importlib
 import json
@@ -265,7 +266,6 @@ def test_phase_accumulates_as_the_old_arithmetic_did():
             clock.now += 0.25
         with tl.phase("h2d_put"):
             clock.now += 0.125
-        tl.step_done()
         clock.now += 1.0
     with tl.phase("report", timed=False):       # flush_report
         with tl.phase("report.sync", timed=False):
@@ -278,7 +278,6 @@ def test_phase_accumulates_as_the_old_arithmetic_did():
     assert out["window_s"] == pytest.approx(6.625)
     assert out["report_frac"] == pytest.approx(0.5 / 6.625)
     assert out["step_frac"] == pytest.approx(5.0 / 6.625)
-    assert out["step_time_s"] == pytest.approx(6.625 / 3)
     with tl.phase("checkpoint"):
         clock.now += 4.0
     assert tl.window["checkpoint"] == 4.0
@@ -320,6 +319,194 @@ def test_phase_accumulates_nothing_when_disabled(monkeypatch):
     assert tl.window == {p: 0.0 for p in _timeline.PHASES}
 
 
+def _windows(tl, clock, boundaries):
+    """Three report windows as `Trainer.fit` runs them: a step's two
+    phases and its dispatch, the boundary's end after the dispatch, then
+    `flush_report`; with `boundaries` the report boundary's span opens
+    before it. Returns the three windows' reports."""
+    out = []
+    for _window in range(3):
+        with tl.phase("data_wait"):
+            clock.now += 0.25
+        with tl.phase("h2d_put"):
+            clock.now += 0.125
+        clock.now += 0.5                        # the dispatch
+        if tl.boundary is not None:
+            tl.end_boundary()
+        clock.now += 1.0
+        if boundaries:
+            tl.begin_boundary()
+        with tl.phase("report", timed=False):
+            with tl.phase("report.sync", timed=False):
+                with tl.boundary_wait():
+                    clock.now += 2.0            # the device's last steps
+                clock.now += 0.375              # the metrics' fetch
+            with tl.phase("report.publish"):
+                clock.now += 0.5
+            out.append(tl.close_window())
+        clock.now += 0.0625                     # the control calls
+    return out
+
+
+def test_boundary_s_is_the_interval_the_window_opened_with():
+    tl, clock = _timeline_with_clock()
+    plain, plain_clock = _timeline_with_clock()
+    got = _windows(tl, clock, boundaries=True)
+    want = _windows(plain, plain_clock, boundaries=False)
+    # the first window was opened by no boundary; each later one by a
+    # boundary whose clock starts when its wait on the device ends: the
+    # fetch 0.375, publish 0.5, the control calls, the next step's phases
+    # and its dispatch
+    assert "boundary_s" not in got[0]
+    for report in got[1:]:
+        assert report.pop("boundary_s") == pytest.approx(
+            0.375 + 0.5 + 0.0625 + 0.25 + 0.125 + 0.5)
+    assert got == want                          # the fractions as before
+    assert tl.boundary is not None              # open until a dispatch
+    tl.end_boundary()
+    assert tl.boundary is None
+    tl.end_boundary()                           # closing twice is a no-op
+
+
+class _CountingClock(_Clock):
+    def __init__(self):
+        super().__init__()
+        self.calls = 0
+
+    def __call__(self):
+        self.calls += 1
+        return self.now
+
+
+def test_boundary_and_collector_record_nothing_when_disabled(monkeypatch):
+    monkeypatch.setenv("DTPU_TIMELINE", "0")
+    tl = Timeline()
+    tl.pc = clock = _CountingClock()
+    callbacks = list(gc.callbacks)
+    tl.hook_gc()
+    assert gc.callbacks == callbacks
+    tl.begin_boundary()
+    assert tl.boundary is not None              # the span stays
+    with tl.boundary_wait():
+        clock.now += 1.0
+    tl.end_boundary()
+    gc.collect()
+    assert clock.calls == 0
+    out = tl.close_window()
+    assert not {"boundary_s", "gc_s", "gc_collections"} & set(out)
+
+
+def test_gc_hook_times_collections_until_unhooked():
+    tl, clock = _timeline_with_clock()
+    callbacks = list(gc.callbacks)
+    tl.hook_gc()
+    tl.hook_gc()                                # once, however often asked
+    try:
+        assert len(gc.callbacks) == len(callbacks) + 1
+        gc.collect()                            # the fake clock stands still
+        for _pause in range(2):                 # two pauses of 3 ms
+            tl._on_gc("start", {"generation": 0})
+            clock.now += 0.003
+            tl._on_gc("stop", {"generation": 0})
+        out = tl.close_window()
+        again = tl.close_window()               # a new window starts at 0
+    finally:
+        tl.unhook_gc()
+    assert gc.callbacks == callbacks
+    assert out["gc_collections"] >= 3.0
+    assert out["gc_s"] == pytest.approx(0.006)
+    assert again["gc_s"] == 0.0
+    assert "gc_s" not in tl.close_window()      # unhooked: not measured
+
+
+def test_gc_windows_lose_no_collection_under_threads():
+    """Collections on many threads while the windows close on this one:
+    the windows' counts add up to every collection the hook saw."""
+    import sys
+
+    tl = Timeline(enabled=True)
+    workers = (os.cpu_count() or 1) + 2
+    done = threading.Event()
+
+    def collect():
+        for _ in range(20):
+            junk = [[i] for i in range(100)]
+            junk.append(junk)
+            del junk
+            gc.collect(0)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    tl.hook_gc()
+    try:
+        threads = [threading.Thread(target=collect) for _ in range(workers)]
+        for t in threads:
+            t.start()
+        counted = 0.0
+        while not done.is_set():
+            counted += tl.close_window()["gc_collections"]
+            if not any(t.is_alive() for t in threads):
+                done.set()
+        for t in threads:
+            t.join(timeout=30)
+            assert not t.is_alive()
+        counted += tl.close_window()["gc_collections"]
+    finally:
+        tl.unhook_gc()
+        sys.setswitchinterval(interval)
+    # (a collection asked for while another runs is skipped, not counted)
+    assert counted == tl._gc_totals[1] > 0
+
+
+def test_fit_restores_gc_callbacks_when_it_returns_and_when_it_raises(
+        tmp_path):
+    class _Failing(_GPTTrial):
+        fail_at = None
+
+        def build_training_data(self):
+            for i, batch in enumerate(super().build_training_data()):
+                if i == self.fail_at:
+                    raise RuntimeError("the data source failed")
+                yield batch
+
+    trial = _Failing()
+    trainer = Trainer(trial, _dummy_core(tmp_path))
+    callbacks = list(gc.callbacks)
+    trainer.fit(max_length=Batch(2), report_period=Batch(1))
+    assert gc.callbacks == callbacks and trainer.timeline.boundary is None
+    prof = [m for g, _s, m in trainer.core.train._reported
+            if g == "profiling"]
+    assert [("boundary_s" in m, "gc_s" in m) for m in prof] == [
+        (False, True), (True, True)]
+    trial.fail_at = 1
+    with pytest.raises(RuntimeError, match="data source"):
+        trainer.fit(max_length=Batch(4), report_period=Batch(1))
+    assert gc.callbacks == callbacks and trainer.timeline.boundary is None
+
+
+def test_the_trainer_readers_name_the_programs_span_and_keys():
+    """The benchmark imports nothing of the program: its four readers of
+    the boundary and the collector carry the names as constants."""
+    readers = {m: importlib.import_module("benchmark.layer_metrics." + m)
+               for m in ("boundary_idle_ms", "boundary_host_ms",
+                         "gc_pause_ms", "unspanned_idle_share")}
+    assert readers["boundary_idle_ms"].SPAN == _timeline.BOUNDARY
+    assert scope_reduce.names()["span_prefix"] == _timeline.SPAN_PREFIX
+    tl, clock = _timeline_with_clock()
+    tl.hook_gc()
+    try:
+        tl.begin_boundary()
+        with tl.boundary_wait():
+            clock.now += 1.0
+        tl.end_boundary()
+        out = tl.close_window()
+    finally:
+        tl.unhook_gc()
+    assert readers["boundary_host_ms"].KEY in out
+    assert readers["gc_pause_ms"].KEY in out
+    assert {r.LAYER for r in readers.values()} == {"trainer"}
+
+
 # -- the spans in a capture -------------------------------------------------
 def test_fit_leaves_the_trainers_spans_on_the_host_plane(tmp_path):
     from jax.profiler import ProfileData
@@ -328,36 +515,78 @@ def test_fit_leaves_the_trainers_spans_on_the_host_plane(tmp_path):
 
     trainer = Trainer(_GPTTrial(), _dummy_core(tmp_path / "ckpt"))
     trainer.fit(max_length=Batch(1))            # compile outside the capture
+    reported = trainer.core.train._reported
+    first_report = len(reported)
     trace_dir = str(tmp_path / "trace")
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     options.host_tracer_level = 2
     jax.profiler.start_trace(trace_dir, profiler_options=options)
     try:
-        trainer.fit(max_length=Batch(3), report_period=Batch(1),
+        trainer.fit(max_length=Batch(4), report_period=Batch(1),
                     checkpoint_period=Batch(2))
+        # a collection on another thread, under the hook
+        trainer.timeline.hook_gc()
+        collector = threading.Thread(target=gc.collect)
+        collector.start()
+        collector.join()
+        trainer.timeline.unhook_gc()
     finally:
         jax.profiler.stop_trace()
     data = ProfileData.from_file(trace_reduce.find_xplane(trace_dir))
-    spans = {}
+    spans, dispatches = {}, []
     for plane in data.planes:
         if not plane.name.startswith("/host:"):
             continue
         for line in plane.lines:
             for e in line.events:
+                at = (e.start_ns, e.start_ns + e.duration_ns)
                 if e.name.startswith(_timeline.SPAN_PREFIX):
-                    spans.setdefault(e.name, []).append(
-                        (e.start_ns, e.start_ns + e.duration_ns))
+                    spans.setdefault(e.name, []).append(at)
+                elif e.name.startswith("PjitFunction(train_step"):
+                    dispatches.append(at)
+    spans = {k: sorted(v) for k, v in spans.items()}
     names = {n[len(_timeline.SPAN_PREFIX):] for n in spans}
     assert names >= {"data_wait", "h2d_put", "report", "report.sync",
-                     "report.publish", "checkpoint"}, names
-    assert len(spans["dtpu.trainer.data_wait"]) == 2
-    assert len(spans["dtpu.trainer.h2d_put"]) == 2
-    # the children lie inside a `report` span
+                     "report.publish", "checkpoint", "boundary",
+                     "boundary.wait", "boundary.control", "boundary.op_end",
+                     "gc"}, names
+    assert len(spans["dtpu.trainer.data_wait"]) == 3
+    assert len(spans["dtpu.trainer.h2d_put"]) == 3
+
+    def inside(child, parent):
+        return [any(pa <= a and b <= pb for pa, pb in spans[parent])
+                for a, b in spans[child]]
+
+    # the children lie inside a `report` span, every `report` and every
+    # part of the boundary inside a `boundary` span
     for child in ("report.sync", "report.publish"):
-        for a, b in spans["dtpu.trainer." + child]:
-            assert any(ra <= a and b <= rb
-                       for ra, rb in spans["dtpu.trainer.report"]), child
+        assert all(inside("dtpu.trainer." + child, "dtpu.trainer.report"))
+    for child in ("report", "boundary.wait", "boundary.control",
+                  "boundary.op_end"):
+        assert all(inside("dtpu.trainer." + child, "dtpu.trainer.boundary"))
+    assert all(inside("dtpu.trainer.boundary.wait", "dtpu.trainer.report.sync"))
+    # a boundary at each of the 3 steps; the first two end once the next
+    # step's dispatch has returned, before that step's next batch
+    boundaries = spans["dtpu.trainer.boundary"]
+    assert len(boundaries) == 3
+    for a, b in boundaries[:2]:
+        dispatch = [d for d in dispatches if a <= d[0] <= b]
+        assert dispatch and dispatch[-1][1] <= b
+        after = [w for w, _ in spans["dtpu.trainer.data_wait"] if w > a]
+        assert after[0] < b and (len(after) == 1 or b < after[1])
+    # the same interval on the program's clock: each window's
+    # `boundary_s` is its opening boundary's span from the end of its wait
+    # on the device, the first window has none
+    prof = [m for g, _s, m in reported[first_report:] if g == "profiling"]
+    assert len(prof) == 3 and "boundary_s" not in prof[0]
+    waits = spans["dtpu.trainer.boundary.wait"]
+    assert len(waits) == 3
+    for (_a, b), (_w, waited), m in zip(boundaries, waits, prof[1:]):
+        span_s = (b - waited) * 1e-9
+        assert abs(m["boundary_s"] - span_s) <= 5e-4 + 0.05 * span_s
+        assert {"gc_s", "gc_collections"} <= set(m)
+    assert trainer.timeline.boundary is None
 
 
 # -- the inner scopes of the layers only some models have (ISSUE 32) ---------

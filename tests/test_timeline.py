@@ -16,7 +16,6 @@ class TestLedger:
         tl.reset_window()
         tl.window["data_wait"] += 0.5
         tl.window["h2d_put"] += 0.25
-        tl.step_done()
         # wall is real perf_counter elapsed (tiny); the injected phase
         # times dominate, so the residual clamps at >= 0
         out = tl.close_window()
