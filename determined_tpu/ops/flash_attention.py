@@ -135,15 +135,13 @@ def _score_mask(qi, ki, *, block_q: int, block_k: int, causal: bool,
 
 
 def _mask_dispatch(qi, ki, *, block_q, block_k, causal, window, kv_offset,
-                   compute, on_skip=None):
+                   compute):
     """Run `compute(band_masked)` for one (qi, ki) block in the right band
     regime — shared by all the blocked kernels so the boundary logic lives
     once:
 
     - block fully outside the band (above the diagonal, or entirely past
-      the sliding window): contributes nothing, skip all work (`on_skip`,
-      when given, still runs — a kernel whose output block is
-      unconditionally mapped must zero it);
+      the sliding window): contributes nothing, skip all work;
     - block straddling a band edge: compute with the element mask;
     - block fully inside: compute without the iota/where VPU work
       (segment masking, when active, is applied inside `compute` either
@@ -168,7 +166,8 @@ def _mask_dispatch(qi, ki, *, block_q, block_k, causal, window, kv_offset,
     if window is not None:
         live = _and(live, last_k >= first_q - (window - 1))
         inside = _and(inside, first_k >= last_q - (window - 1))
-    # `inside` ⊆ `live` componentwise, so these three cover the grid.
+    # `inside` ⊆ `live` componentwise, so edge, inside and the skipped
+    # rest cover the grid.
     on_edge = live & jnp.logical_not(inside)
 
     @pl.when(on_edge)
@@ -178,11 +177,6 @@ def _mask_dispatch(qi, ki, *, block_q, block_k, causal, window, kv_offset,
     @pl.when(inside)
     def _():
         compute(band_masked=False)
-
-    if on_skip is not None:
-        @pl.when(jnp.logical_not(live))
-        def _():
-            on_skip()
 
 
 # ---------------------------------------------------------------------------
@@ -378,9 +372,9 @@ def _flash_fwd_pallas(
 # Monolithic (single-block) kernels: when one block spans the whole
 # sequence — the GPT-2-class regime, S ≤ ~1k — the blocked kernels' online
 # softmax machinery (m/l scratch read-modify-writes, correction multiplies,
-# @pl.when dispatch) is pure overhead, and the two-pass backward recomputes
-# p twice. These do plain softmax, and the fused backward produces
-# dq/dk/dv in ONE pass: 5 MXU dots + 1 exp a score instead of 7 + 2.
+# @pl.when dispatch) is pure overhead. These do plain softmax, and the
+# backward produces dq/dk/dv in ONE pass: 5 MXU dots + 1 exp a score, as
+# the blocked one does where a head's dq fits VMEM (the split pair's 7 + 2).
 #
 # Under the causal mask they walk the score matrix in static row chunks
 # (a Python loop, unrolled at trace time) and give chunk r0..r1 only the
@@ -550,25 +544,32 @@ def _bwd_kernel_mono(*refs, scale, causal, fused):
 def _bwd_fused_blocked_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
                               delta_ref, dlse_ref, *rest, scale, causal,
                               window, kv_offset, has_segments, block_q,
-                              block_k, num_q_blocks):
+                              block_k, num_q_blocks, num_k_blocks):
     """Fused blocked backward: ONE pass over (j, i) blocks computes s and
-    p once and feeds all three gradients — the two-pass split recomputes
-    them (7 matmuls + 2 exps per block pair vs 5 + 1 here) and re-reads
-    every q/k/v/do block a second time. Grid is k-major so dk/dv
-    accumulate in VMEM scratch over the inner q dimension; dq cannot
-    (it accumulates over the OUTER dimension), so each (j, i) writes an
-    fp32 partial and XLA sums the nk partials after the call."""
+    p once and feeds all three gradients — the split pair below recomputes
+    them (7 matmuls + 2 exps per block pair vs 5 + 1 here) and reads every
+    q/k/v/do block a second time. The grid is k-major, so dk/dv accumulate
+    in VMEM scratch over the inner q dimension. dq accumulates over the
+    OUTER dimension, so the whole head's dq is a VMEM scratch too,
+    [nq, block_q, d] fp32: block i is zeroed at its first visit (j = 0;
+    the chip hands scratch out as the last kernel left it), summed over
+    j = 0, 1, … in the dq kernel's order, and cast and written out on the
+    last key block's pass, where it is final."""
     if has_segments:
         qseg_ref, kseg_ref = rest[0], rest[1]
         rest = rest[2:]
-    dqp_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
+    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = rest
     ji = pl.program_id(1)
     qi = pl.program_id(2)
 
     @pl.when(qi == 0)
-    def _init():
+    def _init_kv():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(ji == 0)
+    def _init_q():
+        dq_scr[qi] = jnp.zeros(dq_scr.shape[1:], dq_scr.dtype)
 
     def _compute(band_masked):
         q = q_ref[0]    # [bq, d] bf16
@@ -607,20 +608,19 @@ def _bwd_fused_blocked_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        dqp_ref[0, 0] = jax.lax.dot_general(
+        dq_scr[qi] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    def _skip():
-        # This (j, i) block's dq partial is unconditionally mapped: zero
-        # it, or the XLA partial-sum reads garbage.
-        dqp_ref[0, 0] = jnp.zeros_like(dqp_ref[0, 0])
-
     _mask_dispatch(
         qi, ji, block_q=block_q, block_k=block_k, causal=causal,
-        window=window, kv_offset=kv_offset, compute=_compute, on_skip=_skip,
+        window=window, kv_offset=kv_offset, compute=_compute,
     )
+
+    @pl.when(ji == num_k_blocks - 1)
+    def _dq_out():
+        dq_ref[0] = dq_scr[qi].astype(dq_ref.dtype)
 
     @pl.when(qi == num_q_blocks - 1)
     def _epilogue():
@@ -628,19 +628,37 @@ def _bwd_fused_blocked_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-#: Cap on the fused blocked backward's dq-partials buffer ([BH, nk, S, D]
-#: fp32): past this, fall back to the two-pass split rather than spend
-#: the HBM. 16k sequences at GPT-2-small shapes use ~800 MB.
-_FUSED_BWD_PARTIALS_CAP = 1 << 30
+#: VMEM the fused blocked backward may give its dq accumulator, a head's
+#: [s_q, d] in fp32: 16 MiB holds a 16k sequence at d 256 (the 8k cells'
+#: heads of 256 take 8 MiB) or 64k at d 64. With the blocks the pipeline
+#: double-buffers and the fp32 score temporaries
+#: (`_fused_bwd_vmem_bytes`) that is under 32 MiB at block 512, a
+#: quarter of a v5e core's 128 MiB. Past it the split pair runs, whose
+#: accumulators are a block each.
+_DQ_VMEM_BUDGET = 16 << 20
+
+
+def _fused_bwd_vmem_bytes(s_q, d, block_q, block_k, itemsize):
+    """Scoped VMEM the fused blocked backward asks the compiler for: the
+    dq, dk and dv accumulators; two buffers of every block it reads (q,
+    do, k, v, and the row vectors, padded to 8 sublanes) and writes (dq,
+    dk, dv); and six [block_q, block_k] fp32 temporaries (s, p, dp, ds,
+    the mask and a cast)."""
+    scratch = (s_q + 2 * block_k) * d * 4
+    blocks = (2 * block_q + 2 * block_k) * d * itemsize   # q, do, k, v
+    blocks += (block_q + 2 * block_k) * d * itemsize      # dq, dk, dv
+    blocks += 5 * 8 * max(block_q, block_k) * 4           # lse, delta, …
+    return scratch + 2 * blocks + 6 * block_q * block_k * 4
 
 
 # ---------------------------------------------------------------------------
 # Pallas backward kernels (TPU): dq pass + dk/dv pass.
 #
-# Standard flash backward split: recomputing p costs one extra QK^T matmul
-# per pass but keeps every accumulator in VMEM scratch — dq accumulates
-# over the k-block grid dimension, dk/dv over the q-block dimension. All
-# MXU dots take bf16 inputs with fp32 accumulation.
+# The split flash backward, for a head whose dq passes `_DQ_VMEM_BUDGET`:
+# recomputing p costs one extra QK^T matmul per pass but keeps every
+# accumulator a block of VMEM scratch — dq accumulates over the k-block
+# grid dimension, dk/dv over the q-block dimension. All MXU dots take bf16
+# inputs with fp32 accumulation.
 # ---------------------------------------------------------------------------
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
                    *rest, scale, causal, window, kv_offset, has_segments,
@@ -862,59 +880,70 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
         _remap_k_index, block_q=block_q, block_k=block_k, causal=causal,
         window=window, kv_offset=kv_offset, nk=nk,
     )
+    inputs = [q, k, v, do, lse3, delta3, dlse3]
     if segs is not None:
-        qseg3, kseg3 = _seg3(segs, s_q, s_k)
+        inputs.extend(_seg3(segs, s_q, s_k))
 
-    if bh * nk * s_q * d * 4 <= _FUSED_BWD_PARTIALS_CAP:
-        # q-innermost grid: q-side blocks remap dead iterations for DMA
-        # elision; the k/v blocks are fixed per outer step.
-        fused_specs = [
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, qmap(j, i), 0)),   # q
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),   # k
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),   # v
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, qmap(j, i), 0)),   # do
-            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, qmap(j, i))),   # lse
-            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, qmap(j, i))),   # delta
-            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, qmap(j, i))),   # dlse
-        ]
-        inputs = [q, k, v, do, lse3, delta3, dlse3]
-        if has_segments:
-            fused_specs.append(
-                pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, qmap(j, i)))
-            )
-            fused_specs.append(
-                pl.BlockSpec((1, 1, block_k), lambda b, j, i: (b, 0, j))
-            )
-            inputs.extend([qseg3, kseg3])
-        dqp, dk, dv = pl.pallas_call(
+    # q-innermost grid (the fused kernel and the dk/dv pass): q-side blocks
+    # remap dead iterations for DMA elision; the k/v blocks are fixed per
+    # outer step.
+    col_specs = [
+        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, qmap(j, i), 0)),   # q
+        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),   # k
+        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),   # v
+        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, qmap(j, i), 0)),   # do
+        pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, qmap(j, i))),   # lse
+        pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, qmap(j, i))),   # delta
+        pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, qmap(j, i))),   # dlse
+    ]
+    if has_segments:
+        col_specs.append(
+            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, qmap(j, i)))
+        )
+        col_specs.append(pl.BlockSpec((1, 1, block_k), lambda b, j, i: (b, 0, j)))
+    dkv_specs = [
+        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+    ]
+    dkv_shapes = [
+        jax.ShapeDtypeStruct((bh, s_k, d), k.dtype),
+        jax.ShapeDtypeStruct((bh, s_k, d), v.dtype),
+    ]
+    dkv_scratch = [
+        pltpu.VMEM((block_k, d), jnp.float32),
+        pltpu.VMEM((block_k, d), jnp.float32),
+    ]
+
+    if s_q * d * 4 <= _DQ_VMEM_BUDGET:
+        # dq block i leaves on the last key block's pass (j = nk − 1); until
+        # then its index stays where that pass starts, so nothing is
+        # written back before it is final.
+        dq_spec = pl.BlockSpec(
+            (1, block_q, d),
+            lambda b, j, i: (b, jnp.where(j == nk - 1, i, 0), 0),
+        )
+        vmem = _fused_bwd_vmem_bytes(s_q, d, block_q, block_k,
+                                     max(x.dtype.itemsize for x in (q, k, v)))
+        return tuple(pl.pallas_call(
             functools.partial(
                 _bwd_fused_blocked_kernel, scale=scale, causal=causal,
                 window=window, kv_offset=kv_offset,
-                has_segments=has_segments,
-                block_q=block_q, block_k=block_k, num_q_blocks=nq,
+                has_segments=has_segments, block_q=block_q, block_k=block_k,
+                num_q_blocks=nq, num_k_blocks=nk,
             ),
             grid=(bh, nk, nq),
-            in_specs=fused_specs,
-            out_specs=[
-                pl.BlockSpec(
-                    (1, 1, block_q, d), lambda b, j, i: (b, j, i, 0)
-                ),
-                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((bh, nk, s_q, d), jnp.float32),
-                jax.ShapeDtypeStruct((bh, s_k, d), k.dtype),
-                jax.ShapeDtypeStruct((bh, s_k, d), v.dtype),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32),
-            ],
+            in_specs=col_specs,
+            out_specs=[dq_spec] + dkv_specs,
+            out_shape=[jax.ShapeDtypeStruct((bh, s_q, d), q.dtype)]
+            + dkv_shapes,
+            scratch_shapes=[pltpu.VMEM((nq, block_q, d), jnp.float32)]
+            + dkv_scratch,
+            # a whole head's dq can pass the default scoped limit (16 MiB
+            # on a v5e); never ask for less than it
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=max(vmem, 16 << 20)),
             interpret=interpret,
-        )(*inputs)
-        dq = jnp.sum(dqp, axis=1).astype(q.dtype)
-        return dq, dk, dv
+        )(*inputs))
 
     row_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),   # q
@@ -925,13 +954,11 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),   # delta
         pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),   # dlse
     ]
-    dq_inputs = [q, k, v, do, lse3, delta3, dlse3]
     if has_segments:
         row_specs.append(pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)))
         row_specs.append(
             pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, kmap(i, j)))
         )
-        dq_inputs.extend([qseg3, kseg3])
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, scale=scale, causal=causal,
@@ -944,24 +971,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-    )(*dq_inputs)
-
-    col_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, qmap(j, i), 0)),   # q
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),   # k
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),   # v
-        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, qmap(j, i), 0)),   # do
-        pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, qmap(j, i))),   # lse
-        pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, qmap(j, i))),   # delta
-        pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, qmap(j, i))),   # dlse
-    ]
-    dkv_inputs = [q, k, v, do, lse3, delta3, dlse3]
-    if has_segments:
-        col_specs.append(
-            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, qmap(j, i)))
-        )
-        col_specs.append(pl.BlockSpec((1, 1, block_k), lambda b, j, i: (b, 0, j)))
-        dkv_inputs.extend([qseg3, kseg3])
+    )(*inputs)
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, scale=scale, causal=causal,
@@ -970,20 +980,11 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
         ),
         grid=(bh, nk, nq),
         in_specs=col_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s_k, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
+        out_specs=dkv_specs,
+        out_shape=dkv_shapes,
+        scratch_shapes=dkv_scratch,
         interpret=interpret,
-    )(*dkv_inputs)
+    )(*inputs)
     return dq, dk, dv
 
 

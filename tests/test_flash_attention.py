@@ -1,4 +1,6 @@
 """Flash attention vs dense reference (CPU blockwise path + grads)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -57,7 +59,7 @@ def test_flash_bad_block():
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("block", [16, 32])
 @pytest.mark.parametrize("fused", [True, False])
-def test_flash_pallas_bwd_interpret_matches(causal, block, fused):
+def test_flash_pallas_bwd_interpret_matches(monkeypatch, causal, block, fused):
     """The Pallas backward kernels (the TPU path) against the blockwise
     reference backward, in interpret mode. Block 16 at s=64 exercises all
     three causal regimes (skip / masked diagonal / unmasked below)."""
@@ -87,21 +89,107 @@ def test_flash_pallas_bwd_interpret_matches(causal, block, fused):
     dlse = jax.random.normal(jax.random.PRNGKey(6), lse.shape)
     want = _blockwise_bwd_ref(qf, kf, vf, o, lse, do, scale=scale,
                               causal=causal, block_k=block, dlse=dlse)
-    # fused=True: the one-pass blocked kernel (dq via fp32 partials);
-    # fused=False: the two-pass dq + dkv split (the >cap fallback).
-    prev_cap = fa._FUSED_BWD_PARTIALS_CAP
-    fa._FUSED_BWD_PARTIALS_CAP = prev_cap if fused else 0
-    try:
-        got = _flash_bwd_pallas(qf, kf, vf, o, lse, do, scale=scale,
-                                causal=causal, block_q=block, block_k=block,
-                                interpret=True, dlse=dlse)
-    finally:
-        fa._FUSED_BWD_PARTIALS_CAP = prev_cap
+    # fused=True: the one-pass blocked kernel (dq in VMEM scratch);
+    # fused=False: the two-pass dq + dkv split (the past-budget fallback).
+    if not fused:
+        monkeypatch.setattr(fa, "_DQ_VMEM_BUDGET", 0)
+    got = _flash_bwd_pallas(qf, kf, vf, o, lse, do, scale=scale,
+                            causal=causal, block_q=block, block_k=block,
+                            interpret=True, dlse=dlse)
     for name, a, b_ in zip(("dq", "dk", "dv"), got, want):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b_), atol=5e-5, rtol=5e-5,
             err_msg=name,
         )
+
+
+@pytest.mark.parametrize("s_q,s_k,bq,bk,causal,window,segs,offset", [
+    (64, 64, 16, 16, False, None, False, 0),
+    (64, 64, 16, 16, True, None, False, 0),
+    (64, 64, 16, 32, True, None, False, 0),     # block_q != block_k
+    (64, 64, 16, 16, True, 23, False, 0),
+    (64, 64, 16, 16, True, None, True, 0),
+    (64, 64, 16, 16, False, None, True, 0),
+    (64, 64, 16, 16, True, 17, True, 0),
+    (32, 64, 16, 16, True, None, False, 32),    # kv_offset, s_q != s_k
+    (32, 96, 16, 32, False, None, False, 0),    # s_q != s_k, not causal
+    (48, 80, 16, 16, True, 40, False, 32),
+], ids=["whole", "causal", "causal-rect", "window", "segments",
+        "segments-whole", "window-segments", "kv-offset", "cross",
+        "window-offset"])
+def test_flash_fused_bwd_is_the_split_pair_to_the_bit(
+        monkeypatch, s_q, s_k, bq, bk, causal, window, segs, offset):
+    """The one-pass blocked backward, dq summed in VMEM scratch across the
+    key blocks, gives the split dq / dkv pair's gradients bit for bit in
+    float32: dq adds ds·k over the key blocks, dk and dv over the query
+    blocks, in the order the pair does, from the same s, p, dp and ds. Its
+    scratch starts as NaN here (the chip hands scratch out as the last
+    kernel left it), so an accumulator not zeroed before its first sum
+    fails; three heads share it, so one zeroed once a call fails too.
+    Both are also the blockwise reference backward's, with an lse
+    cotangent (ring attention's)."""
+    import importlib
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    fa = importlib.import_module("determined_tpu.ops.flash_attention")
+    bh, d = 3, 16
+    kq, kk, kv, kd, kl = jax.random.split(jax.random.PRNGKey(s_q + s_k), 5)
+    q = jax.random.normal(kq, (bh, s_q, d))
+    k = jax.random.normal(kk, (bh, s_k, d))
+    v = jax.random.normal(kv, (bh, s_k, d))
+    do = jax.random.normal(kd, q.shape)
+    seg = None
+    if segs:
+        ids = _packed_segments(jax.random.PRNGKey(s_k), bh, s_k, 3)
+        seg = (ids[:, offset:offset + s_q].astype(jnp.float32),
+               ids.astype(jnp.float32))
+    band = dict(scale=1.0 / d ** 0.5, causal=causal, window=window,
+                kv_offset=offset, segs=seg)
+    o, lse = fa._blockwise_fwd_ref(q, k, v, block_k=bk, **band)
+    dlse = jax.random.normal(kl, lse.shape)
+
+    def pallas(interpret):
+        return fa._flash_bwd_pallas(q, k, v, o, lse, do, block_q=bq,
+                                    block_k=bk, interpret=interpret,
+                                    dlse=dlse, **band)
+
+    fused = pallas(pltpu.InterpretParams(uninitialized_memory="nan"))
+    monkeypatch.setattr(fa, "_DQ_VMEM_BUDGET", 0)
+    split = pallas(True)
+    want = fa._blockwise_bwd_ref(q, k, v, o, lse, do, block_k=bk, dlse=dlse,
+                                 **band)
+    for name, a, b_, w in zip(("dq", "dk", "dv"), fused, split, want):
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_),
+                                      err_msg=name)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), atol=5e-5,
+                                   rtol=5e-5, err_msg=name)
+
+
+def test_flash_fused_bwd_takes_shapes_whose_dq_fits_the_budget(monkeypatch):
+    """One blocked backward kernel while a head's fp32 dq fits
+    `_DQ_VMEM_BUDGET` (the 8k cells' 8 MiB), the split pair past it: the
+    choice is the shape's, and the split's two calls are the only way to
+    two."""
+    import importlib
+
+    fa = importlib.import_module("determined_tpu.ops.flash_attention")
+    assert 8192 * 256 * 4 <= fa._DQ_VMEM_BUDGET < 32768 * 256 * 4
+
+    def calls(s, d):
+        x = jax.ShapeDtypeStruct((2, s, d), jnp.bfloat16)
+        vec = jax.ShapeDtypeStruct((2, s), jnp.float32)
+        jaxpr = jax.make_jaxpr(functools.partial(
+            fa._flash_bwd_pallas, scale=1.0, causal=True, block_q=512,
+            block_k=512))(x, x, x, x, vec, x)
+        return str(jaxpr).count("pallas_call")
+
+    assert calls(8192, 256) == 1
+    assert calls(16384, 256) == 1
+    assert calls(32768, 256) == 2
+    monkeypatch.setattr(fa, "_DQ_VMEM_BUDGET", 0)
+    assert calls(8192, 256) == 2
 
 
 def test_flash_pallas_interpret_matches():

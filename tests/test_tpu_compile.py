@@ -71,7 +71,7 @@ def _for_the_chip(monkeypatch):
     compilation_cache.reset_cache()
 
 
-def _flash_train(s, heads=12, head_dim=64):
+def _flash_train(s, heads=12, head_dim=64, block=1024):
     """Forward + all three gradients at batch 1 x 12 heads x 64 (1k rides
     the GPT-2 bench batch of 24 instead: BH 288)."""
     b = 24 if s == 1024 else 1
@@ -80,7 +80,7 @@ def _flash_train(s, heads=12, head_dim=64):
     def step(q, k, v):
         def loss(q, k, v):
             o = fa.flash_attention(
-                q, k, v, causal=True, block_q=1024, block_k=1024
+                q, k, v, causal=True, block_q=block, block_k=block
             )
             return jnp.sum(o.astype(jnp.float32))
 
@@ -184,8 +184,12 @@ CASES = {
     "gdn_recurrence_8k_stash_and_backward": lambda: _gdn_recurrence(True),
     "flash_train_1k_mono": lambda: _flash_train(1024),
     "flash_train_1k_mono_d128": lambda: _flash_train(1024, 16, 128),
-    "flash_train_16k_fused_blocked": lambda: _flash_train(16384),
-    "flash_train_32k_split": lambda: _flash_train(32768),
+    "flash_train_16k_fused_vmem_dq": lambda: _flash_train(16384),
+    "flash_train_32k_fused_vmem_dq": lambda: _flash_train(32768),
+    "flash_train_32k_d256_split": lambda: _flash_train(32768, 2, 256, 512),
+    # the 8k cells' attention: GLM's 20 latent heads, Qwen's 16 query heads
+    "flash_train_8k_glm_20x256": lambda: _flash_train(8192, 20, 256, 512),
+    "flash_train_8k_qwen_16x256": lambda: _flash_train(8192, 16, 256, 512),
     "packed_prefill_4x512_segments": _packed_prefill,
     "cached_prefill_kv_offset": _cached_prefill,
     "gather_decode_q1_8x128": lambda: _gather_decode(1),
@@ -207,6 +211,42 @@ def test_kernel_compiles_for_v5e(chip, case):
     assert "tpu_custom_call" in compiled.as_text(), (
         "compiled without a Mosaic kernel: the reference path was taken"
     )
+
+
+def _flash_kernels(text):
+    """The Mosaic operations of a compiled program that
+    `benchmark/kernels/flash_*.json` match, counted by kernel."""
+    from benchmark import kernel_events, trace_reduce
+
+    found = collections.Counter()
+    for line in text.splitlines():
+        if trace_reduce.MOSAIC in line and " custom-call(" in line:
+            op = trace_reduce.op_name(line.strip())
+            found.update(k for k in ("flash_forward", "flash_backward")
+                         if re.search(kernel_events.kernel(k)["pattern"], op))
+    return found
+
+
+@pytest.mark.parametrize("case,backwards", [
+    ("flash_train_8k_glm_20x256", 1),
+    ("flash_train_8k_qwen_16x256", 1),
+    ("flash_train_32k_d256_split", 2),   # a head's dq passes the budget
+])
+def test_blocked_backward_is_one_kernel_where_dq_fits(chip, case, backwards):
+    """At the 8k cells' attention shapes (batch 1, S 8192, d 256, block
+    512) the blocked backward compiles, its VMEM limit raised for the
+    whole head's fp32 dq, as ONE Mosaic kernel under the name
+    `flash_backward.json` matches, and the program holds no fp32
+    [heads, key blocks, S, d] partials of dq. Past the budget the split
+    pair's two kernels run, under that name too."""
+    step, shapes = CASES[case]()
+    (_, s, heads, d), _ = shapes[0]
+    text = jax.jit(step).lower(*[
+        jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+        for shape, dtype in shapes]).compile().as_text()
+    assert dict(_flash_kernels(text)) == {
+        "flash_forward": 1, "flash_backward": backwards}
+    assert f"f32[{heads},{s // 512},{s},{d}]" not in text
 
 
 # -- the names the flash kernels have in a trace ------------------------------
@@ -539,4 +579,4 @@ def test_glm47flash_cells_step_keeps_the_kernels_names(monkeypatch, chips):
                 found[k] += n
     # 2 layers and the MTP module's block: three forwards, three backwards
     assert sum(ops.values()) == sum(found.values()), ops
-    assert found["flash_forward"] == 3 and found["flash_backward"] >= 3, ops
+    assert found["flash_forward"] == 3 and found["flash_backward"] == 3, ops
